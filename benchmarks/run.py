@@ -83,8 +83,10 @@ def conv_bench(fast: bool) -> dict:
     x = jax.random.normal(key, (1, 32, 32, 8), jnp.float32)
     w = jax.random.normal(key, (3, 3, 8, 16), jnp.float32) * 0.2
     b = jnp.zeros((16,))
+    # a 1 MiB budget forces H-tiling (the (8, 128)-padded tiles of this
+    # 8-channel layer take ~1.2 MiB per 8 conv rows)
     plan = autotune.plan_for_layer(x.shape, w.shape, pad=1, pool="max",
-                                   vmem_budget=256 * 1024)
+                                   vmem_budget=1024 * 1024)
 
     def timed(fn, iters):
         fn().block_until_ready()
@@ -574,6 +576,8 @@ def main() -> None:
     sys.path.insert(0, "src")
     from benchmarks import (bandwidth, fig7_dse, fig8_timeline,
                             lm_roofline, lrn_accuracy, table1_comparison)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     csv_rows = []
 

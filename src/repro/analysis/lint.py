@@ -24,8 +24,8 @@ Determinism (RPA1xx)
 
 Contracts (RPA2xx)
   RPA201  internal calls to the deprecated shims ``cnn_forward`` /
-          ``dump_registry`` / ``set_interpret`` (frozen for external
-          callers; new internal code compiles once).
+          ``dump_registry`` (frozen for external callers; new internal
+          code compiles once).
   RPA202  mutable default arguments (list/dict/set literals or calls) —
           shared state smuggled into the frozen-spec modules.
   RPA203  ``__all__`` drift: names declared but not bound at module
@@ -69,7 +69,7 @@ _STDLIB_RNG = {
     "shuffle", "sample", "gauss", "normalvariate", "betavariate",
     "expovariate", "getrandbits", "seed",
 }
-_DEPRECATED_SHIMS = {"cnn_forward", "dump_registry", "set_interpret"}
+_DEPRECATED_SHIMS = {"cnn_forward", "dump_registry"}
 
 # API-surface snapshot set -> the module __all__ it pins (repo-relative).
 SNAPSHOT_MODULES = {

@@ -47,7 +47,7 @@ from repro.kernels.autotune import (ConvPlan, ConvShape, GemmPlan,
                                     GemmShape, _DTYPE_BYTES,
                                     conv_vmem_bytes, gemm_plan_fits,
                                     gemm_vmem_bytes, plan_fits)
-from repro.kernels.conv_pipe import conv_tile_geometry
+from repro.kernels.conv_pipe import conv_tile_geometry, s2d_geometry
 
 _ROW_FIELDS = ("shape", "backend", "vmem_budget", "plan")
 
@@ -112,13 +112,16 @@ def _check_conv_row(loc: str, row: dict, shape: ConvShape, plan: ConvPlan,
             f"b_blk={plan.b_blk} exceeds the serving batch b={shape.b} "
             f"the plan is keyed for — the grid would read past the "
             f"batch"))
-    cg, mg = shape.c // shape.groups, shape.m // shape.groups
+    # c_blk counts the kernel's space-to-depth channels (s*s*C/G)
+    cg = s2d_geometry(shape.h, shape.w, shape.c // shape.groups, shape.kh,
+                      shape.kw, stride=shape.stride, pad=shape.pad).c
+    mg = shape.m // shape.groups
     if plan.c_blk > cg or plan.m_blk > mg:
         findings.append(Finding(
             "RPA303", loc, 0,
             f"channel blocks (c_blk={plan.c_blk}, m_blk={plan.m_blk}) "
-            f"exceed the per-group channels (c/g={cg}, m/g={mg}) — the "
-            f"committed plan over-declares its tile"))
+            f"exceed the per-group channels (space-to-depth c/g={cg}, "
+            f"m/g={mg}) — the committed plan over-declares its tile"))
     if shape.pool and plan.oh_blk and plan.oh_blk % shape.pool_s:
         findings.append(Finding(
             "RPA303", loc, 0,

@@ -18,12 +18,37 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-# --- TPU v5e hardware constants (per chip) ---
-PEAK_FLOPS = 197e12          # bf16
-PEAK_OPS_INT8 = 394e12       # int8 MACs run at 2x the bf16 MXU rate
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
-VMEM_BYTES = 16 * 2 ** 20    # per-core VMEM (the TPU's "DSP budget")
+# --- per-chip peaks, keyed by jax's ``device_kind`` ---
+# TPU v5e ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" — 197
+# TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect (4 links of 50 GB/s).
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bw": 819e9, "hbm_bytes": 16e9, "ici_bw": 50e9},
+}
+# the device the roofline model and the tuner price plans for
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_peaks(kind: str) -> Dict[str, float]:
+    """The published peaks of ``kind`` (a ``device_kind`` string).
+
+    A device that is not in :data:`DEVICE_PEAKS` is an error, not a
+    default: a roofline share against the wrong chip's peak is noise.
+    """
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {kind!r}; "
+                       f"known: {sorted(DEVICE_PEAKS)}") from None
+
+
+_TARGET = device_peaks(TARGET_DEVICE_KIND)
+PEAK_FLOPS = _TARGET["bf16_flops"]
+PEAK_OPS_INT8 = _TARGET["int8_ops"]   # int8 MACs run at 2x the bf16 rate
+HBM_BW = _TARGET["hbm_bw"]            # bytes/s
+ICI_BW = _TARGET["ici_bw"]            # bytes/s per link
+VMEM_BYTES = 16 * 2 ** 20    # Mosaic's default scoped-VMEM limit per kernel
 MXU_DIM = 128                # systolic array dimension
 
 
@@ -38,12 +63,8 @@ def peak_ops(dtype: str = "bfloat16") -> float:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: older releases
-    return a one-element list of dicts, newer ones the dict itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()`` as a dict (empty when XLA gives none)."""
+    return compiled.cost_analysis() or {}
 
 
 def mxu_utilization(c_blk: int, m_blk: int) -> float:

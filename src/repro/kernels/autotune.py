@@ -26,7 +26,7 @@ are per image, so plans tuned at different serve batches are comparable.
     its measured-refinement pass from. Operand data is deterministic per
     ``(shape, plan)`` (crc32-derived key, split into independent x/w
     streams) and the ``interpret`` mode defaults to the process backend
-    mode (``ops.get_interpret()``) so a measurement is taken — and
+    mode (``mode.get_interpret()``) so a measurement is taken — and
     recorded in provenance — in the mode that will actually run.
 
 Plans are plain frozen dataclasses so they can ride through ``jax.jit``
@@ -43,9 +43,27 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 from repro.core.roofline import (MXU_DIM, VMEM_BYTES, mxu_utilization,
                                  time_bounds)
-from repro.kernels.conv_pipe import _round_up, conv_tile_geometry
+from repro.kernels.conv_pipe import (LANE, SUBLANE, _round_up,
+                                     conv_tile_geometry, s2d_geometry)
+from repro.kernels.mode import resolve_interpret
 
 _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
+
+# The tuner prices plans for one device, the TPU v5e
+# (core.roofline.TARGET_DEVICE_KIND: its peaks there, its VMEM layout
+# here). The plans it proposes lower there.
+
+
+def _sublanes(dt: int) -> int:
+    """Rows of one (sublane, 128) VMEM tile: 8 for 32-bit dtypes, 16
+    for 16-bit, 32 for int8 (packed rows)."""
+    return SUBLANE * 4 // dt
+
+
+def _tile_bytes(rows: int, cols: int, dt: int) -> int:
+    """Bytes a (rows, cols) slab takes in VMEM: Mosaic pads the minor
+    dim to 128 lanes and the second-minor to the dtype's sublane tile."""
+    return _round_up(rows, _sublanes(dt)) * _round_up(cols, LANE) * dt
 
 # The public autotune surface (pinned by tests/test_api_surface.py).
 __all__ = [
@@ -116,39 +134,58 @@ class ConvPlan:
         return dataclasses.asdict(self)
 
 
+def _conv_geometry(shape: ConvShape, c_blk: int, m_blk: int,
+                   oh_blk: int, b_blk: int):
+    """The kernel's real geometry for one plan: the space-to-depth conv
+    (stride 1, ``s*s*C/G`` channels), clamped blocks and the H tiling."""
+    g = s2d_geometry(shape.h, shape.w, shape.c // shape.groups, shape.kh,
+                     shape.kw, stride=shape.stride, pad=shape.pad)
+    c_blk = min(c_blk, g.c)
+    m_blk = min(m_blk, shape.m // shape.groups)
+    b_blk = max(1, min(b_blk, shape.b))
+    tiles = conv_tile_geometry(g.oh, oh_blk, stride=1, kh=g.kh,
+                               pool=shape.pool, pool_k=shape.pool_k,
+                               pool_s=shape.pool_s)
+    pw = ((g.ow - shape.pool_k) // shape.pool_s + 1
+          if shape.pool else g.ow)
+    return g, c_blk, m_blk, b_blk, tiles, pw
+
+
 def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
                     oh_blk: int, b_blk: int = 1) -> int:
     """VMEM working set of one grid step of the tiled conv_pipe kernel.
 
-    Pipelined refs (x tile, w tile, bias, out tile) are double-buffered by
-    Pallas (factor 2); the accumulator scratch is single-buffered and
-    always 4 bytes/element (fp32, or int32 in the fixed-point pipeline).
-    The x tile, out tile and accumulator scale with ``b_blk`` (batch
-    folding keeps b_blk images of the H-tile resident); the weight tile
-    does not — that asymmetry is the whole point of batching. int8
-    shrinks the streamed tiles 4x (1-byte tensors) but adds the fp32
-    requantize-scale tile (rides like the bias), and bias/scale stay fp32.
+    Every slab is counted as Mosaic lays it out: minor dim padded to 128
+    lanes, second-minor to the dtype's sublane tile (AlexNet conv1's 48
+    space-to-depth channels occupy 128 lanes). Pipelined refs (x tile,
+    w tile, bias, out tile) are double-buffered by Pallas (factor 2); the
+    accumulator scratch (and the pool scratch, when pooling) is single-
+    buffered and always 4 bytes/element. The kernel body's temporaries
+    are counted too: the column-shifted x window, the folded im2col
+    patch, one tap's matmul result and the fp32 epilogue value. The x
+    tile, out tile, accumulator and temporaries scale with ``b_blk``; the
+    weight tile does not — that asymmetry is the whole point of batching.
+    int8 shrinks the streamed tiles (1-byte tensors) but bias/scale stay
+    fp32.
     """
     dt = _DTYPE_BYTES.get(shape.dtype, 4)
     quantized = shape.dtype == "int8"
-    cg = shape.c // shape.groups
-    mg = shape.m // shape.groups
-    c_blk = min(c_blk, cg)
-    m_blk = min(m_blk, mg)
-    b_blk = max(1, min(b_blk, shape.b))
-    wp = shape.w + 2 * shape.pad
-    _, pr, oh_ext, hp_blk, _ = conv_tile_geometry(
-        shape.oh, oh_blk, stride=shape.stride, kh=shape.kh,
-        pool=shape.pool, pool_k=shape.pool_k, pool_s=shape.pool_s)
-    pw = ((shape.ow - shape.pool_k) // shape.pool_s + 1
-          if shape.pool else shape.ow)
-    x_tile = b_blk * hp_blk * wp * c_blk * dt
-    w_tile = shape.kh * shape.kw * c_blk * m_blk * dt
-    b_tile = m_blk * (4 if quantized else dt)      # int8 keeps fp32 bias
-    s_tile = m_blk * 4 if quantized else 0   # requantize multiplier (fp32)
-    o_tile = b_blk * pr * pw * m_blk * dt
-    acc = b_blk * oh_ext * shape.ow * m_blk * 4    # fp32 / int32 scratch
-    return 2 * (x_tile + w_tile + b_tile + s_tile + o_tile) + acc
+    g, c_blk, m_blk, b_blk, tiles, pw = _conv_geometry(
+        shape, c_blk, m_blk, oh_blk, b_blk)
+    _, pr, oh_ext, hp_blk, _ = tiles
+    rows = b_blk * oh_ext * g.ow_p
+    x_tile = b_blk * hp_blk * _tile_bytes(g.w, c_blk, dt)
+    w_tile = g.kh * g.kw * _tile_bytes(c_blk, m_blk, dt)
+    vec = _tile_bytes(1, m_blk, 4)               # fp32 (1, M_BLK) row
+    o_tile = b_blk * pr * _tile_bytes(pw, m_blk, dt)
+    acc = _tile_bytes(rows, m_blk, 4)            # fp32 / int32 scratch
+    pool = (b_blk * oh_ext * _tile_bytes(g.ow_p, m_blk, 4)
+            if shape.pool else 0)
+    temps = (b_blk * hp_blk * _tile_bytes(g.ow_p, c_blk, dt)   # x window
+             + _tile_bytes(rows, c_blk, dt)                    # patch
+             + 2 * acc)                        # tap result + epilogue
+    pipelined = x_tile + w_tile + vec * (2 if quantized else 1) + o_tile
+    return 2 * pipelined + acc + pool + temps
 
 
 def plan_fits(shape: ConvShape, plan: ConvPlan,
@@ -169,14 +206,17 @@ def score_plan(shape: ConvShape, c_blk: int, m_blk: int,
                oh_blk: int, b_blk: int = 1) -> Tuple[float, float]:
     """(t_compute, t_memory) roofline terms PER IMAGE for one plan.
 
-    Models the traffic the BlockSpec index maps actually generate:
+    Models the traffic the BlockSpec index maps actually generate for the
+    space-to-depth conv the kernel runs:
       x  — re-fetched for every M-tile; halo rows re-fetched per H-tile
       w  — re-fetched for every (image-block, H-tile): batch folding
            divides the per-image weight traffic by ``b_blk``
       out — written once
     Channel padding waste (Fig. 7's VEC_SIZE argument) shows up through
     the padded c/m tile counts; batch padding waste (a trailing partial
-    image block computes zero images) through the padded image count.
+    image block computes zero images) through the padded image count;
+    space-to-depth tap padding and the 8-aligned output width through
+    the computed rows and taps.
 
     Dtype-aware (the paper's fixed-point trade, modeled): int8 shrinks
     every streamed byte 4x vs fp32 AND doubles the MXU op rate
@@ -185,30 +225,32 @@ def score_plan(shape: ConvShape, c_blk: int, m_blk: int,
     different (b,c,m,oh)_blk points for int8 than for fp32.
     """
     dt = _DTYPE_BYTES.get(shape.dtype, 4)
-    cg, mg = shape.c // shape.groups, shape.m // shape.groups
-    c_blk, m_blk = min(c_blk, cg), min(m_blk, mg)
-    b_blk = max(1, min(b_blk, shape.b))
-    cgp, mgp = _round_up(cg, c_blk), _round_up(mg, m_blk)
+    g, c_blk, m_blk, b_blk, tiles, pw = _conv_geometry(
+        shape, c_blk, m_blk, oh_blk, b_blk)
+    n_h, pr, oh_ext, hp_blk, _ = tiles
+    mg = shape.m // shape.groups
+    cgp, mgp = _round_up(g.c, c_blk), _round_up(mg, m_blk)
     n_c, n_m = cgp // c_blk, shape.groups * (mgp // m_blk)
     n_b = -(-shape.b // b_blk)
     bp = n_b * b_blk                       # padded image count
-    wp = shape.w + 2 * shape.pad
-    n_h, pr, oh_ext, hp_blk, _ = conv_tile_geometry(
-        shape.oh, oh_blk, stride=shape.stride, kh=shape.kh,
-        pool=shape.pool, pool_k=shape.pool_k, pool_s=shape.pool_s)
-    pw = ((shape.ow - shape.pool_k) // shape.pool_s + 1
-          if shape.pool else shape.ow)
 
-    x_bytes = bp * n_h * n_m * n_c * hp_blk * wp * c_blk * dt
-    w_bytes = n_b * n_h * n_m * n_c * shape.kh * shape.kw * c_blk * m_blk * dt
+    x_bytes = bp * n_h * n_m * n_c * hp_blk * g.w * c_blk * dt
+    w_bytes = n_b * n_h * n_m * n_c * g.kh * g.kw * c_blk * m_blk * dt
     o_bytes = bp * n_h * pr * pw * (n_m * m_blk) * dt
     # padded-lane compute: the kernel multiplies the padded tiles
-    flops = 2 * bp * (n_h * pr if shape.pool is None else n_h * oh_ext) \
-        * shape.ow * (n_m * m_blk) * shape.kh * shape.kw * cgp
+    flops = 2 * bp * n_h * oh_ext * g.ow_p * (n_m * m_blk) \
+        * g.kh * g.kw * cgp
     tc, tm = time_bounds(flops, x_bytes + w_bytes + o_bytes,
                          mxu_util=mxu_utilization(c_blk, m_blk),
                          dtype=shape.dtype)
     return tc / shape.b, tm / shape.b
+
+
+def _lane_cands(full: int, cap: int) -> List[int]:
+    """Lane-legal block sizes for a minor dim of size ``full``: multiples
+    of 128 below it (up to ``cap``) plus the whole dim."""
+    return sorted({v for v in range(LANE, min(full, cap + 1), LANE)}
+                  | {full})
 
 
 def _pow2_upto(limit: int, lo: int = 8) -> List[int]:
@@ -223,15 +265,20 @@ def _pow2_upto(limit: int, lo: int = 8) -> List[int]:
 
 def enumerate_plans(shape: ConvShape,
                     vmem_budget: int = VMEM_BYTES) -> List[ConvPlan]:
-    """All (b_blk, c_blk, m_blk, oh_blk) points that fit the VMEM budget.
+    """All (b_blk, c_blk, m_blk, oh_blk) points that fit the VMEM budget
+    and lower on the target: channel blocks are lane-legal
+    (:func:`repro.kernels.conv_pipe.lane_legal`).
 
     ``b_blk`` candidates are powers of two up to the serving batch
     ``shape.b`` (plus the batch itself); for b=1 this degenerates to the
     PR 1 three-axis sweep.
     """
-    cg, mg = shape.c // shape.groups, shape.m // shape.groups
-    c_cands = sorted({min(v, cg) for v in _pow2_upto(min(cg, 2 * MXU_DIM))})
-    m_cands = sorted({min(v, mg) for v in _pow2_upto(min(mg, 2 * MXU_DIM))})
+    g = s2d_geometry(shape.h, shape.w, shape.c // shape.groups, shape.kh,
+                     shape.kw, stride=shape.stride, pad=shape.pad)
+    # channel blocks are lane dims: multiples of 128 or the whole
+    # (space-to-depth, per-group) channel count
+    c_cands = _lane_cands(g.c, 2 * MXU_DIM)
+    m_cands = _lane_cands(shape.m // shape.groups, 2 * MXU_DIM)
     step = shape.pool_s if shape.pool else 1
     oh_cands = sorted({min(_round_up(v, step), _round_up(shape.oh, step))
                        for v in (1, 2, 4, 8, 16, 32, 64, shape.oh)})
@@ -264,20 +311,6 @@ def best_plan(shape: ConvShape,
                                        * p.oh_blk)))
 
 
-def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    """``None`` -> the process backend mode (``ops.get_interpret()``).
-
-    Measuring the interpreter while the pipeline runs compiled (or vice
-    versa) is silently meaningless, so the default follows whatever mode
-    ``ops.interpret_mode`` / ``set_interpret`` put the process in — and
-    callers record the RESOLVED mode into measurement provenance.
-    """
-    if interpret is None:
-        from repro.kernels import ops
-        return ops.get_interpret()
-    return bool(interpret)
-
-
 def _measure_seed(shape, plan) -> int:
     """Deterministic PRNG seed per ``(shape, plan)`` measurement point.
 
@@ -308,7 +341,7 @@ def measure_plan(shape: ConvShape, plan: ConvPlan, *, iters: int = 3,
 
     from repro.kernels.conv_pipe import conv_pipe
 
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     kx, kw = jax.random.split(jax.random.key(_measure_seed(shape, plan)))
     x = jax.random.normal(kx, (shape.b, shape.h, shape.w, shape.c),
                           jnp.float32)
@@ -389,21 +422,22 @@ class GemmPlan:
 def gemm_vmem_bytes(shape: GemmShape, bm: int, bn: int, bk: int) -> int:
     """VMEM working set of one ``matmul_pipe`` grid step.
 
-    Pipelined refs (x, w, bias, out) are double-buffered; the accumulator
-    scratch is single-buffered and always 4 bytes/element (fp32, or int32
-    in the int8 mode). int8 keeps an fp32 bias and adds the fp32
-    requantize-scale tile — the same asymmetry as :func:`conv_vmem_bytes`.
+    Slabs are counted with Mosaic's (sublane, 128) padding. Pipelined
+    refs (x, w, bias, out) are double-buffered; the accumulator scratch
+    is single-buffered and always 4 bytes/element (fp32, or int32 in the
+    int8 mode), and the tile's matmul result is a temporary of the same
+    size. int8 keeps an fp32 bias and adds the fp32 requantize-scale
+    row — the same asymmetry as :func:`conv_vmem_bytes`.
     """
     dt = _DTYPE_BYTES.get(shape.dtype, 4)
     quantized = shape.dtype == "int8"
     bm, bn, bk = min(bm, shape.m), min(bn, shape.n), min(bk, shape.k)
-    x_t = bm * bk * dt
-    w_t = bk * bn * dt
-    b_t = bn * (4 if quantized else dt)
-    s_t = bn * 4 if quantized else 0
-    o_t = bm * bn * dt
-    acc = bm * bn * 4
-    return 2 * (x_t + w_t + b_t + s_t + o_t) + acc
+    x_t = _tile_bytes(bm, bk, dt)
+    w_t = _tile_bytes(bk, bn, dt)
+    vec = _tile_bytes(1, bn, 4)
+    o_t = _tile_bytes(bm, bn, dt)
+    acc = _tile_bytes(bm, bn, 4)
+    return 2 * (x_t + w_t + vec * (2 if quantized else 1) + o_t) + 2 * acc
 
 
 def gemm_plan_fits(shape: GemmShape, plan: GemmPlan,
@@ -441,13 +475,19 @@ def score_gemm_plan(shape: GemmShape, bm: int, bn: int,
 
 def enumerate_gemm_plans(shape: GemmShape,
                          vmem_budget: int = VMEM_BYTES) -> List[GemmPlan]:
-    """All (bm, bn, bk) points that fit the VMEM budget."""
-    bm_cands = sorted({min(v, shape.m)
-                       for v in _pow2_upto(min(shape.m, 4 * MXU_DIM), lo=8)})
+    """All (bm, bn, bk) points that fit the VMEM budget and lower on
+    the target: ``bn``/``bk`` are lane dims (multiples of 128 or the
+    whole dim), ``bm`` a sublane dim (a multiple of the dtype's sublane
+    tile — 8 fp32, 32 int8 — or the whole dim)."""
+    sub = _sublanes(_DTYPE_BYTES.get(shape.dtype, 4))
+    bm_cands = sorted({min(v, shape.m) for v in
+                       _pow2_upto(min(shape.m, 4 * MXU_DIM), lo=sub)})
     bn_cands = sorted({min(v, shape.n)
-                       for v in _pow2_upto(min(shape.n, 4 * MXU_DIM), lo=64)})
+                       for v in _pow2_upto(min(shape.n, 4 * MXU_DIM),
+                                           lo=LANE)})
     bk_cands = sorted({min(v, shape.k)
-                       for v in _pow2_upto(min(shape.k, 8 * MXU_DIM), lo=64)})
+                       for v in _pow2_upto(min(shape.k, 8 * MXU_DIM),
+                                           lo=LANE)})
     plans = []
     for bm in bm_cands:
         for bn in bn_cands:
@@ -490,7 +530,7 @@ def measure_gemm_plan(shape: GemmShape, plan: GemmPlan, *, iters: int = 3,
 
     from repro.kernels.matmul_pipe import matmul_pipe
 
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     kx, kw = jax.random.split(jax.random.key(_measure_seed(shape, plan)))
     x = jax.random.normal(kx, (shape.m, shape.k), jnp.float32)
     w = jax.random.normal(kw, (shape.k, shape.n), jnp.float32) * 0.1

@@ -21,16 +21,15 @@ Layout: q (B, HKV, G, D); cache (B, S, HKV, D). Causal over `pos`.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from repro.kernels.mode import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,13 +81,15 @@ def _decode_kernel(pos_ref, q_ref, newk_ref, newv_ref, k_ref, v_ref,
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      new_k: jax.Array, new_v: jax.Array, pos: jax.Array, *,
-                     bs: int = 512, interpret: bool = True):
+                     bs: int = 512,
+                     interpret: Optional[bool] = None):
     """Fused decode attention + in-place cache update.
 
     q: (B, HKV, G, D); k/v_cache: (B, S, HKV, D); new_k/v: (B, HKV, D);
     pos: scalar int32. Returns (o (B, HKV, G, D), new_k_cache, new_v_cache).
     On TPU the cache update aliases the input buffers (no copy).
     """
+    interpret = resolve_interpret(interpret)
     B, S, HKV, D = k_cache.shape
     G = q.shape[2]
     bs = min(bs, S)

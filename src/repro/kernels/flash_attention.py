@@ -10,16 +10,15 @@ MHA layout (B, H, S, D); the GQA adaptation lives in ops.py. Causal only.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from repro.kernels.mode import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -62,8 +61,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """Causal MHA. q/k/v (B, H, S, D) -> (B, H, S, D)."""
+    interpret = resolve_interpret(interpret)
     B, H, Sq, D = q.shape
     Sk = k.shape[2]
     bq, bk = min(bq, Sq), min(bk, Sk)

@@ -26,11 +26,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from repro.kernels.mode import resolve_interpret
 
 
 def _matmul_kernel(x_ref, w_ref, b_ref, *refs, n_k: int, relu: bool,
@@ -45,8 +43,10 @@ def _matmul_kernel(x_ref, w_ref, b_ref, *refs, n_k: int, relu: bool,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # fp32 runs at fp32 on the MXU (the default rounds operands to bf16)
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        precision=None if quantized else jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.int32 if quantized else jnp.float32)
 
     @pl.when(k_idx == n_k - 1)
@@ -67,13 +67,18 @@ def matmul_pipe(x: jax.Array, w: jax.Array, b: jax.Array = None, *,
                 scale: Optional[jax.Array] = None,
                 out_scale: Optional[float] = None,
                 relu: bool = False, bm: int = 128, bn: int = 128,
-                bk: int = 128, interpret: bool = True) -> jax.Array:
+                bk: int = 128,
+                interpret: Optional[bool] = None) -> jax.Array:
     """y = relu(x @ w + b). x (M, K); w (K, N); b (N,).
 
     ``scale`` ((N,) fp32) selects the int8 path: x/w int8, int32
     accumulation, requantize epilogue; ``out_scale`` (static float) emits
-    int8 instead of fp32.
+    int8 instead of fp32. Bias and scale ride as (1, BN) lane rows.
+    Compiled (``interpret`` False; None = from the backend), bn/bk must
+    be multiples of 128 or the whole dim and bm a multiple of the
+    dtype's sublane tile or the whole dim.
     """
+    interpret = resolve_interpret(interpret)
     M, K = x.shape
     _, N = w.shape
     quantized = scale is not None
@@ -89,8 +94,8 @@ def matmul_pipe(x: jax.Array, w: jax.Array, b: jax.Array = None, *,
         padw[axis] = (0, blk - rem)
         return jnp.pad(a, padw)
 
-    xp, wp, bp = padto(padto(x, 0, bm), 1, bk), padto(padto(w, 0, bk), 1, bn), \
-        padto(b, 0, bn)
+    xp, wp = padto(padto(x, 0, bm), 1, bk), padto(padto(w, 0, bk), 1, bn)
+    bp = padto(b.reshape(1, N), 1, bn)
     Mp, Kp = xp.shape
     Np = wp.shape[1]
     grid = (Mp // bm, Np // bn, Kp // bk)
@@ -98,12 +103,13 @@ def matmul_pipe(x: jax.Array, w: jax.Array, b: jax.Array = None, *,
     in_specs = [
         pl.BlockSpec((bm, bk), lambda mi, ni, ki: (mi, ki)),
         pl.BlockSpec((bk, bn), lambda mi, ni, ki: (ki, ni)),
-        pl.BlockSpec((bn,), lambda mi, ni, ki: (ni,)),
+        pl.BlockSpec((1, bn), lambda mi, ni, ki: (0, ni)),
     ]
     args = (xp, wp, bp)
     if quantized:
-        in_specs.append(pl.BlockSpec((bn,), lambda mi, ni, ki: (ni,)))
-        args = args + (padto(scale.astype(jnp.float32), 0, bn),)
+        in_specs.append(pl.BlockSpec((1, bn), lambda mi, ni, ki: (0, ni)))
+        args = args + (padto(scale.astype(jnp.float32).reshape(1, N), 1,
+                             bn),)
         out_dtype = jnp.int8 if out_scale is not None else jnp.float32
     else:
         out_dtype = x.dtype
@@ -118,6 +124,8 @@ def matmul_pipe(x: jax.Array, w: jax.Array, b: jax.Array = None, *,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM(
             (bm, bn), jnp.int32 if quantized else jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
     return out[:M, :N]

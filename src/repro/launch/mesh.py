@@ -9,25 +9,12 @@ import jax
 from repro.parallel.sharding import AxisRule
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions.
-
-    ``jax.sharding.AxisType`` (explicit-sharding API) only exists from
-    jax 0.5; Auto is the implicit default before that, so omitting the
-    kwarg on older versions is semantics-preserving.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def rules_for_mesh(mesh, *, seq_shard_batch1: bool = False
@@ -52,4 +39,5 @@ def smoke_mesh(n: int = 1):
     """Tiny mesh over however many devices exist (tests)."""
     dev = len(jax.devices())
     d = min(n, dev)
-    return compat_make_mesh((d, 1), ("data", "model"))
+    return jax.make_mesh((d, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

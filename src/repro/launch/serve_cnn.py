@@ -37,6 +37,9 @@ suffix for Prometheus text format), and ``--report-json`` dumps
 ``FleetReport.to_dict()``. All three are derived from the same counters
 the report prints, so ``repro.obs.validate`` can reconcile them exactly.
 
+On a TPU the Pallas kernels compile with Mosaic; on any other backend
+they run in interpret mode. ``JAX_COMPILATION_CACHE_DIR`` places the
+persistent compilation cache (default ``<checkout>/.jax_cache``).
 Multi-device runs on CPU need forced host devices, e.g.::
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -51,11 +54,13 @@ from __future__ import annotations
 import argparse
 from typing import List
 
+import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core.config import CNNConfig, flops_per_image
 from repro.kernels import autotune
+from repro.kernels.mode import get_interpret
 from repro.pipeline import (ExecutionSpec, Placement, Precision, Serving,
                             Tiling, compile_cnn)
 from repro.serve import (Completion, FaultSchedule,  # noqa: F401
@@ -258,6 +263,8 @@ def main() -> None:
                          "and fusion-group coverage of the compiled "
                          "plan table, and refuse to serve on findings")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not isinstance(cfg, CNNConfig):
@@ -384,6 +391,11 @@ def main() -> None:
           f"{'pallas' if use_pallas else 'xla-ref'} path"
           f"{', int8' if args.quant == 'int8' else ''})")
     print(f"[serve_cnn] {rep.summary()}")
+    if use_pallas:
+        mode = "interpret mode" if get_interpret() else "compiled (Mosaic)"
+        print(f"[serve_cnn] Pallas kernels: {mode} on "
+              f"{jax.devices()[0].device_kind} "
+              f"({jax.default_backend()})")
     print(f"[serve_cnn] aggregate {gops:.2f} GOPS at the reported "
           f"throughput")
     if use_pallas and cfg.autotune:

@@ -58,7 +58,7 @@ class MeasureOptions:
     timed calls averaged), the ``trim`` fastest and slowest samples are
     dropped, and the rest are meaned — the standard guard against both
     one-off stalls and suspiciously-fast outliers. ``interpret=None``
-    resolves to the process backend mode (``ops.get_interpret()``);
+    resolves to the process backend mode (``mode.get_interpret()``);
     the RESOLVED mode is recorded per measurement, because a number
     without its backend is noise.
     """
@@ -70,10 +70,8 @@ class MeasureOptions:
     interpret: Optional[bool] = None
 
     def resolve_interpret(self) -> bool:
-        if self.interpret is None:
-            from repro.kernels import ops
-            return ops.get_interpret()
-        return bool(self.interpret)
+        from repro.kernels.mode import resolve_interpret
+        return resolve_interpret(self.interpret)
 
     def harness(self) -> dict:
         """The JSON view stored in measurement provenance."""
@@ -83,19 +81,26 @@ class MeasureOptions:
 
 def backend_fingerprint(interpret: Optional[bool] = None) -> dict:
     """Where a measurement was taken: platform, device kind, jax
-    version, and the RESOLVED interpret mode. Stored in
+    version, the RESOLVED interpret mode and, for compiled kernels, the
+    device's published peaks. Stored in
     ``provenance["measurement"]["backend"]`` — an interpret-mode number
     compared against a real-TPU number is not drift, it is apples vs
-    oranges, and this dict is how the drift report can tell."""
+    oranges, and this dict is how the drift report can tell. A compiled
+    measurement on a device kind with no entry in
+    ``core.roofline.DEVICE_PEAKS`` raises: its roofline would be priced
+    against another chip."""
     import jax
-    if interpret is None:
-        from repro.kernels import ops
-        interpret = ops.get_interpret()
+
+    from repro.core.roofline import device_peaks
+    from repro.kernels.mode import resolve_interpret
+    interpret = resolve_interpret(interpret)
     dev = jax.devices()[0]
+    kind = getattr(dev, "device_kind", str(dev))
     return {"platform": jax.default_backend(),
-            "device": getattr(dev, "device_kind", str(dev)),
+            "device": kind,
             "jax": jax.__version__,
-            "interpret": bool(interpret),
+            "interpret": interpret,
+            "peaks": None if interpret else dict(device_peaks(kind)),
             "timer": "time.perf_counter"}
 
 

@@ -22,7 +22,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -59,11 +58,11 @@ def ring_matmul_overlapped(x: jax.Array, w: jax.Array, mesh: Mesh,
         out, _ = jax.lax.fori_loop(0, n, step, (out, blk))
         return out.astype(x_loc.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P(None, axis)),
         out_specs=P(None, axis),
-        check_rep=False)(x, w)
+        check_vma=False)(x, w)
 
 
 def sp_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -98,7 +97,7 @@ def sp_decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         o_g = jax.lax.psum(o * corr[..., None], axis)
         return (o_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None, None), P(None, axis, None, None),
                   P(None, axis, None, None), P()),

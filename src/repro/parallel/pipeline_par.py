@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -83,11 +82,11 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x: jax.Array,
         return _gpipe_schedule(lambda h: stage_fn(stage_params, h),
                                micro_local, idx, S, n_microbatches, axis)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),         # params stage-sharded; x replicated
         out_specs=P(),
-        check_rep=False)(params_stacked, micro)
+        check_vma=False)(params_stacked, micro)
     return out.reshape(B, *x.shape[1:])
 
 
@@ -122,6 +121,6 @@ def pipeline_forward_stages(stage_fn: Callable, x: jax.Array, mesh: Mesh,
                                micro_local, idx, S, n_microbatches, axis)
 
     spec = P(None, dp_axis) if dp_axis else P()
-    out = shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                    check_rep=False)(micro)
+    out = jax.shard_map(body, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                    check_vma=False)(micro)
     return out.reshape(B, *x.shape[1:])

@@ -131,6 +131,18 @@ def batch_sharding(mesh: Mesh, shape: Sequence[int],
     return named(mesh, rules or DEFAULT_RULES, shape, *logical)
 
 
+def data_parallel(f, mesh: Mesh, axis: str = "data"):
+    """``f(params, x)`` run once per shard of mesh ``axis``: params
+    replicated, ``x`` and the result split on their leading (batch) dim.
+
+    XLA cannot partition a compiled Pallas kernel (a ``tpu_custom_call``)
+    by itself, so a batch-sharded forward is an explicit ``shard_map``:
+    each device runs the kernels on its own slice of the batch.
+    """
+    return jax.shard_map(f, mesh=mesh, in_specs=(P(), P(axis)),
+                         out_specs=P(axis), check_vma=False)
+
+
 # ---------------------------------------------------------------------------
 # Parameter shardings: leaf-name -> logical axes per dimension
 # ---------------------------------------------------------------------------

@@ -33,8 +33,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.config import CNNConfig
+from repro.core.config import CNNConfig, SpecError
 from repro.kernels import autotune, ops
+from repro.kernels.mode import backend_interprets
 from repro.pipeline.plan_table import PlanTable, load_plan, plan_key
 from repro.pipeline.spec import ExecutionSpec, resolve_config, \
     spec_from_config
@@ -114,6 +115,7 @@ class CompiledCNN:
         from repro.models.cnn import fuse_plan
         self._fuse = fuse_plan(cfg)
         self._fwd = None                   # lazily-jitted single forward
+        self._pp_fwd = None                # lazily-jitted pipeline forward
 
     # -- plumbing ----------------------------------------------------------
 
@@ -160,8 +162,29 @@ class CompiledCNN:
                 run = cnn_forward_stage_quant if quant else cnn_forward_stage
                 return run(p, x, cfg, groups, use_pallas=up, plans=plans)
 
+            if self.spec.placement.replicas > 1 and self.mesh is not None:
+                from repro.parallel.sharding import data_parallel
+                f = data_parallel(f, self.mesh)
             self._fwd = jax.jit(f)
         return self._fwd
+
+    def _pipeline_forward(self):
+        """The jitted GPipe forward over the compiled stage plan (params
+        are arguments, so they are not baked in as constants)."""
+        if self._pp_fwd is None:
+            from repro.serve.engine import pipeline_logits
+            cfg, mesh, sp = self.cfg, self.mesh, self.stage_plan
+            n_micro, up = self.engine.n_micro, self.spec.use_pallas
+            quant = self.quant
+
+            def f(p, x):
+                return pipeline_logits(p, x, cfg, mesh, sp,
+                                       n_microbatches=n_micro,
+                                       use_pallas=up, quant=quant,
+                                       dp_axis="data")
+
+            self._pp_fwd = jax.jit(f)
+        return self._pp_fwd
 
     # -- the run phase -----------------------------------------------------
 
@@ -176,17 +199,22 @@ class CompiledCNN:
         """
         with self._ctx():
             if self.spec.placement.pp_stages > 1:
-                from repro.serve.engine import pipeline_logits
-                return pipeline_logits(
-                    self.params, x, self.cfg, self.mesh, self.stage_plan,
-                    n_microbatches=self.engine.n_micro,
-                    use_pallas=self.spec.use_pallas, quant=self.quant,
-                    dp_axis="data")
+                return self._pipeline_forward()(self.params, x)
             fwd = self._single_forward()
             if self.spec.placement.replicas > 1 and self.mesh is not None:
                 from repro.parallel.sharding import batch_sharding
                 x = jax.device_put(x, batch_sharding(self.mesh, x.shape))
             return fwd(self.params, x)
+
+    def lower(self, x: jax.Array):
+        """The program :meth:`forward` runs, lowered for ``x`` (a
+        ``jax.stages.Lowered``): its text names every kernel — on a TPU
+        each Pallas kernel is a ``tpu_custom_call`` — and ``.compile()``
+        gives the executable. Params are arguments, not constants."""
+        with self._ctx():
+            if self.spec.placement.pp_stages > 1:
+                return self._pipeline_forward().lower(self.params, x)
+            return self._single_forward().lower(self.params, x)
 
     def forward_stage(self, i: int, h: jax.Array) -> jax.Array:
         """Run compiled stage ``i`` on its boundary activation ``h``.
@@ -403,6 +431,12 @@ def compile_cnn(cfg: CNNConfig, spec: Optional[ExecutionSpec] = None,
     from repro.quant.calibrate import QuantizedCNNParams, calibrate_cnn
 
     spec = spec if spec is not None else spec_from_config(cfg)
+    if spec.interpret and not backend_interprets():
+        raise SpecError(
+            "ExecutionSpec.interpret",
+            "interpret=True on a TPU backend would run the kernels in the "
+            "Pallas interpreter on the host and hide the device; leave "
+            "interpret=None to compile them")
     rcfg = resolve_config(cfg, spec)
     quantize = spec.precision.quant == "int8"
 

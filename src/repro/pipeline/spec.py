@@ -114,8 +114,9 @@ class ExecutionSpec:
     placement: Placement = field(default_factory=Placement)
     serving: Serving = field(default_factory=Serving)
     use_pallas: bool = True            # fused kernels vs the XLA reference
-    # None = inherit the process default (ops.get_interpret()); True/False
-    # pins interpret-vs-hardware for everything this compile runs
+    # None = follow the backend (compiled on a TPU, interpreted
+    # elsewhere); True/False pins it for everything this compile runs.
+    # compile_cnn refuses True on a TPU: it would hide the device
     interpret: Optional[bool] = None
 
     def __post_init__(self):

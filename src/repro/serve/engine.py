@@ -76,7 +76,7 @@ from repro.models.cnn import (cnn_forward, cnn_forward_stage,
 from repro.obs.metrics import MetricsRegistry, record_report
 from repro.obs.trace import (CAT_REQUEST, FLEET_TRACK, TraceRecorder)
 from repro.parallel.pipeline_par import pipeline_forward_stages
-from repro.parallel.sharding import batch_sharding
+from repro.parallel.sharding import batch_sharding, data_parallel
 from repro.serve.faults import FaultSchedule
 from repro.serve.report import FleetReport, fleet_report
 from repro.serve.router import Completion, Request, Router
@@ -340,12 +340,16 @@ class ServeEngine:
         if execute and self.scheduler == "gang":
             if R * S > 1:
                 if jax.device_count() < R * S:
+                    hint = ("; on the CPU backend, set XLA_FLAGS="
+                            f"--xla_force_host_platform_device_count={R * S}"
+                            if jax.default_backend() == "cpu" else "")
                     raise RuntimeError(
                         f"{self.mode} mode needs {R * S} devices, have "
-                        f"{jax.device_count()}; set XLA_FLAGS="
-                        f"--xla_force_host_platform_device_count={R * S}")
-                from repro.launch.mesh import compat_make_mesh
-                self.mesh = compat_make_mesh((R, S), ("data", "pipe"))
+                        f"{jax.device_count()} {jax.default_backend()} "
+                        f"device(s){hint}")
+                self.mesh = jax.make_mesh(
+                    (R, S), ("data", "pipe"),
+                    axis_types=(jax.sharding.AxisType.Auto,) * 2)
             self._round_fn = self._round_fns[0] = self._build_round_fn()
         # continuous scheduling needs no mesh: admissions execute as
         # per-replica padded forwards (see _slot_fn), so the fleet can
@@ -388,30 +392,35 @@ class ServeEngine:
         cfg = self.cfg if cfg is None else cfg
         R = self.replicas
 
+        # params are jit ARGUMENTS, never closed over: a closure would
+        # bake every weight into the program as a constant
         if self.pp_stages == 1:
-            def logits_fn(imgs):        # (R*batch, H, W, C)
+            def preds(p, imgs):          # (R*batch, H, W, C)
                 # repro: allow[RPA201] the shim IS the parity oracle here
-                return cnn_forward(params, imgs, cfg,
-                                   use_pallas=self.use_pallas)
-            fn = jax.jit(lambda imgs: jnp.argmax(logits_fn(imgs), -1))
+                return jnp.argmax(cnn_forward(p, imgs, cfg,
+                                              use_pallas=self.use_pallas),
+                                  -1)
             if self.mesh is None:
-                return lambda imgs: fn(imgs)
+                fn = jax.jit(preds)
+                return lambda imgs: fn(params, imgs)
+            fn = jax.jit(data_parallel(preds, self.mesh))
 
             def dp_round(imgs):
                 sharded = jax.device_put(
                     imgs, batch_sharding(self.mesh, imgs.shape))
-                return fn(sharded)
+                return fn(params, sharded)
             return dp_round
 
         sp = self.stage_plan if stage_plan is None else stage_plan
 
-        def pp_fn(imgs_flat):           # (n_micro*R*mb, H, W, C)
+        def pp_fn(p, imgs_flat):        # (n_micro*R*mb, H, W, C)
             logits = pipeline_logits(
-                params, imgs_flat, cfg, self.mesh, sp,
+                p, imgs_flat, cfg, self.mesh, sp,
                 n_microbatches=self.n_micro, use_pallas=self.use_pallas,
                 quant=quant, dp_axis="data")
             return jnp.argmax(logits, -1)
-        return jax.jit(pp_fn)
+        pp = jax.jit(pp_fn)
+        return lambda imgs: pp(params, imgs)
 
     def _slot_fn(self, v: int):
         """Padded single-replica forward ``imgs (batch, ...) -> preds``
@@ -424,12 +433,13 @@ class ServeEngine:
             rec = self._versions[v]
             params, cfg = rec["params"], rec["cfg"]
 
-            def fn(imgs, params=params, cfg=cfg):
+            def fn(p, imgs):
                 # repro: allow[RPA201] the shim IS the parity oracle here
-                logits = cnn_forward(params, imgs, cfg,
+                logits = cnn_forward(p, imgs, cfg,
                                      use_pallas=self.use_pallas)
                 return jnp.argmax(logits, -1)
-            self._slot_fns[v] = jax.jit(fn)
+            jitted = jax.jit(fn)
+            self._slot_fns[v] = lambda imgs: jitted(params, imgs)
         return self._slot_fns[v]
 
     def _version_fn(self, v: int):

@@ -109,7 +109,6 @@ OPS_SURFACE = {
     "get_interpret",
     "interpret_mode",
     "lrn",
-    "set_interpret",
 }
 
 

@@ -1,6 +1,6 @@
 """Spatial tiling, batch folding, grouped conv and the DSE autotuner.
 
-Covers the H-tiled conv_pipe (halo'd input tiles via unblocked indexing)
+Covers the H-tiled conv_pipe (halo'd input tiles via element-indexed H)
 against the oracle across tile sizes that do and don't divide OH, strides,
 pool windows straddling tile boundaries, and AlexNet's two-tower grouped
 convs — plus the batch-folded grid (b_blk images per grid step, the
@@ -309,8 +309,8 @@ def test_tuned_plan_runs_and_matches_oracle():
     """End to end: tune a smoke-scale layer, run conv_pipe with the plan."""
     s = autotune.ConvShape(h=19, w=19, c=6, kh=3, kw=3, m=16, pad=1,
                            pool="max", pool_k=3, pool_s=2)
-    plan = autotune.best_plan(s, vmem_budget=256 * 1024)  # force tiling
-    assert plan.vmem_bytes <= 256 * 1024
+    plan = autotune.best_plan(s, vmem_budget=768 * 1024)  # force tiling
+    assert plan.vmem_bytes <= 768 * 1024
     _check(1, 19, 6, 3, 16, pad=1, pool="max", pool_k=3, pool_s=2,
            oh_blk=plan.oh_blk, c_blk=plan.c_blk, m_blk=plan.m_blk)
 

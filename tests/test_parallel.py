@@ -71,7 +71,10 @@ def run_in_mesh_subprocess(body: str):
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     code = _SUBPROC_TEMPLATE.format(src=os.path.abspath(src),
                                     body=textwrap.dedent(body))
-    res = subprocess.run([sys.executable, "-c", code],
+    # the child forces 8 host devices, so it must run on the CPU backend
+    # whatever the parent's environment says
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    res = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-3000:]
     assert "SUBPROC_OK" in res.stdout
@@ -80,8 +83,8 @@ def run_in_mesh_subprocess(body: str):
 def test_sp_decode_attention_matches_reference():
     run_in_mesh_subprocess("""
         from repro.parallel.collectives import sp_decode_attention
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         B, H, D, S = 2, 4, 16, 32
         key = jax.random.key(0)
         q = jax.random.normal(key, (B, H, D))
@@ -102,8 +105,8 @@ def test_sp_decode_attention_matches_reference():
 def test_ring_matmul_overlapped_matches_dot():
     run_in_mesh_subprocess("""
         from repro.parallel.collectives import ring_matmul_overlapped
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((1, 8), ("data", "model"))
+        mesh = jax.make_mesh((1, 8), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         M, K, N = 64, 32, 80
         x = jax.random.normal(jax.random.key(0), (M, K))
         w = jax.random.normal(jax.random.key(1), (K, N))
@@ -116,8 +119,8 @@ def test_ring_matmul_overlapped_matches_dot():
 def test_pipeline_parallel_forward_matches_sequential():
     run_in_mesh_subprocess("""
         from repro.parallel.pipeline_par import pipeline_forward
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((8,), ("pod",))
+        mesh = jax.make_mesh((8,), ("pod",),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 1)
         S_stages, B, D = 8, 16, 32
         ws = jax.random.normal(jax.random.key(0), (S_stages, D, D)) * 0.2
         x = jax.random.normal(jax.random.key(1), (B, D))
@@ -140,7 +143,6 @@ def test_pipeline_forward_stages_heterogeneous_matches_sequential():
     dp_axis: both must reproduce the sequential forward."""
     run_in_mesh_subprocess("""
         from repro.parallel.pipeline_par import pipeline_forward_stages
-        from repro.launch.mesh import compat_make_mesh
         S, B, D = 4, 16, 8
         widths = [D, 12, 6, 10, D]          # heterogeneous stage widths
         maxw = max(widths)
@@ -161,7 +163,8 @@ def test_pipeline_forward_stages_heterogeneous_matches_sequential():
             want = jnp.tanh(want @ w)
         xpad = jnp.pad(x, ((0, 0), (0, maxw - D)))
         for mesh_shape, dp in (((1, 4), None), ((2, 4), "data")):
-            mesh = compat_make_mesh(mesh_shape, ("data", "pipe"))
+            mesh = jax.make_mesh(mesh_shape, ("data", "pipe"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             got = pipeline_forward_stages(stage_fn, xpad, mesh,
                                           axis="pipe", n_microbatches=4,
                                           dp_axis=dp)
@@ -192,9 +195,8 @@ def test_sharded_train_step_matches_single_device():
         state = init_train_state(key, cfg)
         _, m0 = jax.jit(lambda s, b: train_step(s, b, cfg))(state, batch)
         loss0 = float(m0["loss"])
-
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with sharding_ctx(mesh, DEFAULT_RULES):
             pshard = param_shardings(mesh, DEFAULT_RULES, state.params)
             state_sh = state._replace(

@@ -375,11 +375,11 @@ def test_artifact_uncommitted_or_corrupt_raises(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# interpret_mode (satellite: the scoped replacement for set_interpret)
+# interpret_mode: the scoped override of the backend-chosen mode
 # ---------------------------------------------------------------------------
 
 def test_interpret_mode_scopes_and_restores():
-    assert ops.get_interpret() is True
+    assert ops.get_interpret() is True          # the CPU backend interprets
     with ops.interpret_mode(False):
         assert ops.get_interpret() is False
         with ops.interpret_mode(True):
@@ -395,16 +395,9 @@ def test_interpret_mode_restores_on_exception():
     assert ops.get_interpret() is True
 
 
-def test_set_interpret_shim_still_works():
-    ops.set_interpret(False)
-    assert ops.get_interpret() is False
-    ops.set_interpret(True)
-    assert ops.get_interpret() is True
-
-
 def test_compiled_threads_interpret_through_forward():
     """A spec pinning interpret=True runs inside interpret_mode — the
-    compile's choice, not the process global, governs the run."""
+    compile's choice, not the surrounding scope, governs the run."""
     cfg, params, x = _setup()
     spec = ExecutionSpec(serving=Serving(batch=4), use_pallas=False,
                          interpret=True)
@@ -418,6 +411,32 @@ def test_compiled_threads_interpret_through_forward():
         seen.append(ops.get_interpret())
     assert seen == [True, False]
     assert ops.get_interpret() is True
+
+
+def test_interpret_mode_is_part_of_the_wrapper_jit_key():
+    """A trace taken in one mode is never reused in the other: each mode
+    resolves at call time and keys a jit cache entry of its own."""
+    x = jnp.ones((1, 4, 4, 8), jnp.float32)
+    w = jnp.ones((3, 3, 8, 8), jnp.float32) * 0.1
+    b = jnp.zeros((8,), jnp.float32)
+    ops.fused_conv(x, w, b, pad=1, use_pallas=True)
+    with ops.interpret_mode(False):
+        # compiled Mosaic kernels cannot run on the CPU backend: reusing
+        # the interpret-mode trace would silently succeed here instead
+        with pytest.raises(Exception):
+            jax.block_until_ready(
+                ops.fused_conv(x, w, b, pad=1, use_pallas=True))
+
+
+def test_interpret_true_on_tpu_backend_is_a_spec_error(monkeypatch):
+    """On a TPU an explicit interpret=True would hide the device."""
+    import repro.pipeline.compile as compile_mod
+    from repro.core.config import SpecError
+    cfg, params, _ = _setup()
+    monkeypatch.setattr(compile_mod, "backend_interprets", lambda: False)
+    with pytest.raises(SpecError, match="interpret"):
+        compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=4),
+                                       interpret=True), params)
 
 
 # ---------------------------------------------------------------------------

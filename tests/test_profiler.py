@@ -82,18 +82,30 @@ def test_measure_gemm_plan_both_dtypes(dtype):
 
 
 def test_interpret_default_is_backend_aware():
-    """interpret=None resolves from ops.get_interpret() — measuring the
-    interpreter while the pipeline runs compiled (or vice versa) would
-    be silently meaningless."""
+    """interpret=None resolves from the backend-chosen mode — measuring
+    the interpreter while the pipeline runs compiled (or vice versa)
+    would be silently meaningless."""
+    from repro.kernels.mode import resolve_interpret
     with ops.interpret_mode(True):
-        assert autotune._resolve_interpret(None) is True
+        assert resolve_interpret(None) is True
         assert MeasureOptions().resolve_interpret() is True
         assert backend_fingerprint()["interpret"] is True
     # explicit values win regardless of process mode
     with ops.interpret_mode(True):
-        assert autotune._resolve_interpret(False) is False
+        assert resolve_interpret(False) is False
     fp = backend_fingerprint(True)
     assert {"platform", "device", "jax", "interpret", "timer"} <= set(fp)
+    assert fp["peaks"] is None          # interpreted: no device roofline
+
+
+def test_compiled_fingerprint_refuses_unknown_device_kind():
+    """A compiled measurement is priced against its device's published
+    peaks; a device kind missing from the table is an error."""
+    from repro.core.roofline import DEVICE_PEAKS, TARGET_DEVICE_KIND
+    assert TARGET_DEVICE_KIND == "TPU v5 lite"
+    assert DEVICE_PEAKS[TARGET_DEVICE_KIND]["bf16_flops"] == 197e12
+    with pytest.raises(KeyError, match="no published peaks"):
+        backend_fingerprint(False)     # this backend's kind is not a TPU
 
 
 def test_measure_record_cache_counts_hits():

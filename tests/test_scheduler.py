@@ -181,9 +181,10 @@ def test_fail_recover_chaos_all_accounted():
     tr = total_cost(CFG, 4)
     eng = _engine(batch=4, replicas=2, retries=2)
     chaos = FaultSchedule.at(tr * 1.5, tr * 3.0, replica=0)
-    done, rep = eng.serve([_req(i, t=i * tr / 16) for i in range(48)],
+    # enough requests that the stream outlasts the constant restore
+    done, rep = eng.serve([_req(i, t=i * tr / 16) for i in range(144)],
                           faults=chaos)
-    assert sorted(c.rid for c in done) == list(range(48))
+    assert sorted(c.rid for c in done) == list(range(144))
     assert rep.n_failures == 1 and rep.n_recoveries == 1
     assert all(c.status == "ok" for c in done)
 

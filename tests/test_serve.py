@@ -285,7 +285,6 @@ def test_pipeline_stages_cnn_fp32_matches_unsharded():
     run_in_mesh_subprocess("""
         from repro.configs import get_config
         from repro.models.cnn import cnn_forward, init_cnn_params
-        from repro.launch.mesh import compat_make_mesh
         from repro.serve import plan_stages, pipeline_logits
         cfg = get_config('alexnet').smoke()
         key = jax.random.key(3)
@@ -295,7 +294,8 @@ def test_pipeline_stages_cnn_fp32_matches_unsharded():
         want = np.asarray(cnn_forward(params, x, cfg, use_pallas=True))
         # pure pipeline (1x4) and hybrid (2x4) must both match
         for dp, mb_n in ((1, 4), (2, 2)):
-            mesh = compat_make_mesh((dp, 4), ('data', 'pipe'))
+            mesh = jax.make_mesh((dp, 4), ('data', 'pipe'),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             sp = plan_stages(cfg, 4, batch=8 // (mb_n * dp))
             got = pipeline_logits(params, x, cfg, mesh, sp,
                                   n_microbatches=mb_n, use_pallas=True,
@@ -312,7 +312,6 @@ def test_pipeline_stages_cnn_int8_bit_exact():
     run_in_mesh_subprocess("""
         from repro.configs import get_config
         from repro.models.cnn import cnn_forward, init_cnn_params
-        from repro.launch.mesh import compat_make_mesh
         from repro.quant import calibrate_cnn
         from repro.serve import plan_stages, pipeline_logits
         cfg = get_config('alexnet').smoke()
@@ -322,7 +321,8 @@ def test_pipeline_stages_cnn_int8_bit_exact():
                                     cfg.input_ch), jnp.float32)
         qp = calibrate_cnn(params, x, cfg)
         want = np.asarray(cnn_forward(qp, x, cfg, use_pallas=True))
-        mesh = compat_make_mesh((1, 4), ('data', 'pipe'))
+        mesh = jax.make_mesh((1, 4), ('data', 'pipe'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         sp = plan_stages(cfg, 4, batch=2, dtype='int8')
         got = pipeline_logits(qp, x, cfg, mesh, sp, n_microbatches=4,
                               use_pallas=True, quant=True, dp_axis='data')
@@ -408,8 +408,9 @@ def test_engine_fail_then_recover_charges_restore_latency():
     from repro.serve.engine import RESTORE_OVERHEAD_S
     t_round = total_cost(get_config("alexnet"), 8)
     fs = FaultSchedule.at(t_round * 0.5, t_round * 2.5, replica=1)
-    eng, done, rep = _chaos_sim(200, faults=fs, retries=3)
-    assert _terminal_rids(done) == list(range(200))
+    # enough rounds that the stream outlasts the constant restore overhead
+    eng, done, rep = _chaos_sim(400, faults=fs, retries=3)
+    assert _terminal_rids(done) == list(range(400))
     assert rep.n_failures == 1 and rep.n_recoveries == 1
     assert len(rep.time_to_recover_s) == 1
     # TTR = (recover_at - fail_at) + modeled artifact restore (params
@@ -478,10 +479,11 @@ def test_engine_mtbf_chaos_deterministic_and_terminal():
 
 
 def test_hot_swap_rolls_every_replica_and_drops_nothing():
-    eng, done, rep = _chaos_sim(200, swap_to=[],
+    # enough rounds that the stream outlasts four per-replica restores
+    eng, done, rep = _chaos_sim(400, swap_to=[],
                                 swap_at=total_cost(get_config("alexnet"),
                                                    8) * 0.5)
-    assert _terminal_rids(done) == list(range(200))
+    assert _terminal_rids(done) == list(range(400))
     assert all(c.status == "ok" for c in done), \
         "a graceful rolling swap must never drop a request"
     assert rep.n_swapped == 4 and rep.n_failed == 0
