@@ -7,7 +7,10 @@ into the kernel layer lazily (only when a measurement actually runs):
   * :mod:`repro.obs.trace` — :class:`TraceRecorder`, Chrome trace-event
     JSON export (Perfetto-viewable), byte-deterministic on the modeled
     clock; since PR 9 it also carries compile-phase ``sweep``/
-    ``measure`` spans on the ``compile`` track;
+    ``measure`` spans on the ``compile`` track; and :data:`SPANS`, the
+    process's :class:`~repro.obs.trace.SpanLog` of wall-clock spans of
+    real forwards (``cnn.*``) and garbage collections (``py.gc``), on
+    by default (``REPRO_SPANS=0`` or :func:`set_spans` switch it off);
   * :mod:`repro.obs.metrics` — :class:`MetricsRegistry` of counters /
     gauges / histograms / windows, JSON + Prometheus text exports;
   * :mod:`repro.obs.profiler` — the measured-refinement harness
@@ -28,9 +31,15 @@ from .trace import (  # noqa: F401
     CAT_FLEET,
     CAT_REQUEST,
     CAT_ROUND,
+    CAT_WALL,
     COMPILE_TRACK,
     FLEET_TRACK,
+    SPANS,
+    WALL_TRACK,
+    SpanLog,
     TraceRecorder,
+    now_ns,
+    set_spans,
 )
 from .metrics import (  # noqa: F401
     DEFAULT_LATENCY_BUCKETS,
@@ -71,6 +80,12 @@ __all__ = [
     "CAT_COMPILE",
     "FLEET_TRACK",
     "COMPILE_TRACK",
+    "CAT_WALL",
+    "WALL_TRACK",
+    "SpanLog",
+    "SPANS",
+    "set_spans",
+    "now_ns",
     "MetricsRegistry",
     "Counter",
     "Gauge",

@@ -47,20 +47,55 @@ phase (PR 9): ``compile_cnn(..., trace=...)`` emits a ``sweep`` span
 over its DSE-resolve block and, with ``measure=True``, one ``measure``
 span per profiled plan — so one Perfetto view shows where compile time
 went before the first request span begins.
+
+Wall-clock spans of real runs
+-----------------------------
+
+Everything above runs on the modeled clock. :data:`SPANS`, a
+:class:`SpanLog`, records what the process really did, on the host's
+``time.perf_counter_ns`` clock (read in :func:`now_ns` and nowhere
+else): ``(name, start_ns, end_ns, span_id, parent_id, args)`` tuples in
+a bounded buffer that drops its oldest spans when full and counts them.
+It is on by default, so it must stay cheap: a span is one tuple
+appended, and with the log off a call costs one branch and records
+nothing. ``REPRO_SPANS=0`` in the environment at import, or
+:func:`set_spans`, switches it off. :meth:`SpanLog.read` returns the
+spans and counters; :meth:`SpanLog.export` writes them through a
+:class:`TraceRecorder`, one ``wall`` track, for Perfetto.
+
+  =============  =====  ===============================================
+  name           kind   meaning
+  =============  =====  ===============================================
+  cnn.forward    span   one ``CompiledCNN.forward``: batch, images, bytes
+  cnn.h2d        span   the host's time in the batch's copy call
+  cnn.dispatch   span   the jitted call, up to its return (not-ready)
+  cnn.retrace    inst.  + counter: ``jax.jit`` traced the forward
+  py.gc          span   one generation-1 or -2 collection
+  py.gc.gen0     count  generation-0 collections (counted only)
+  =============  =====  ===============================================
+
+An instant is a span whose end equals its start.
 """
 from __future__ import annotations
 
+import gc
+import itertools
 import json
-from typing import Dict, List, Optional
+import os
+import time
+from collections import deque
+from typing import Any, Dict, List, Optional
 
 # Event categories (the "cat" field): filterable lanes in Perfetto.
 CAT_REQUEST = "request"        # per-request lifecycle events
 CAT_ROUND = "round"            # gang-round execution spans
 CAT_FLEET = "fleet"            # fleet mutations (faults, swaps, scaling)
 CAT_COMPILE = "compile"        # compile-phase spans (DSE sweep, measure)
+CAT_WALL = "wall"              # wall-clock spans of real runs (SpanLog)
 
 FLEET_TRACK = "fleet"          # the non-replica instant track
 COMPILE_TRACK = "compile"      # the compile-phase span track
+WALL_TRACK = "wall"            # the exported SpanLog's track
 
 
 class TraceRecorder:
@@ -166,3 +201,137 @@ class TraceRecorder:
         with open(path, "w") as f:
             f.write(self.to_json())
         return path
+
+
+# -- wall-clock spans --------------------------------------------------------
+
+SPANS_ENV = "REPRO_SPANS"      # "0" at import switches SPANS off
+SPAN_CAPACITY = 1 << 16        # spans kept before the oldest are dropped
+
+Args = Optional[tuple]         # flat: ("key", value, "key", value, ...)
+
+
+def now_ns() -> int:
+    """The host clock every wall-clock span reads, in ns."""
+    # repro: allow[RPA102] wall-clock spans time the real run path
+    return time.perf_counter_ns()
+
+
+def _as_dict(args: Args) -> Optional[dict]:
+    return None if args is None else dict(zip(args[::2], args[1::2]))
+
+
+class SpanLog:
+    """Wall-clock spans and counters of real runs, in a bounded buffer.
+
+    A span is ``(name, start_ns, end_ns, span_id, parent_id, args)``;
+    ids count from 1, and a parent id of 0 means none. ``args`` is given
+    flat, ``("key", value, ...)``, and read back as a dict: a tuple of
+    numbers and strings is one allocation, and the garbage collector
+    stops tracking it, so a full buffer adds nothing to a collection's
+    work. When ``capacity`` spans are held, each new span drops the
+    oldest and ``dropped`` counts it. Counters are plain name -> int.
+    """
+
+    def __init__(self, capacity: int = SPAN_CAPACITY, enabled: bool = True):
+        self.capacity = capacity
+        self.enabled = enabled
+        self.dropped = 0
+        self.counters: Dict[str, int] = {}
+        self._spans: deque = deque(maxlen=capacity)
+        self._ids = itertools.count(1)
+
+    def record(self, name: str, t0: int, t1: int, parent: int = 0,
+               args: Args = None) -> int:
+        """Keep a finished span [t0, t1] (:func:`now_ns` readings);
+        returns its id, 0 with the log off."""
+        if not self.enabled:
+            return 0
+        sid = next(self._ids)
+        if len(self._spans) == self.capacity:
+            self.dropped += 1
+        self._spans.append((name, t0, t1, sid, parent, args))
+        return sid
+
+    def instant(self, name: str, args: Args = None) -> int:
+        """A span whose end is its start."""
+        if not self.enabled:
+            return 0
+        t = now_ns()
+        return self.record(name, t, t, 0, args)
+
+    def count(self, name: str) -> None:
+        if self.enabled:
+            self.counters[name] = self.counters.get(name, 0) + 1
+
+    def read(self) -> Dict[str, Any]:
+        """``{"spans": [...], "counters": {...}, "dropped": n}``, spans
+        in the order they ended, each with its args as a dict."""
+        # copy first: a collection while the dicts are made records a
+        # py.gc span into the buffer
+        spans = list(self._spans)
+        return {"spans": [(n, t0, t1, sid, parent, _as_dict(args))
+                          for n, t0, t1, sid, parent, args in spans],
+                "counters": dict(self.counters), "dropped": self.dropped}
+
+    def clear(self) -> None:
+        self._spans.clear()
+        self.counters.clear()
+        self.dropped = 0
+
+    def export(self) -> TraceRecorder:
+        """The spans as Chrome trace events on the ``wall`` track
+        (instants as ``ph: "i"``), in seconds from the first span's
+        start, with the span and parent ids in each event's ``args``;
+        the counters, the dropped count and the origin
+        (``perf_counter_ns``) go under ``otherData``."""
+        rec = TraceRecorder("repro.wall")
+        got = self.read()
+        origin = min((s[1] for s in got["spans"]), default=0)
+        for name, t0, t1, sid, parent, args in got["spans"]:
+            a = {"id": sid, "parent": parent, **(args or {})}
+            if t1 == t0:
+                rec.instant(name, (t0 - origin) * 1e-9, track=WALL_TRACK,
+                            cat=CAT_WALL, args=a)
+            else:
+                rec.span(name, (t0 - origin) * 1e-9, (t1 - origin) * 1e-9,
+                         track=WALL_TRACK, cat=CAT_WALL, args=a)
+        rec.set_meta("wall_origin_ns", origin)
+        rec.set_meta("wall_counters", got["counters"])
+        rec.set_meta("wall_dropped", got["dropped"])
+        return rec
+
+
+SPANS = SpanLog(enabled=os.environ.get(SPANS_ENV, "1") != "0")
+
+_gc_t0 = 0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: a ``py.gc`` span per generation-1/2
+    collection, a ``py.gc.gen0`` count per generation-0 one."""
+    global _gc_t0
+    gen = info["generation"]
+    if gen == 0:
+        if phase == "stop":
+            SPANS.count("py.gc.gen0")
+    elif phase == "start":
+        _gc_t0 = now_ns()
+    else:
+        SPANS.record("py.gc", _gc_t0, now_ns(), 0,
+                     ("generation", gen, "collected", info["collected"]))
+
+
+def set_spans(on: bool) -> bool:
+    """Switch :data:`SPANS` (and its garbage-collector hook) on or off;
+    returns the previous setting."""
+    was = SPANS.enabled
+    SPANS.enabled = bool(on)
+    if on and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not on and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+    return was
+
+
+set_spans(SPANS.enabled)

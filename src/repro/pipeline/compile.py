@@ -27,6 +27,7 @@ shims delegating here.
 from __future__ import annotations
 
 import contextlib
+import itertools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -36,6 +37,7 @@ import numpy as np
 from repro.core.config import CNNConfig, SpecError
 from repro.kernels import autotune, ops
 from repro.kernels.mode import backend_interprets
+from repro.obs.trace import SPANS, now_ns
 from repro.pipeline.plan_table import PlanTable, load_plan, plan_key
 from repro.pipeline.spec import ExecutionSpec, resolve_config, \
     spec_from_config
@@ -66,6 +68,17 @@ def _group_shapes(cfg: CNNConfig, batch: int, dtype: str):
                 k *= d
             yield group, "gemm", autotune.GemmShape(
                 m=batch, k=k, n=out_shape[-1], dtype=dtype)
+
+
+def _note_trace(x, placement: str) -> None:
+    """Mark (``cnn.retrace`` instant) and count one trace of a jitted
+    forward. It runs while ``jax.jit`` traces, so once for each input
+    shape, dtype or placement not traced before, the first included; a
+    ``lower()`` at a shape not yet traced counts too."""
+    SPANS.instant("cnn.retrace", ("shape", tuple(x.shape),
+                                  "dtype", str(x.dtype),
+                                  "placement", placement))
+    SPANS.count("cnn.retrace")
 
 
 def _resolve_group_plans(cfg: CNNConfig, batch: int,
@@ -116,6 +129,7 @@ class CompiledCNN:
         self._fuse = fuse_plan(cfg)
         self._fwd = None                   # lazily-jitted single forward
         self._pp_fwd = None                # lazily-jitted pipeline forward
+        self._batches = itertools.count()  # forward's batch ids
 
     # -- plumbing ----------------------------------------------------------
 
@@ -156,15 +170,20 @@ class CompiledCNN:
             from repro.models.cnn import (cnn_forward_stage,
                                           cnn_forward_stage_quant)
             cfg, groups, plans = self.cfg, self._fuse, self.group_plans
-            up, quant = self.spec.use_pallas, self.quant
+            up, quant, mode = self.spec.use_pallas, self.quant, self.mode
 
-            def f(p, x):
+            def fold(p, x):
                 run = cnn_forward_stage_quant if quant else cnn_forward_stage
                 return run(p, x, cfg, groups, use_pallas=up, plans=plans)
 
             if self.spec.placement.replicas > 1 and self.mesh is not None:
                 from repro.parallel.sharding import data_parallel
-                f = data_parallel(f, self.mesh)
+                fold = data_parallel(fold, self.mesh)
+
+            def f(p, x):
+                _note_trace(x, mode)
+                return fold(p, x)
+
             self._fwd = jax.jit(f)
         return self._fwd
 
@@ -175,9 +194,10 @@ class CompiledCNN:
             from repro.serve.engine import pipeline_logits
             cfg, mesh, sp = self.cfg, self.mesh, self.stage_plan
             n_micro, up = self.engine.n_micro, self.spec.use_pallas
-            quant = self.quant
+            quant, mode = self.quant, self.mode
 
             def f(p, x):
+                _note_trace(x, mode)
                 return pipeline_logits(p, x, cfg, mesh, sp,
                                        n_microbatches=n_micro,
                                        use_pallas=up, quant=quant,
@@ -196,15 +216,55 @@ class CompiledCNN:
         device-resident stages (pp/hybrid; B must divide into the
         compiled microbatch grid). Numerics are placement-independent:
         fp32 allclose / int8 bit-exact vs the unsharded fold.
+
+        With :data:`repro.obs.SPANS` on (the default) a call records a
+        ``cnn.forward`` span (args ``batch``, this object's count of
+        calls; ``images``; ``bytes``) over two children: ``cnn.h2d``,
+        the host's time in the ``jax.device_put`` of ``x`` to the
+        compiled input placement (the call, not the copy's completion
+        on the device), and ``cnn.dispatch``, the jitted call up to its
+        return of logits that may not be ready yet. Recording waits for
+        nothing on the device.
         """
+        if not SPANS.enabled:
+            with self._ctx():
+                fwd, where = self._entry(x)
+                return fwd(self.params, jax.device_put(x, where))
+        t0 = now_ns()
         with self._ctx():
-            if self.spec.placement.pp_stages > 1:
-                return self._pipeline_forward()(self.params, x)
-            fwd = self._single_forward()
-            if self.spec.placement.replicas > 1 and self.mesh is not None:
-                from repro.parallel.sharding import batch_sharding
-                x = jax.device_put(x, batch_sharding(self.mesh, x.shape))
-            return fwd(self.params, x)
+            fwd, where = self._entry(x)
+            t1 = now_ns()
+            x_dev = jax.device_put(x, where)
+            t2 = now_ns()
+            out = fwd(self.params, x_dev)
+            t3 = now_ns()
+        self._record_forward(x, where, t0, t1, t2, t3)
+        return out
+
+    def _entry(self, x):
+        """The jitted forward of the compiled placement, and where its
+        input goes: the batch sharding over the mesh under dp, else
+        None (the default device, where the jitted call would put it)."""
+        if self.spec.placement.pp_stages > 1:
+            return self._pipeline_forward(), None
+        if self.spec.placement.replicas > 1 and self.mesh is not None:
+            from repro.parallel.sharding import batch_sharding
+            return self._single_forward(), batch_sharding(self.mesh,
+                                                          x.shape)
+        return self._single_forward(), None
+
+    def _record_forward(self, x, where, t0: int, t1: int, t2: int,
+                        t3: int) -> None:
+        """One forward's spans from its four clock readings: entry,
+        copy call, jitted call, return."""
+        nbytes = x.nbytes
+        top = SPANS.record("cnn.forward", t0, t3, 0,
+                           ("batch", next(self._batches),
+                            "images", x.shape[0], "bytes", nbytes))
+        SPANS.record("cnn.h2d", t1, t2, top,
+                     ("bytes", nbytes, "devices", 1 if where is None
+                      else self.mesh.devices.size))
+        SPANS.record("cnn.dispatch", t2, t3, top)
 
     def lower(self, x: jax.Array):
         """The program :meth:`forward` runs, lowered for ``x`` (a

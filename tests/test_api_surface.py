@@ -38,6 +38,13 @@ OBS_SURFACE = {
     "CAT_COMPILE",
     "FLEET_TRACK",
     "COMPILE_TRACK",
+    # wall-clock spans of real forwards and GC pauses
+    "CAT_WALL",
+    "WALL_TRACK",
+    "SpanLog",
+    "SPANS",
+    "set_spans",
+    "now_ns",
     "MetricsRegistry",
     "Counter",
     "Gauge",

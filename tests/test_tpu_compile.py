@@ -177,3 +177,32 @@ def test_tuned_alexnet_plans_compile(sds, dtype):
             _assert_kernel(f, *args)
             n_fc += 1
     assert (n_conv, n_fc) == (5, 3)
+
+
+def test_whole_alexnet_forward_names_its_kernels(topo, sds):
+    """``CompiledCNN.lower`` of the whole AlexNet forward (with the span
+    log on, as it is by default): every compiled Pallas kernel is an
+    instruction named after its jitted wrapper, ``fused_conv``, ``fc`` or
+    ``lrn``, the families the benchmark's kernel rooflines read from
+    the device trace."""
+    import re
+
+    from repro.models.cnn import init_cnn_params
+    from repro.obs import SPANS
+    from repro.pipeline import ExecutionSpec, Serving, compile_cnn
+
+    assert SPANS.enabled
+    cfg = get_config("alexnet")
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: init_cnn_params(jax.random.key(0), cfg)))
+    compiled = compile_cnn(cfg, ExecutionSpec(
+        interpret=False, serving=Serving(batch=BATCH)), params,
+        with_engine=False)
+    txt = compiled.lower(sds((BATCH, cfg.input_hw, cfg.input_hw,
+                              cfg.input_ch), jnp.float32)).compile().as_text()
+    kernels = [re.match(r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=", line).group(1)
+               for line in txt.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    families = sorted({k.split(".")[0] for k in kernels})
+    assert families == ["fc", "fused_conv", "lrn"], kernels
+    assert len(kernels) == 10, kernels     # 5 conv groups, 2 LRNs, 3 FCs
