@@ -44,7 +44,8 @@ from typing import Dict, Iterator, List, Optional, Tuple
 from repro.core.roofline import (MXU_DIM, VMEM_BYTES, mxu_utilization,
                                  time_bounds)
 from repro.kernels.conv_pipe import (LANE, SUBLANE, _round_up,
-                                     conv_tile_geometry, s2d_geometry)
+                                     contraction_block, conv_tile_geometry,
+                                     s2d_geometry)
 from repro.kernels.mode import resolve_interpret
 
 _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
@@ -137,10 +138,11 @@ class ConvPlan:
 def _conv_geometry(shape: ConvShape, c_blk: int, m_blk: int,
                    oh_blk: int, b_blk: int):
     """The kernel's real geometry for one plan: the space-to-depth conv
-    (stride 1, ``s*s*C/G`` channels), clamped blocks and the H tiling."""
+    (stride 1, ``s*s*C/G`` channels, times ``kw`` where the column taps
+    fold), clamped blocks and the H tiling."""
     g = s2d_geometry(shape.h, shape.w, shape.c // shape.groups, shape.kh,
                      shape.kw, stride=shape.stride, pad=shape.pad)
-    c_blk = min(c_blk, g.c)
+    c_blk = contraction_block(c_blk, g)
     m_blk = min(m_blk, shape.m // shape.groups)
     b_blk = max(1, min(b_blk, shape.b))
     tiles = conv_tile_geometry(g.oh, oh_blk, stride=1, kh=g.kh,
@@ -157,12 +159,14 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
 
     Every slab is counted as Mosaic lays it out: minor dim padded to 128
     lanes, second-minor to the dtype's sublane tile (AlexNet conv1's 48
-    space-to-depth channels occupy 128 lanes). Pipelined refs (x tile,
-    w tile, bias, out tile) are double-buffered by Pallas (factor 2); the
-    accumulator scratch (and the pool scratch, when pooling) is single-
-    buffered and always 4 bytes/element. The kernel body's temporaries
-    are counted too: the column-shifted x window, the folded im2col
-    patch, one tap's matmul result and the fp32 epilogue value. The x
+    space-to-depth channels occupy 128 lanes, its 144 folded ones 256).
+    Pipelined refs (x tile, w tile, bias, out tile) are double-buffered by
+    Pallas (factor 2); the accumulator scratch (and the pool scratch, when
+    pooling) is single-buffered and always 4 bytes/element. The kernel
+    body's temporaries are counted too: the column-shifted x window (for
+    a folded layer its ``kw`` pieces and their side-by-side
+    concatenation), the folded im2col patch, one tap's matmul result and
+    the fp32 epilogue value. The x
     tile, out tile, accumulator and temporaries scale with ``b_blk``; the
     weight tile does not — that asymmetry is the whole point of batching.
     int8 shrinks the streamed tiles (1-byte tensors) but bias/scale stay
@@ -174,14 +178,17 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
         shape, c_blk, m_blk, oh_blk, b_blk)
     _, pr, oh_ext, hp_blk, _ = tiles
     rows = b_blk * oh_ext * g.ow_p
-    x_tile = b_blk * hp_blk * _tile_bytes(g.w, c_blk, dt)
+    cx = c_blk // g.kw_fold                      # channels of the x tile
+    x_tile = b_blk * hp_blk * _tile_bytes(g.w, cx, dt)
     w_tile = g.kh * g.kw * _tile_bytes(c_blk, m_blk, dt)
     vec = _tile_bytes(1, m_blk, 4)               # fp32 (1, M_BLK) row
     o_tile = b_blk * pr * _tile_bytes(pw, m_blk, dt)
     acc = _tile_bytes(rows, m_blk, 4)            # fp32 / int32 scratch
     pool = (b_blk * oh_ext * _tile_bytes(g.ow_p, m_blk, 4)
             if shape.pool else 0)
-    temps = (b_blk * hp_blk * _tile_bytes(g.ow_p, c_blk, dt)   # x window
+    pieces = g.kw_fold if g.kw_fold > 1 else 0
+    temps = (b_blk * hp_blk * (_tile_bytes(g.ow_p, c_blk, dt)  # x window
+                               + pieces * _tile_bytes(g.ow_p, cx, dt))
              + _tile_bytes(rows, c_blk, dt)                    # patch
              + 2 * acc)                        # tap result + epilogue
     pipelined = x_tile + w_tile + vec * (2 if quantized else 1) + o_tile
@@ -234,7 +241,8 @@ def score_plan(shape: ConvShape, c_blk: int, m_blk: int,
     n_b = -(-shape.b // b_blk)
     bp = n_b * b_blk                       # padded image count
 
-    x_bytes = bp * n_h * n_m * n_c * hp_blk * g.w * c_blk * dt
+    cx = c_blk // g.kw_fold                # channels of the x tile
+    x_bytes = bp * n_h * n_m * n_c * hp_blk * g.w * cx * dt
     w_bytes = n_b * n_h * n_m * n_c * g.kh * g.kw * c_blk * m_blk * dt
     o_bytes = bp * n_h * pr * pw * (n_m * m_blk) * dt
     # padded-lane compute: the kernel multiplies the padded tiles
@@ -276,8 +284,9 @@ def enumerate_plans(shape: ConvShape,
     g = s2d_geometry(shape.h, shape.w, shape.c // shape.groups, shape.kh,
                      shape.kw, stride=shape.stride, pad=shape.pad)
     # channel blocks are lane dims: multiples of 128 or the whole
-    # (space-to-depth, per-group) channel count
-    c_cands = _lane_cands(g.c, 2 * MXU_DIM)
+    # (space-to-depth, per-group) channel count; one whole tile if folded
+    c_cands = sorted({contraction_block(c, g)
+                      for c in _lane_cands(g.c, 2 * MXU_DIM)})
     m_cands = _lane_cands(shape.m // shape.groups, 2 * MXU_DIM)
     step = shape.pool_s if shape.pool else 1
     oh_cands = sorted({min(_round_up(v, step), _round_up(shape.oh, step))

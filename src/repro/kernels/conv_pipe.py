@@ -10,6 +10,13 @@ inter-stage data never touches DDR. On TPU the same dataflow is one
   * The conv is computed as an on-the-fly im2col matmul on the MXU
     (kh/kw-unrolled stride-1 slices — the multi-mode engine's conv mode;
     a strided conv is space-to-depth'd in the wrapper first).
+  * A layer whose per-group channels fill less than a lane tile has its
+    kw column taps folded into the contraction, where that saves MXU row
+    passes: the kernel lays the kw column-shifted windows of its VMEM x
+    tile side by side in lanes and runs kh dots of K = kw*c instead of
+    kh*kw dots of K = c (AlexNet conv1: 3 dots of 144 for 9 of 48; conv2:
+    5 of 240 for 25 of 48). :func:`s2d_geometry` decides, for kernel,
+    tuner and static verifier alike.
   * bias + ReLU + line-buffer pooling run in the epilogue while the tile is
     still in VMEM (the Conv->Pool channel).
 
@@ -90,29 +97,48 @@ class S2DGeometry(NamedTuple):
     (padded) input's s x s pixel blocks fold into channels, so the kernel
     only ever takes stride-1 taps (Mosaic refuses strided value slices).
     AlexNet conv1 (227x227x3, 11x11/4) becomes 57x57x48 with 3x3 taps.
+
+    Where a group's channels fill less than a lane tile and folding the
+    ``kw`` column taps into the contraction saves MXU passes
+    (``ceil(kw * c / 128) < kw``), the kernel folds them: ``kh`` dots of
+    K = ``kw * c`` instead of ``kh * kw`` dots of K = ``c``. AlexNet
+    conv1 contracts over 144 with 3x1 taps, conv2 (two groups of 48)
+    over 240 with 5x1 taps; conv3-5 (c >= 128) keep their taps.
     """
     h: int          # input rows after padding and space-to-depth
-    w: int          # input cols the kernel reads (>= ow_p + kw - 1)
-    c: int          # channels per group after space-to-depth (s*s*cg)
+    w: int          # input cols the kernel reads (>= ow_p + kw*kw_fold - 1)
+    c: int          # contraction channels per group: s*s*cg, times kw_fold
+                    # (the x tile holds c // kw_fold)
     kh: int         # taps per axis after space-to-depth (ceil(K / s))
-    kw: int
+    kw: int         # (1 where the column taps are folded into c)
     oh: int         # true conv output rows / cols
     ow: int
     ow_p: int       # conv cols computed per row (ow rounded up to SUBLANE)
+    kw_fold: int    # column taps folded into c (1 = none)
 
 
 def s2d_geometry(h: int, w: int, cg: int, kh: int, kw: int, *,
                  stride: int, pad: int) -> S2DGeometry:
-    """Resolve the stride-1 geometry shared by the kernel and the tuner."""
+    """Resolve the stride-1 geometry shared by the kernel, the tuner and
+    the static verifier, with the column-tap fold decided here alone."""
     hp, wp = h + 2 * pad, w + 2 * pad
     oh = (hp - kh) // stride + 1
     ow = (wp - kw) // stride + 1
     s = stride
     khs, kws = -(-kh // s), -(-kw // s)
     ow_p = _round_up(ow, SUBLANE)
+    c = s * s * cg
+    # fewer MXU row passes folded than tap by tap (only c < LANE can)
+    fold = kws if -(-kws * c // LANE) < kws else 1
     return S2DGeometry(h=-(-hp // s), w=max(-(-wp // s), ow_p + kws - 1),
-                       c=s * s * cg, kh=khs, kw=kws, oh=oh, ow=ow,
-                       ow_p=ow_p)
+                       c=fold * c, kh=khs, kw=kws // fold, oh=oh, ow=ow,
+                       ow_p=ow_p, kw_fold=fold)
+
+
+def contraction_block(c_blk: int, g: S2DGeometry) -> int:
+    """The contraction block the kernel runs for a requested ``c_blk``: a
+    folded layer's taps lie side by side in one whole-dim tile."""
+    return g.c if g.kw_fold > 1 else min(c_blk, g.c)
 
 
 def conv_tile_geometry(oh: int, oh_blk: int, *, stride: int, kh: int,
@@ -163,14 +189,16 @@ def _space_to_depth(x: jax.Array, w: jax.Array, s: int, g: S2DGeometry
     x = x.reshape(G, B, g.h, s, -1, s, C).transpose(0, 1, 2, 4, 3, 5, 6)
     x = x.reshape(G, B, g.h, -1, s * s * C)
     _, KH, KW, _, M = w.shape
-    w = jnp.pad(w, ((0, 0), (0, g.kh * s - KH), (0, g.kw * s - KW), (0, 0),
+    kws = g.kw * g.kw_fold                 # column taps before any fold
+    w = jnp.pad(w, ((0, 0), (0, g.kh * s - KH), (0, kws * s - KW), (0, 0),
                     (0, 0)))
-    w = w.reshape(G, g.kh, s, g.kw, s, C, M).transpose(0, 1, 3, 2, 4, 5, 6)
-    return x, w.reshape(G, g.kh, g.kw, s * s * C, M)
+    w = w.reshape(G, g.kh, s, kws, s, C, M).transpose(0, 1, 3, 2, 4, 5, 6)
+    return x, w.reshape(G, g.kh, kws, s * s * C, M)
 
 
 def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
-                      ow_p: int, relu: bool, pool: Optional[str],
+                      ow_p: int, kw_fold: int, relu: bool,
+                      pool: Optional[str],
                       pool_k: int, pool_s: int, pr: int, n_c_tiles: int,
                       quantized: bool = False,
                       out_scale: Optional[float] = None):
@@ -192,8 +220,8 @@ def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    b_blk, _, _, c_blk = x_ref.shape               # (B_BLK, HP_BLK, WP, C)
-    kh, kw, _, m_blk = w_ref.shape                 # (KH, KW, C_BLK, M_BLK)
+    b_blk = x_ref.shape[0]                         # (B_BLK, HP_BLK, WP, C)
+    kh, kw, c_blk, m_blk = w_ref.shape             # (KH, KW, C_BLK, M_BLK)
     rows = b_blk * oh_ext * ow_p
     acc_t = jnp.int32 if quantized else jnp.float32
     # fp32 is computed at fp32 on the MXU (the default would round the
@@ -207,8 +235,12 @@ def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
     # so one weight fetch feeds b_blk images (batched weight reuse).
     # OW_P is a multiple of 8, so folding (B, OH, OW_P) into the MXU row
     # axis is a free relayout; the tail columns are dropped below.
+    # A folded layer (kw = 1) lays its kw_fold column-shifted windows side
+    # by side in lanes, tap-major as the wrapper laid out w, so one dot a
+    # row tap contracts over all of them.
     for j in range(kw):
-        xj = x_ref[:, :, pl.ds(j, ow_p), :]        # (B_BLK, HP_BLK, OW_P, C)
+        xj = jnp.concatenate([x_ref[:, :, pl.ds(j + t, ow_p), :]
+                              for t in range(kw_fold)], axis=-1)
         for i in range(kh):
             patch = xj[:, i:i + oh_ext].reshape(rows, c_blk)
             acc_ref[...] += jax.lax.dot_general(
@@ -265,7 +297,9 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     """Fused conv(+bias)(+ReLU)(+pool). x (B,H,W,C); w (KH,KW,C/G,M); b (M,).
 
     c_blk/m_blk are the VEC_SIZE/CU_NUM analogues, counted in the
-    space-to-depth channels (``s*s*C/G``) and per-group output channels;
+    space-to-depth channels (``s*s*C/G``, times kw where the column taps
+    fold, run as one tile: :func:`contraction_block`) and per-group
+    output channels;
     oh_blk is the line-buffer depth in conv-output rows (0 = full height);
     b_blk is the number of images per grid step (0 = whole batch).
     ``groups`` runs grouped convolution inside the one kernel: groups get
@@ -306,8 +340,11 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
         x, w = _space_to_depth(x, w, stride, g)
     # right-pad W so every tap reads ow_p columns in bounds
     x = jnp.pad(x, ((0, 0),) * 3 + ((0, g.w - x.shape[3]), (0, 0)))
+    if g.kw_fold > 1:
+        # the column taps side by side in the contraction, tap-major
+        w = w.reshape(groups, g.kh, 1, g.c, mg)
 
-    c_blk = min(c_blk, g.c)
+    c_blk = contraction_block(c_blk, g)
     m_blk = min(m_blk, mg)
     if not interpret and not (lane_legal(c_blk, g.c)
                               and lane_legal(m_blk, mg)):
@@ -339,7 +376,8 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
                     (0, max(0, need_h - x.shape[2])), (0, 0), (0, 0)))
 
     kernel = functools.partial(
-        _conv_pipe_kernel, oh_ext=oh_ext, ow=g.ow, ow_p=g.ow_p, relu=relu,
+        _conv_pipe_kernel, oh_ext=oh_ext, ow=g.ow, ow_p=g.ow_p,
+        kw_fold=g.kw_fold, relu=relu,
         pool=pool, pool_k=pool_k, pool_s=pool_s, pr=pr, n_c_tiles=n_c,
         quantized=quantized, out_scale=out_scale)
 
@@ -352,7 +390,7 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     # whole dim, so the lane offset is provably tile-aligned).
     x_spec = pl.BlockSpec(
         (None, pl.Element(b_blk), pl.Element(hp_blk), pl.Element(g.w),
-         pl.Element(c_blk)),
+         pl.Element(c_blk // g.kw_fold)),
         lambda bh, mi, ci: (mi // n_mg, (bh // n_h) * b_blk,
                             (bh % n_h) * row_step, 0,
                             ci * c_blk if n_c > 1 else 0))

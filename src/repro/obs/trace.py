@@ -72,6 +72,8 @@ spans and counters; :meth:`SpanLog.export` writes them through a
   cnn.retrace    inst.  + counter: ``jax.jit`` traced the forward
   py.gc          span   one generation-1 or -2 collection
   py.gc.gen0     count  generation-0 collections (counted only)
+  conv.kw_fold   count  ``compile_cnn``: a conv group whose column taps
+                        ``conv_pipe`` folds into its contraction
   =============  =====  ===============================================
 
 An instant is a span whose end equals its start.

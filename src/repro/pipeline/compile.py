@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core.config import CNNConfig, SpecError
 from repro.kernels import autotune, ops
+from repro.kernels.conv_pipe import s2d_geometry
 from repro.kernels.mode import backend_interprets
 from repro.obs.trace import SPANS, now_ns
 from repro.pipeline.plan_table import PlanTable, load_plan, plan_key
@@ -95,6 +96,17 @@ def _resolve_group_plans(cfg: CNNConfig, batch: int,
             plans[group] = autotune.get_gemm_plan(
                 shape, vmem_budget=cfg.vmem_budget)
     return plans
+
+
+def _count_kw_folds(cfg: CNNConfig, batch: int, dtype: str) -> None:
+    """Count (``conv.kw_fold``) each conv group whose column taps
+    ``conv_pipe`` folds into its MXU contraction
+    (:func:`~repro.kernels.conv_pipe.s2d_geometry` decides)."""
+    for _, kind, s in _group_shapes(cfg, batch, dtype):
+        if kind == "conv" and s2d_geometry(
+                s.h, s.w, s.c // s.groups, s.kh, s.kw, stride=s.stride,
+                pad=s.pad).kw_fold > 1:
+            SPANS.count("conv.kw_fold")
 
 
 class CompiledCNN:
@@ -543,6 +555,8 @@ def compile_cnn(cfg: CNNConfig, spec: Optional[ExecutionSpec] = None,
         quant = isinstance(params, QuantizedCNNParams)
 
         group_plans: Dict[Tuple[int, ...], Any] = {}
+        if spec.use_pallas:
+            _count_kw_folds(rcfg, spec.serving.batch, spec.run_dtype)
         if spec.use_pallas and spec.tiling.autotune:
             group_plans = _resolve_group_plans(
                 rcfg, spec.serving.batch, spec.run_dtype)

@@ -13,7 +13,10 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels import autotune, ops, ref
-from repro.kernels.conv_pipe import conv_pipe, conv_tile_geometry
+from repro.kernels.conv_pipe import conv_pipe, conv_tile_geometry, \
+    s2d_geometry
+from repro.quant import abs_max_scale, quantize, quantize_channelwise
+from repro.quant import ref as qref
 
 KEY = jax.random.key(11)
 
@@ -97,18 +100,115 @@ def test_grouped_conv_unpadded_group_channels():
     _check(1, 13, 6, 3, 30, pad=1, oh_blk=4, groups=3, c_blk=4, m_blk=4)
 
 
+def _prims_outside_kernels(jaxpr):
+    """Primitive names of a jaxpr and its sub-jaxprs, not descending
+    into a ``pallas_call``'s kernel body (a folded layer concatenates
+    its column-shifted windows there, in VMEM)."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for param in eqn.params.values():
+            sub = getattr(param, "jaxpr", param)      # ClosedJaxpr or Jaxpr
+            if hasattr(sub, "eqns"):
+                names += _prims_outside_kernels(sub)
+    return names
+
+
 def test_grouped_conv_single_pallas_call_no_concat():
     """Acceptance: grouped conv is ONE pallas_call with no activation
     concatenate (the seed launched G kernels and concatenated)."""
     x = _rand((1, 15, 15, 8))
     w = _rand((3, 3, 4, 16), scale=0.2)
     b = _rand((16,))
-    jaxpr = str(jax.make_jaxpr(
+    jaxpr = jax.make_jaxpr(
         lambda x, w, b: ops.fused_conv(
             x, w, b, pad=1, pool="max", pool_k=3, pool_s=2,
-            use_pallas=True, groups=2, oh_blk=4))(x, w, b))
-    assert jaxpr.count("pallas_call") == 1
-    assert "concatenate" not in jaxpr
+            use_pallas=True, groups=2, oh_blk=4))(x, w, b).jaxpr
+    prims = _prims_outside_kernels(jaxpr)
+    assert prims.count("pallas_call") == 1
+    assert "concatenate" not in prims
+
+
+# ---------------------------------------------------------------------------
+# kw-tap folding: a narrow layer contracts over kw * c in kh dots
+# ---------------------------------------------------------------------------
+
+def _geometry(H, C, K, *, stride=1, pad=0, groups=1, **_):
+    return s2d_geometry(H, H, C // groups, K, K, stride=stride, pad=pad)
+
+
+@pytest.mark.parametrize("B,H,C,K,M,kw", [
+    # conv1: 11x11/4 space-to-depth'd to 48 channels x 3 taps = 144
+    (2, 27, 3, 11, 16, dict(stride=4, c_blk=144, m_blk=16)),
+    # conv2: two groups of 48 channels x 5 taps = 240 (a c_blk of 128
+    # runs as the one whole tile the folded taps need)
+    (2, 9, 96, 5, 16, dict(pad=2, groups=2, c_blk=128, m_blk=8)),
+    # a fused 3x3/2 max pool over H-tiles
+    (1, 17, 8, 3, 8, dict(pad=1, pool="max", pool_k=3, pool_s=2,
+                          oh_blk=4, c_blk=24)),
+    # b_blk 2 does not divide a batch of 3
+    (3, 12, 4, 3, 8, dict(pad=1, oh_blk=4, b_blk=2, c_blk=12)),
+], ids=["conv1_s2d", "conv2_groups", "pool_tiled", "b_blk_partial"])
+def test_kw_fold_matches_oracle(B, H, C, K, M, kw):
+    g = _geometry(H, C, K, **kw)
+    assert g.kw == 1 and g.kw_fold > 1
+    _check(B, H, C, K, M, **kw)
+
+
+def test_unfolded_taps_match_oracle():
+    """96 channels x 3 taps take 3 MXU row passes folded or not, so the
+    kernel keeps its column taps (the conv3-5 path at a small size)."""
+    g = _geometry(9, 96, 3, pad=1)
+    assert (g.c, g.kw, g.kw_fold) == (96, 3, 1)
+    _check(2, 9, 96, 3, 8, pad=1, oh_blk=4, c_blk=96)
+
+
+def test_kw_fold_int8_bit_equal():
+    """int32 accumulation is exact in any order: a folded grouped,
+    pooled layer equals the exact-int reference code for code."""
+    x = _rand((3, 11, 11, 96))
+    w = _rand((5, 5, 48, 16), scale=0.2)
+    b = _rand((16,), scale=0.1)
+    sx = float(abs_max_scale(x))
+    wq, ws = quantize_channelwise(w, axis=-1)
+    xq = quantize(x, sx)
+    kw = dict(pad=2, groups=2, pool="max", pool_k=3, pool_s=2,
+              out_scale=0.05)
+    assert _geometry(11, 96, 5, **kw).kw_fold == 5
+    got = conv_pipe(xq, wq, b, scale=ws * sx, c_blk=240, m_blk=8,
+                    oh_blk=4, b_blk=2, **kw)
+    want = qref.conv_int8_ref(xq, wq, b, ws * sx, **kw)
+    assert got.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_kw_fold_rule_on_alexnet():
+    """The fold is decided from the shape: AlexNet conv1 and conv2 fold,
+    conv3-5 (c >= 128) do not and keep the batch-32 plans they had
+    before it; the static verifier re-proves the folded plans."""
+    from repro.models.cnn import init_cnn_params
+    from repro.pipeline import ExecutionSpec, Serving, compile_cnn
+
+    cfg = get_config("alexnet")
+    geoms = [s2d_geometry(s.h, s.w, s.c // s.groups, s.kh, s.kw,
+                          stride=s.stride, pad=s.pad)
+             for s in _conv_shapes(cfg)]
+    assert [(g.c, g.kh, g.kw, g.kw_fold) for g in geoms] == [
+        (144, 3, 1, 3), (240, 5, 1, 5), (256, 3, 3, 1), (192, 3, 3, 1),
+        (192, 3, 3, 1)]
+    params = jax.eval_shape(lambda: init_cnn_params(KEY, cfg))
+    compiled = compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=32)),
+                           params, with_engine=False)
+    plans = {g: (p.c_blk, p.m_blk, p.oh_blk, p.b_blk)
+             for g, p in compiled.group_plans.items()
+             if isinstance(p, autotune.ConvPlan)}
+    assert plans[(0,)][0] == 144 and plans[(3,)][0] == 240
+    assert {g: plans[g] for g in ((6,), (7,), (8, 9))} == {
+        (6,): (128, 384, 13, 4), (7,): (192, 192, 13, 4),
+        (8, 9): (192, 128, 4, 16)}
+    assert compiled.verify() == []
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.autotune import ConvShape, GemmShape, get_gemm_plan, \
     get_plan
-from repro.kernels.conv_pipe import conv_pipe
+from repro.kernels.conv_pipe import conv_pipe, s2d_geometry
 from repro.kernels.lrn_pwl import lrn_pwl
 from repro.kernels.matmul_pipe import matmul_pipe
 from repro.serve.stage_planner import group_io_shapes
@@ -130,10 +130,11 @@ def test_lrn_pwl_compiles(sds):
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
 def test_tuned_alexnet_plans_compile(sds, dtype):
     """Every plan the tuner picks for AlexNet at the serving batch lowers:
-    its lane/sublane-legal blocks and its VMEM model hold on the chip."""
+    its lane/sublane-legal blocks and its VMEM model hold on the chip,
+    conv1's and conv2's over their folded column taps among them."""
     cfg = get_config("alexnet")
     int8 = dtype == "int8"
-    n_conv = n_fc = 0
+    n_conv = n_fc = n_folded = 0
     for group, in_shape, out_shape in group_io_shapes(cfg):
         layer = cfg.layers[group[0]]
         if layer.kind == "conv":
@@ -146,6 +147,9 @@ def test_tuned_alexnet_plans_compile(sds, dtype):
                 pool_k=pool.kernel if pool else 2,
                 pool_s=pool.stride if pool else 2, dtype=dtype, b=BATCH)
             plan = get_plan(shape, vmem_budget=cfg.vmem_budget)
+            n_folded += s2d_geometry(h, w, c // layer.groups, layer.kernel,
+                                     layer.kernel, stride=layer.stride,
+                                     pad=layer.pad).kw_fold > 1
             f = _conv_fn(int8, stride=shape.stride, pad=shape.pad,
                          groups=shape.groups, pool=shape.pool,
                          pool_k=shape.pool_k, pool_s=shape.pool_s,
@@ -176,7 +180,7 @@ def test_tuned_alexnet_plans_compile(sds, dtype):
                 f = lambda x, w, b: matmul_pipe(x, w, b, **blocks)
             _assert_kernel(f, *args)
             n_fc += 1
-    assert (n_conv, n_fc) == (5, 3)
+    assert (n_conv, n_fc, n_folded) == (5, 3, 2)
 
 
 def test_whole_alexnet_forward_names_its_kernels(topo, sds):

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core.config import CNNConfig, ConvLayer
 from repro.obs import SPANS, SpanLog, set_spans, validate_trace
 from repro.pipeline import ExecutionSpec, Serving, compile_cnn
 from tests.test_parallel import run_in_mesh_subprocess
@@ -167,6 +168,26 @@ def test_retrace_counts_each_new_shape_once(compiled, spans):
     assert all(m[1] == m[2] for m in marks)          # instants
     assert marks[0][5] == {"shape": x3.shape, "dtype": "float32",
                            "placement": "single"}
+
+
+def test_kw_fold_counted_per_folded_conv_group(spans):
+    """``compile_cnn`` counts ``conv.kw_fold`` once for each conv group
+    whose column taps ``conv_pipe`` folds: AlexNet's conv1 and conv2,
+    and none in a net whose conv channels fill a lane tile."""
+    from repro.models.cnn import init_cnn_params
+
+    cfg = get_config("alexnet")
+    params = jax.eval_shape(lambda: init_cnn_params(jax.random.key(0), cfg))
+    compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=8)), params,
+                with_engine=False)
+    assert spans.read()["counters"].get("conv.kw_fold") == 2
+    spans.clear()
+    wide = CNNConfig(name="wide", input_hw=9, input_ch=128, n_classes=4,
+                     layers=(ConvLayer("conv", out_ch=128, kernel=3, pad=1),
+                             ConvLayer("fc", out_ch=4, relu=False)))
+    compile_cnn(wide, ExecutionSpec(serving=Serving(batch=2)),
+                key=jax.random.key(0), with_engine=False)
+    assert "conv.kw_fold" not in spans.read()["counters"]
 
 
 def test_chrome_export_validates(compiled, spans):
