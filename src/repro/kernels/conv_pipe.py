@@ -18,7 +18,8 @@ inter-stage data never touches DDR. On TPU the same dataflow is one
     5 of 240 for 25 of 48). :func:`s2d_geometry` decides, for kernel,
     tuner and static verifier alike.
   * bias + ReLU + line-buffer pooling run in the epilogue while the tile is
-    still in VMEM (the Conv->Pool channel).
+    still in VMEM (the Conv->Pool channel), one 128-lane slab of a wide
+    output tile at a time (VGG-16 conv5_3 + pool: four slabs of 512).
 
 Grid: ``(B_tiles * H_tiles, M_tiles, C_tiles)`` with the input-channel axis
 LAST and "arbitrary" semantics — the fp32 VMEM scratch accumulates partial
@@ -262,21 +263,30 @@ def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
             # line-buffer pooling: the conv tile is still in VMEM; reduce
             # pool_k x pool_k windows read back with strided loads (the
             # (L+1)-input pool logic). oh_ext was sized so every window
-            # lies inside this tile.
-            y_ref[...] = y
+            # lies inside this tile. Mosaic takes strided loads only from
+            # a scratch whose minor dim is one lane tile, so a wide tile
+            # (VGG conv3_3: 256, conv5_3: 512) goes through it one
+            # 128-lane slab at a time and the slabs rejoin in lanes.
+            n_slab, lanes = y_ref.shape[0], y_ref.shape[-1]
             pw = o_ref.shape[2]
-            win = None
-            for i in range(pool_k):
-                for j in range(pool_k):
-                    sl = y_ref[:, pl.ds(i, pr, stride=pool_s),
-                               pl.ds(j, pw, stride=pool_s), :]
-                    if win is None:
-                        win = sl
-                    elif pool == "max":
-                        win = jnp.maximum(win, sl)
-                    else:
-                        win = win + sl
-            y = win / (pool_k * pool_k) if pool == "avg" else win
+            pooled = []
+            for s in range(n_slab):
+                y_ref[s] = y[..., s * lanes:(s + 1) * lanes]
+                win = None
+                for i in range(pool_k):
+                    for j in range(pool_k):
+                        sl = y_ref[s, :, pl.ds(i, pr, stride=pool_s),
+                                   pl.ds(j, pw, stride=pool_s), :]
+                        if win is None:
+                            win = sl
+                        elif pool == "max":
+                            win = jnp.maximum(win, sl)
+                        else:
+                            win = win + sl
+                pooled.append(win)
+            y = pooled[0] if n_slab == 1 else jnp.concatenate(pooled, -1)
+            if pool == "avg":
+                y = y / (pool_k * pool_k)
         else:
             y = y[:, :, :ow]
         if quantized and out_scale is not None:
@@ -421,8 +431,11 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     acc_dtype = jnp.int32 if quantized else jnp.float32
     scratch = [pltpu.VMEM((b_blk * oh_ext * g.ow_p, m_blk), acc_dtype)]
     if pool is not None:
-        scratch.append(pltpu.VMEM((b_blk, oh_ext, g.ow_p, m_blk),
-                                  jnp.float32))
+        # the pool scratch in lane-tile slabs (one slab where m_blk is
+        # narrower than a lane tile or not a multiple of one)
+        lanes = LANE if m_blk % LANE == 0 else m_blk
+        scratch.append(pltpu.VMEM((m_blk // lanes, b_blk, oh_ext, g.ow_p,
+                                   lanes), jnp.float32))
 
     out = pl.pallas_call(
         kernel,

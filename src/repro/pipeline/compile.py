@@ -98,15 +98,19 @@ def _resolve_group_plans(cfg: CNNConfig, batch: int,
     return plans
 
 
-def _count_kw_folds(cfg: CNNConfig, batch: int, dtype: str) -> None:
+def _count_conv_paths(cfg: CNNConfig, batch: int, dtype: str) -> None:
     """Count (``conv.kw_fold``) each conv group whose column taps
     ``conv_pipe`` folds into its MXU contraction
-    (:func:`~repro.kernels.conv_pipe.s2d_geometry` decides)."""
+    (:func:`~repro.kernels.conv_pipe.s2d_geometry` decides), and
+    (``conv.pool_fused``) each whose pool runs in its epilogue."""
     for _, kind, s in _group_shapes(cfg, batch, dtype):
-        if kind == "conv" and s2d_geometry(
-                s.h, s.w, s.c // s.groups, s.kh, s.kw, stride=s.stride,
-                pad=s.pad).kw_fold > 1:
+        if kind != "conv":
+            continue
+        if s2d_geometry(s.h, s.w, s.c // s.groups, s.kh, s.kw,
+                        stride=s.stride, pad=s.pad).kw_fold > 1:
             SPANS.count("conv.kw_fold")
+        if s.pool is not None:
+            SPANS.count("conv.pool_fused")
 
 
 class CompiledCNN:
@@ -556,7 +560,7 @@ def compile_cnn(cfg: CNNConfig, spec: Optional[ExecutionSpec] = None,
 
         group_plans: Dict[Tuple[int, ...], Any] = {}
         if spec.use_pallas:
-            _count_kw_folds(rcfg, spec.serving.batch, spec.run_dtype)
+            _count_conv_paths(rcfg, spec.serving.batch, spec.run_dtype)
         if spec.use_pallas and spec.tiling.autotune:
             group_plans = _resolve_group_plans(
                 rcfg, spec.serving.batch, spec.run_dtype)

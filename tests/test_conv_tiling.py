@@ -212,6 +212,41 @@ def test_kw_fold_rule_on_alexnet():
 
 
 # ---------------------------------------------------------------------------
+# the pooled epilogue over 128-lane slabs (VGG-16's wide conv->pool tiles)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("M,m_blk", [
+    (512, 256),     # two M-tiles of two slabs (VGG conv3_3, conv4_3)
+    (512, 512),     # one M-tile of four slabs (VGG conv5_3)
+    (64, 64),       # one slab narrower than a lane tile (VGG conv1_2)
+])
+def test_pool_slabs_match_oracle(M, m_blk):
+    """A pooled tile wider than a lane tile is pooled one 128-lane slab
+    at a time and rejoined in lanes; pool windows straddle H-tiles."""
+    _check(2, 10, 8, 3, M, pad=1, pool="max", pool_k=2, pool_s=2,
+           oh_blk=4, b_blk=2, c_blk=8, m_blk=m_blk)
+
+
+@pytest.mark.parametrize("M,m_blk", [(512, 256), (512, 512), (64, 64)])
+def test_pool_slabs_int8_bit_equal(M, m_blk):
+    """The int8 pipeline runs the same slab epilogue (requantize, bias,
+    ReLU, pool over slabs, round): code for code with the exact-int
+    reference."""
+    x = _rand((2, 10, 10, 8))
+    w = _rand((3, 3, 8, M), scale=0.2)
+    b = _rand((M,), scale=0.1)
+    sx = float(abs_max_scale(x))
+    wq, ws = quantize_channelwise(w, axis=-1)
+    xq = quantize(x, sx)
+    kw = dict(pad=1, pool="max", pool_k=2, pool_s=2, out_scale=0.05)
+    got = conv_pipe(xq, wq, b, scale=ws * sx, c_blk=8, m_blk=m_blk,
+                    oh_blk=4, b_blk=2, **kw)
+    want = qref.conv_int8_ref(xq, wq, b, ws * sx, **kw)
+    assert got.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
 # batch folding (the serving path): b_blk images per grid step
 # ---------------------------------------------------------------------------
 

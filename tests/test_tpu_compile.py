@@ -80,7 +80,8 @@ def _conv_fn(int8, **kw):
 
 # AlexNet conv1 (stride 4: space-to-depth), conv2 (two groups of 48
 # channels), conv5 with its fused 3x3/2 max pool; VGG-16 conv1_2 + pool
-# (224x224x64, H-tiled)
+# (224x224x64, H-tiled) and conv5_3 + pool (512 channels a tile: four
+# 128-lane pool slabs)
 CONV_LAYERS = {
     "alexnet_conv1": ((BATCH, 227, 227, 3), (11, 11, 3, 96),
                       dict(stride=4, pad=0)),
@@ -92,6 +93,10 @@ CONV_LAYERS = {
     "vgg16_conv1_pool": ((1, 224, 224, 64), (3, 3, 64, 64),
                          dict(stride=1, pad=1, pool="max", pool_k=2,
                               pool_s=2, c_blk=64, m_blk=64, oh_blk=8)),
+    "vgg16_conv5_pool": ((BATCH, 14, 14, 512), (3, 3, 512, 512),
+                         dict(stride=1, pad=1, pool="max", pool_k=2,
+                              pool_s=2, c_blk=128, m_blk=512, oh_blk=14,
+                              b_blk=4)),
 }
 
 
@@ -99,7 +104,7 @@ CONV_LAYERS = {
     ("alexnet_conv1", False), ("alexnet_conv1", True),
     ("alexnet_conv2_grouped", False), ("alexnet_conv2_grouped", True),
     ("alexnet_conv5_pool", False), ("alexnet_conv5_pool", True),
-    ("vgg16_conv1_pool", False)])
+    ("vgg16_conv1_pool", False), ("vgg16_conv5_pool", True)])
 def test_conv_pipe_compiles(sds, layer, int8):
     x_shape, w_shape, kw = CONV_LAYERS[layer]
     _assert_kernel(_conv_fn(int8, **kw),
@@ -127,14 +132,25 @@ def test_lrn_pwl_compiles(sds):
                    sds((BATCH, 55, 55, 96), jnp.float32))
 
 
-@pytest.mark.parametrize("dtype", ["float32", "int8"])
-def test_tuned_alexnet_plans_compile(sds, dtype):
-    """Every plan the tuner picks for AlexNet at the serving batch lowers:
-    its lane/sublane-legal blocks and its VMEM model hold on the chip,
-    conv1's and conv2's over their folded column taps among them."""
-    cfg = get_config("alexnet")
+# (conv groups, fc layers, groups with folded column taps, groups with
+# the pool in the kernel's epilogue)
+NET_GROUPS = {"alexnet": (5, 3, 2, 1), "vgg16": (13, 3, 3, 5)}
+
+
+@pytest.mark.parametrize("arch,dtype", [
+    ("alexnet", "float32"), ("alexnet", "int8"), ("vgg16", "float32")],
+    ids=["float32", "int8", "vgg16-float32"])
+def test_tuned_alexnet_plans_compile(sds, arch, dtype):
+    """Every plan the tuner picks for AlexNet and VGG-16 at the serving
+    batch lowers: its lane/sublane-legal blocks and its VMEM model hold
+    on the chip, AlexNet conv1's and conv2's and VGG conv1_1's, conv1_2's
+    and conv2_1's over their folded column taps among them, and VGG's
+    pooled groups at 256 and 512 output channels a tile (the epilogue's
+    128-lane pool slabs; their int8 form is ``vgg16_conv5_pool``
+    above)."""
+    cfg = get_config(arch)
     int8 = dtype == "int8"
-    n_conv = n_fc = n_folded = 0
+    n_conv = n_fc = n_folded = n_pooled = 0
     for group, in_shape, out_shape in group_io_shapes(cfg):
         layer = cfg.layers[group[0]]
         if layer.kind == "conv":
@@ -150,6 +166,7 @@ def test_tuned_alexnet_plans_compile(sds, dtype):
             n_folded += s2d_geometry(h, w, c // layer.groups, layer.kernel,
                                      layer.kernel, stride=layer.stride,
                                      pad=layer.pad).kw_fold > 1
+            n_pooled += pool is not None
             f = _conv_fn(int8, stride=shape.stride, pad=shape.pad,
                          groups=shape.groups, pool=shape.pool,
                          pool_k=shape.pool_k, pool_s=shape.pool_s,
@@ -180,15 +197,20 @@ def test_tuned_alexnet_plans_compile(sds, dtype):
                 f = lambda x, w, b: matmul_pipe(x, w, b, **blocks)
             _assert_kernel(f, *args)
             n_fc += 1
-    assert (n_conv, n_fc, n_folded) == (5, 3, 2)
+    assert (n_conv, n_fc, n_folded, n_pooled) == NET_GROUPS[arch]
 
 
-def test_whole_alexnet_forward_names_its_kernels(topo, sds):
-    """``CompiledCNN.lower`` of the whole AlexNet forward (with the span
-    log on, as it is by default): every compiled Pallas kernel is an
-    instruction named after its jitted wrapper, ``fused_conv``, ``fc`` or
-    ``lrn``, the families the benchmark's kernel rooflines read from
-    the device trace."""
+@pytest.mark.parametrize("arch,families,n_kernels", [
+    ("alexnet", ["fc", "fused_conv", "lrn"], 10),   # 5 conv, 2 LRN, 3 FC
+    ("vgg16", ["fc", "fused_conv"], 16)],           # 13 conv, 3 FC
+    ids=["alexnet", "vgg16"])
+def test_whole_alexnet_forward_names_its_kernels(topo, sds, arch, families,
+                                                 n_kernels):
+    """``CompiledCNN.lower`` of the whole AlexNet (and VGG-16) forward
+    (with the span log on, as it is by default): every compiled Pallas
+    kernel is an instruction named after its jitted wrapper,
+    ``fused_conv``, ``fc`` or ``lrn``, the families the benchmark's
+    kernel rooflines read from the device trace."""
     import re
 
     from repro.models.cnn import init_cnn_params
@@ -196,7 +218,7 @@ def test_whole_alexnet_forward_names_its_kernels(topo, sds):
     from repro.pipeline import ExecutionSpec, Serving, compile_cnn
 
     assert SPANS.enabled
-    cfg = get_config("alexnet")
+    cfg = get_config(arch)
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
         lambda: init_cnn_params(jax.random.key(0), cfg)))
     compiled = compile_cnn(cfg, ExecutionSpec(
@@ -207,6 +229,5 @@ def test_whole_alexnet_forward_names_its_kernels(topo, sds):
     kernels = [re.match(r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=", line).group(1)
                for line in txt.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
-    families = sorted({k.split(".")[0] for k in kernels})
-    assert families == ["fc", "fused_conv", "lrn"], kernels
-    assert len(kernels) == 10, kernels     # 5 conv groups, 2 LRNs, 3 FCs
+    assert sorted({k.split(".")[0] for k in kernels}) == families, kernels
+    assert len(kernels) == n_kernels, kernels

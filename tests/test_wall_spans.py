@@ -190,6 +190,20 @@ def test_kw_fold_counted_per_folded_conv_group(spans):
     assert "conv.kw_fold" not in spans.read()["counters"]
 
 
+@pytest.mark.parametrize("arch,pooled", [("alexnet", 1), ("vgg16", 5)])
+def test_pool_fused_counted_per_pooled_conv_group(spans, arch, pooled):
+    """``compile_cnn`` counts ``conv.pool_fused`` once for each conv group
+    whose pool runs in ``conv_pipe``'s epilogue: AlexNet's conv5 (its
+    other pools follow an LRN), every VGG-16 block's last conv."""
+    from repro.models.cnn import init_cnn_params
+
+    cfg = get_config(arch).smoke()
+    params = jax.eval_shape(lambda: init_cnn_params(jax.random.key(0), cfg))
+    compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=2)), params,
+                with_engine=False)
+    assert spans.read()["counters"].get("conv.pool_fused") == pooled
+
+
 def test_chrome_export_validates(compiled, spans):
     c, cfg = compiled
     np.asarray(c.forward(_images(cfg, 4)))         # traced: one instant
