@@ -47,7 +47,7 @@ from repro.kernels.autotune import (ConvPlan, ConvShape, GemmPlan,
                                     GemmShape, _DTYPE_BYTES,
                                     conv_vmem_bytes, gemm_plan_fits,
                                     gemm_vmem_bytes, plan_fits)
-from repro.kernels.conv_pipe import conv_tile_geometry, s2d_geometry
+from repro.kernels.conv_pipe import s2d_geometry
 
 _ROW_FIELDS = ("shape", "backend", "vmem_budget", "plan")
 
@@ -113,8 +113,10 @@ def _check_conv_row(loc: str, row: dict, shape: ConvShape, plan: ConvPlan,
             f"the plan is keyed for — the grid would read past the "
             f"batch"))
     # c_blk counts the kernel's space-to-depth channels (s*s*C/G)
-    cg = s2d_geometry(shape.h, shape.w, shape.c // shape.groups, shape.kh,
-                      shape.kw, stride=shape.stride, pad=shape.pad).c
+    geom = s2d_geometry(shape.h, shape.w, shape.c // shape.groups,
+                        shape.kh, shape.kw, stride=shape.stride,
+                        pad=shape.pad)
+    cg = geom.c
     mg = shape.m // shape.groups
     if plan.c_blk > cg or plan.m_blk > mg:
         findings.append(Finding(
@@ -133,9 +135,9 @@ def _check_conv_row(loc: str, row: dict, shape: ConvShape, plan: ConvPlan,
         # Re-derive the halo geometry and prove the H-tiling covers the
         # (pooled) output exactly once — the line-buffer feasibility
         # argument of the paper, re-run from the committed numbers.
-        n_h, pr, _oh_ext, _hp, _step = conv_tile_geometry(
-            shape.oh, plan.oh_blk, stride=shape.stride, kh=shape.kh,
-            pool=shape.pool, pool_k=shape.pool_k, pool_s=shape.pool_s)
+        n_h, pr, _oh_ext, _hp, _step = geom.tiles(
+            plan.oh_blk, pool=shape.pool, pool_k=shape.pool_k,
+            pool_s=shape.pool_s)
         out_rows = ((shape.oh - shape.pool_k) // shape.pool_s + 1
                     if shape.pool else shape.oh)
         if n_h * pr < out_rows or (n_h - 1) * pr >= out_rows:

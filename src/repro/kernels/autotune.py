@@ -44,8 +44,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 from repro.core.roofline import (MXU_DIM, VMEM_BYTES, mxu_utilization,
                                  time_bounds)
 from repro.kernels.conv_pipe import (LANE, SUBLANE, _round_up,
-                                     contraction_block, conv_tile_geometry,
-                                     s2d_geometry)
+                                     contraction_block, s2d_geometry)
 from repro.kernels.mode import resolve_interpret
 
 _DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
@@ -138,16 +137,15 @@ class ConvPlan:
 def _conv_geometry(shape: ConvShape, c_blk: int, m_blk: int,
                    oh_blk: int, b_blk: int):
     """The kernel's real geometry for one plan: the space-to-depth conv
-    (stride 1, ``s*s*C/G`` channels, times ``kw`` where the column taps
-    fold), clamped blocks and the H tiling."""
+    (stride 1, ``s*s*C/G`` channels, times the taps folded into the
+    contraction), clamped blocks and the H tiling."""
     g = s2d_geometry(shape.h, shape.w, shape.c // shape.groups, shape.kh,
                      shape.kw, stride=shape.stride, pad=shape.pad)
     c_blk = contraction_block(c_blk, g)
     m_blk = min(m_blk, shape.m // shape.groups)
     b_blk = max(1, min(b_blk, shape.b))
-    tiles = conv_tile_geometry(g.oh, oh_blk, stride=1, kh=g.kh,
-                               pool=shape.pool, pool_k=shape.pool_k,
-                               pool_s=shape.pool_s)
+    tiles = g.tiles(oh_blk, pool=shape.pool, pool_k=shape.pool_k,
+                    pool_s=shape.pool_s)
     pw = ((g.ow - shape.pool_k) // shape.pool_s + 1
           if shape.pool else g.ow)
     return g, c_blk, m_blk, b_blk, tiles, pw
@@ -165,8 +163,10 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
     pooling) is single-buffered and always 4 bytes/element. The kernel
     body's temporaries are counted too: the column-shifted x window (for
     a folded layer its ``kw`` pieces and their side-by-side
-    concatenation), the folded im2col patch, one tap's matmul result and
-    the fp32 epilogue value. The x
+    concatenation), for a row-folded layer the ``kh`` row-shifted pieces
+    of that window, the folded im2col patch (VGG-16 conv1_2's 576
+    channels take 640 lanes), one tap's matmul result and the fp32
+    epilogue value. The x
     tile, out tile, accumulator and temporaries scale with ``b_blk``; the
     weight tile does not — that asymmetry is the whole point of batching.
     int8 shrinks the streamed tiles (1-byte tensors) but bias/scale stay
@@ -178,7 +178,8 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
         shape, c_blk, m_blk, oh_blk, b_blk)
     _, pr, oh_ext, hp_blk, _ = tiles
     rows = b_blk * oh_ext * g.ow_p
-    cx = c_blk // g.kw_fold                      # channels of the x tile
+    cx = c_blk // g.taps_folded                  # channels of the x tile
+    cj = c_blk // g.kh_fold                      # of the x window
     x_tile = b_blk * hp_blk * _tile_bytes(g.w, cx, dt)
     w_tile = g.kh * g.kw * _tile_bytes(c_blk, m_blk, dt)
     vec = _tile_bytes(1, m_blk, 4)               # fp32 (1, M_BLK) row
@@ -187,8 +188,10 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
     pool = (b_blk * oh_ext * _tile_bytes(g.ow_p, m_blk, 4)
             if shape.pool else 0)
     pieces = g.kw_fold if g.kw_fold > 1 else 0
-    temps = (b_blk * hp_blk * (_tile_bytes(g.ow_p, c_blk, dt)  # x window
+    row_pieces = g.kh_fold if g.kh_fold > 1 else 0
+    temps = (b_blk * hp_blk * (_tile_bytes(g.ow_p, cj, dt)     # x window
                                + pieces * _tile_bytes(g.ow_p, cx, dt))
+             + row_pieces * b_blk * oh_ext * _tile_bytes(g.ow_p, cj, dt)
              + _tile_bytes(rows, c_blk, dt)                    # patch
              + 2 * acc)                        # tap result + epilogue
     pipelined = x_tile + w_tile + vec * (2 if quantized else 1) + o_tile
@@ -241,7 +244,7 @@ def score_plan(shape: ConvShape, c_blk: int, m_blk: int,
     n_b = -(-shape.b // b_blk)
     bp = n_b * b_blk                       # padded image count
 
-    cx = c_blk // g.kw_fold                # channels of the x tile
+    cx = c_blk // g.taps_folded            # channels of the x tile
     x_bytes = bp * n_h * n_m * n_c * hp_blk * g.w * cx * dt
     w_bytes = n_b * n_h * n_m * n_c * g.kh * g.kw * c_blk * m_blk * dt
     o_bytes = bp * n_h * pr * pw * (n_m * m_blk) * dt

@@ -14,9 +14,12 @@ inter-stage data never touches DDR. On TPU the same dataflow is one
     kw column taps folded into the contraction, where that saves MXU row
     passes: the kernel lays the kw column-shifted windows of its VMEM x
     tile side by side in lanes and runs kh dots of K = kw*c instead of
-    kh*kw dots of K = c (AlexNet conv1: 3 dots of 144 for 9 of 48; conv2:
-    5 of 240 for 25 of 48). :func:`s2d_geometry` decides, for kernel,
-    tuner and static verifier alike.
+    kh*kw dots of K = c (AlexNet conv2: 5 of 240 for 25 of 48). Where it
+    saves passes again, the kh row-shifted windows of that concatenation
+    go side by side too, for one dot of K = kh*kw*c (AlexNet conv1: 1 of
+    432 for 9 of 48; VGG-16 conv1_1: 1 of 27 for 9 of 3).
+    :func:`s2d_geometry` decides, for kernel, tuner and static verifier
+    alike.
   * bias + ReLU + line-buffer pooling run in the epilogue while the tile is
     still in VMEM (the Conv->Pool channel), one 128-lane slab of a wide
     output tile at a time (VGG-16 conv5_3 + pool: four slabs of 512).
@@ -102,26 +105,45 @@ class S2DGeometry(NamedTuple):
     Where a group's channels fill less than a lane tile and folding the
     ``kw`` column taps into the contraction saves MXU passes
     (``ceil(kw * c / 128) < kw``), the kernel folds them: ``kh`` dots of
-    K = ``kw * c`` instead of ``kh * kw`` dots of K = ``c``. AlexNet
-    conv1 contracts over 144 with 3x1 taps, conv2 (two groups of 48)
-    over 240 with 5x1 taps; conv3-5 (c >= 128) keep their taps.
+    K = ``kw * c`` instead of ``kh * kw`` dots of K = ``c``. Where folding
+    the ``kh`` row taps on top saves passes again (``ceil(kh * kw * c /
+    128) < kh * ceil(kw * c / 128)``), it runs one dot of K = ``kh * kw *
+    c``. AlexNet conv1 contracts over 432 with 1x1 taps, conv2 (two groups
+    of 48) over 240 with 5x1 taps (1,200 would take 10 passes either
+    way); VGG-16 conv1_1 over 27, conv1_2 and conv2_1 over 576; layers
+    with c >= 128 keep their taps.
     """
     h: int          # input rows after padding and space-to-depth
     w: int          # input cols the kernel reads (>= ow_p + kw*kw_fold - 1)
-    c: int          # contraction channels per group: s*s*cg, times kw_fold
-                    # (the x tile holds c // kw_fold)
+    c: int          # contraction channels per group: s*s*cg, times the
+                    # folded taps (the x tile holds c // taps_folded)
     kh: int         # taps per axis after space-to-depth (ceil(K / s))
-    kw: int         # (1 where the column taps are folded into c)
+    kw: int         # (1 where that axis's taps are folded into c)
     oh: int         # true conv output rows / cols
     ow: int
     ow_p: int       # conv cols computed per row (ow rounded up to SUBLANE)
     kw_fold: int    # column taps folded into c (1 = none)
+    kh_fold: int    # row taps folded into c (1 = none)
+
+    @property
+    def taps_folded(self) -> int:
+        """Taps laid side by side in the contraction: x-tile channels =
+        contraction channels // this."""
+        return self.kh_fold * self.kw_fold
+
+    def tiles(self, oh_blk: int, *, pool: Optional[str], pool_k: int,
+              pool_s: int) -> Tuple[int, int, int, int, int]:
+        """:func:`conv_tile_geometry` of this conv: the x tile's halo
+        spans every row tap, folded into the contraction or not."""
+        return conv_tile_geometry(self.oh, oh_blk,
+                                  kh=self.kh * self.kh_fold, pool=pool,
+                                  pool_k=pool_k, pool_s=pool_s)
 
 
 def s2d_geometry(h: int, w: int, cg: int, kh: int, kw: int, *,
                  stride: int, pad: int) -> S2DGeometry:
     """Resolve the stride-1 geometry shared by the kernel, the tuner and
-    the static verifier, with the column-tap fold decided here alone."""
+    the static verifier, with the tap folds decided here alone."""
     hp, wp = h + 2 * pad, w + 2 * pad
     oh = (hp - kh) // stride + 1
     ow = (wp - kw) // stride + 1
@@ -131,21 +153,26 @@ def s2d_geometry(h: int, w: int, cg: int, kh: int, kw: int, *,
     c = s * s * cg
     # fewer MXU row passes folded than tap by tap (only c < LANE can)
     fold = kws if -(-kws * c // LANE) < kws else 1
+    # and the row taps on top of the column fold, on the same rule
+    kc = kws * c
+    hfold = (khs if fold > 1 and -(-khs * kc // LANE) < khs * -(-kc // LANE)
+             else 1)
     return S2DGeometry(h=-(-hp // s), w=max(-(-wp // s), ow_p + kws - 1),
-                       c=fold * c, kh=khs, kw=kws // fold, oh=oh, ow=ow,
-                       ow_p=ow_p, kw_fold=fold)
+                       c=hfold * fold * c, kh=khs // hfold, kw=kws // fold,
+                       oh=oh, ow=ow, ow_p=ow_p, kw_fold=fold, kh_fold=hfold)
 
 
 def contraction_block(c_blk: int, g: S2DGeometry) -> int:
     """The contraction block the kernel runs for a requested ``c_blk``: a
     folded layer's taps lie side by side in one whole-dim tile."""
-    return g.c if g.kw_fold > 1 else min(c_blk, g.c)
+    return g.c if g.taps_folded > 1 else min(c_blk, g.c)
 
 
-def conv_tile_geometry(oh: int, oh_blk: int, *, stride: int, kh: int,
+def conv_tile_geometry(oh: int, oh_blk: int, *, kh: int,
                        pool: Optional[str], pool_k: int, pool_s: int
                        ) -> Tuple[int, int, int, int, int]:
-    """Resolve the H-tiling geometry shared by kernel, tuner and tests.
+    """Resolve the H-tiling geometry of a stride-1 conv with ``kh`` row
+    taps, shared by kernel, tuner and tests.
 
     Returns ``(n_h, pr, oh_ext, hp_blk, row_step)``:
       n_h      number of H-tiles in the grid
@@ -158,8 +185,9 @@ def conv_tile_geometry(oh: int, oh_blk: int, *, stride: int, kh: int,
     With pooling it is rounded up to a multiple of ``pool_s`` so every pool
     window is computed by exactly one tile (windows that straddle the tile
     boundary are handled by recomputing ``pool_k - pool_s`` conv rows).
-    The kernel calls it with the space-to-depth taps (``stride=1``,
-    ``kh`` = :class:`S2DGeometry` ``kh``).
+    Kernel, tuner and verifier call it through
+    :meth:`S2DGeometry.tiles` (every row tap of the space-to-depth
+    conv).
     """
     oh_blk = min(oh_blk, oh) if oh_blk else oh
     oh_blk = max(1, oh_blk)
@@ -173,8 +201,8 @@ def conv_tile_geometry(oh: int, oh_blk: int, *, stride: int, kh: int,
         pr = oh_blk
         n_h = -(-oh // oh_blk)
         oh_ext = oh_blk
-    hp_blk = (oh_ext - 1) * stride + kh
-    row_step = oh_blk * stride
+    hp_blk = oh_ext + kh - 1
+    row_step = oh_blk
     return n_h, pr, oh_ext, hp_blk, row_step
 
 
@@ -190,15 +218,15 @@ def _space_to_depth(x: jax.Array, w: jax.Array, s: int, g: S2DGeometry
     x = x.reshape(G, B, g.h, s, -1, s, C).transpose(0, 1, 2, 4, 3, 5, 6)
     x = x.reshape(G, B, g.h, -1, s * s * C)
     _, KH, KW, _, M = w.shape
-    kws = g.kw * g.kw_fold                 # column taps before any fold
-    w = jnp.pad(w, ((0, 0), (0, g.kh * s - KH), (0, kws * s - KW), (0, 0),
+    khs, kws = g.kh * g.kh_fold, g.kw * g.kw_fold   # taps before any fold
+    w = jnp.pad(w, ((0, 0), (0, khs * s - KH), (0, kws * s - KW), (0, 0),
                     (0, 0)))
-    w = w.reshape(G, g.kh, s, kws, s, C, M).transpose(0, 1, 3, 2, 4, 5, 6)
-    return x, w.reshape(G, g.kh, kws, s * s * C, M)
+    w = w.reshape(G, khs, s, kws, s, C, M).transpose(0, 1, 3, 2, 4, 5, 6)
+    return x, w.reshape(G, khs, kws, s * s * C, M)
 
 
 def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
-                      ow_p: int, kw_fold: int, relu: bool,
+                      ow_p: int, kw_fold: int, kh_fold: int, relu: bool,
                       pool: Optional[str],
                       pool_k: int, pool_s: int, pr: int, n_c_tiles: int,
                       quantized: bool = False,
@@ -238,12 +266,17 @@ def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
     # axis is a free relayout; the tail columns are dropped below.
     # A folded layer (kw = 1) lays its kw_fold column-shifted windows side
     # by side in lanes, tap-major as the wrapper laid out w, so one dot a
-    # row tap contracts over all of them.
+    # row tap contracts over all of them; a row-folded one (kh = 1) lays
+    # the kh_fold row-shifted windows of that side by side again, row-tap
+    # major, so one dot contracts over every tap and the accumulator is
+    # read and written once a C-tile.
     for j in range(kw):
         xj = jnp.concatenate([x_ref[:, :, pl.ds(j + t, ow_p), :]
                               for t in range(kw_fold)], axis=-1)
         for i in range(kh):
-            patch = xj[:, i:i + oh_ext].reshape(rows, c_blk)
+            patch = jnp.concatenate([xj[:, i + r:i + r + oh_ext]
+                                     for r in range(kh_fold)], axis=-1)
+            patch = patch.reshape(rows, c_blk)
             acc_ref[...] += jax.lax.dot_general(
                 patch, w_ref[i, j], (((1,), (0,)), ((), ())),
                 precision=prec, preferred_element_type=acc_t)
@@ -350,9 +383,9 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
         x, w = _space_to_depth(x, w, stride, g)
     # right-pad W so every tap reads ow_p columns in bounds
     x = jnp.pad(x, ((0, 0),) * 3 + ((0, g.w - x.shape[3]), (0, 0)))
-    if g.kw_fold > 1:
-        # the column taps side by side in the contraction, tap-major
-        w = w.reshape(groups, g.kh, 1, g.c, mg)
+    # folded taps lie side by side in the contraction, (row tap, column
+    # tap, channel) major to minor; a no-op where nothing folds
+    w = w.reshape(groups, g.kh, g.kw, g.c, mg)
 
     c_blk = contraction_block(c_blk, g)
     m_blk = min(m_blk, mg)
@@ -370,9 +403,8 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     n_c, n_mg = cgp // c_blk, mgp // m_blk
     n_m = groups * n_mg
 
-    n_h, pr, oh_ext, hp_blk, row_step = conv_tile_geometry(
-        g.oh, oh_blk, stride=1, kh=g.kh,
-        pool=pool, pool_k=pool_k, pool_s=pool_s)
+    n_h, pr, oh_ext, hp_blk, row_step = g.tiles(
+        oh_blk, pool=pool, pool_k=pool_k, pool_s=pool_s)
 
     # batch folding: b_blk images share each grid step (0 = whole batch);
     # pad B up so the image-block axis tiles evenly (zero images, dropped)
@@ -387,7 +419,7 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
 
     kernel = functools.partial(
         _conv_pipe_kernel, oh_ext=oh_ext, ow=g.ow, ow_p=g.ow_p,
-        kw_fold=g.kw_fold, relu=relu,
+        kw_fold=g.kw_fold, kh_fold=g.kh_fold, relu=relu,
         pool=pool, pool_k=pool_k, pool_s=pool_s, pr=pr, n_c_tiles=n_c,
         quantized=quantized, out_scale=out_scale)
 
@@ -400,7 +432,7 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     # whole dim, so the lane offset is provably tile-aligned).
     x_spec = pl.BlockSpec(
         (None, pl.Element(b_blk), pl.Element(hp_blk), pl.Element(g.w),
-         pl.Element(c_blk // g.kw_fold)),
+         pl.Element(c_blk // g.taps_folded)),
         lambda bh, mi, ci: (mi // n_mg, (bh // n_h) * b_blk,
                             (bh % n_h) * row_step, 0,
                             ci * c_blk if n_c > 1 else 0))

@@ -74,6 +74,8 @@ spans and counters; :meth:`SpanLog.export` writes them through a
   py.gc.gen0       count  generation-0 collections (counted only)
   conv.kw_fold     count  ``compile_cnn``: a conv group whose column taps
                           ``conv_pipe`` folds into its contraction
+  conv.kh_fold     count  ``compile_cnn``: a conv group whose row taps
+                          fold into that contraction too
   conv.pool_fused  count  ``compile_cnn``: a conv group whose pool runs in
                           ``conv_pipe``'s epilogue
   ===============  =====  ===============================================

@@ -100,15 +100,19 @@ def _resolve_group_plans(cfg: CNNConfig, batch: int,
 
 def _count_conv_paths(cfg: CNNConfig, batch: int, dtype: str) -> None:
     """Count (``conv.kw_fold``) each conv group whose column taps
-    ``conv_pipe`` folds into its MXU contraction
-    (:func:`~repro.kernels.conv_pipe.s2d_geometry` decides), and
-    (``conv.pool_fused``) each whose pool runs in its epilogue."""
+    ``conv_pipe`` folds into its MXU contraction, (``conv.kh_fold``) each
+    whose row taps fold too (:func:`~repro.kernels.conv_pipe.s2d_geometry`
+    decides), and (``conv.pool_fused``) each whose pool runs in its
+    epilogue."""
     for _, kind, s in _group_shapes(cfg, batch, dtype):
         if kind != "conv":
             continue
-        if s2d_geometry(s.h, s.w, s.c // s.groups, s.kh, s.kw,
-                        stride=s.stride, pad=s.pad).kw_fold > 1:
+        g = s2d_geometry(s.h, s.w, s.c // s.groups, s.kh, s.kw,
+                         stride=s.stride, pad=s.pad)
+        if g.kw_fold > 1:
             SPANS.count("conv.kw_fold")
+        if g.kh_fold > 1:
+            SPANS.count("conv.kh_fold")
         if s.pool is not None:
             SPANS.count("conv.pool_fused")
 

@@ -196,7 +196,7 @@ def test_kw_fold_rule_on_alexnet():
                           stride=s.stride, pad=s.pad)
              for s in _conv_shapes(cfg)]
     assert [(g.c, g.kh, g.kw, g.kw_fold) for g in geoms] == [
-        (144, 3, 1, 3), (240, 5, 1, 5), (256, 3, 3, 1), (192, 3, 3, 1),
+        (432, 1, 1, 3), (240, 5, 1, 5), (256, 3, 3, 1), (192, 3, 3, 1),
         (192, 3, 3, 1)]
     params = jax.eval_shape(lambda: init_cnn_params(KEY, cfg))
     compiled = compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=32)),
@@ -204,10 +204,92 @@ def test_kw_fold_rule_on_alexnet():
     plans = {g: (p.c_blk, p.m_blk, p.oh_blk, p.b_blk)
              for g, p in compiled.group_plans.items()
              if isinstance(p, autotune.ConvPlan)}
-    assert plans[(0,)][0] == 144 and plans[(3,)][0] == 240
+    assert plans[(0,)][0] == 432 and plans[(3,)][0] == 240
     assert {g: plans[g] for g in ((6,), (7,), (8, 9))} == {
         (6,): (128, 384, 13, 4), (7,): (192, 192, 13, 4),
         (8, 9): (192, 128, 4, 16)}
+    assert compiled.verify() == []
+
+
+# ---------------------------------------------------------------------------
+# kh-tap folding: a narrow layer contracts over kh * kw * c in one dot
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,H,C,K,M,kw", [
+    # VGG-16 conv1_1 at a small H: 3 channels x 9 taps = 27
+    (2, 12, 3, 3, 16, dict(pad=1, oh_blk=4, c_blk=27, m_blk=16)),
+    # VGG-16 conv1_2 at a small H: 64 channels x 9 taps = 576
+    (2, 10, 64, 3, 16, dict(pad=1, oh_blk=4, b_blk=2, c_blk=576,
+                            m_blk=16)),
+    # AlexNet conv1: 11x11/4 space-to-depth'd to 48 channels x 9 taps
+    (2, 27, 3, 11, 16, dict(stride=4, oh_blk=2, c_blk=432, m_blk=16)),
+    # VGG's fused 2x2/2 max pool over H-tiles, b_blk 2 of a batch of 3
+    (3, 10, 64, 3, 64, dict(pad=1, pool="max", pool_k=2, pool_s=2,
+                            oh_blk=4, b_blk=2, c_blk=576, m_blk=64)),
+], ids=["conv1_1", "conv1_2", "conv1_s2d", "pool_tiled"])
+def test_kh_fold_matches_oracle(B, H, C, K, M, kw):
+    """The row taps fold on top of the column taps; the x tile's halo
+    still spans every unfolded row tap."""
+    g = _geometry(H, C, K, **kw)
+    assert (g.kh, g.kw) == (1, 1) and g.kh_fold > 1 and g.kw_fold > 1
+    _, _, oh_ext, hp_blk, _ = g.tiles(
+        kw["oh_blk"], pool=kw.get("pool"), pool_k=kw.get("pool_k", 2),
+        pool_s=kw.get("pool_s", 2))
+    assert hp_blk == oh_ext + -(-K // kw.get("stride", 1)) - 1
+    _check(B, H, C, K, M, **kw)
+
+
+def test_kh_fold_int8_bit_equal():
+    """A row-folded, pooled int8 layer (VGG conv1_2's shape at a small
+    H) equals the exact-int reference code for code."""
+    x = _rand((3, 10, 10, 64))
+    w = _rand((3, 3, 64, 64), scale=0.2)
+    b = _rand((64,), scale=0.1)
+    sx = float(abs_max_scale(x))
+    wq, ws = quantize_channelwise(w, axis=-1)
+    xq = quantize(x, sx)
+    kw = dict(pad=1, pool="max", pool_k=2, pool_s=2, out_scale=0.05)
+    assert _geometry(10, 64, 3, **kw).kh_fold == 3
+    got = conv_pipe(xq, wq, b, scale=ws * sx, c_blk=576, m_blk=64,
+                    oh_blk=4, b_blk=2, **kw)
+    want = qref.conv_int8_ref(xq, wq, b, ws * sx, **kw)
+    assert got.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_kh_fold_rule_on_vgg16():
+    """VGG-16 conv1_1, conv1_2 and conv2_1 fold their row taps; the other
+    ten conv groups (c >= 128) keep the batch-32 plans they had before
+    the fold, byte for byte; the static verifier re-proves every plan."""
+    from repro.models.cnn import init_cnn_params
+    from repro.pipeline import ExecutionSpec, Serving, compile_cnn
+
+    cfg = get_config("vgg16")
+    geoms = [s2d_geometry(s.h, s.w, s.c // s.groups, s.kh, s.kw,
+                          stride=s.stride, pad=s.pad)
+             for s in _conv_shapes(cfg)]
+    assert [(g.c, g.kh_fold) for g in geoms[:4]] == [
+        (27, 3), (576, 3), (576, 3), (128, 1)]
+    assert all(g.taps_folded == 1 for g in geoms[3:])
+    params = jax.eval_shape(lambda: init_cnn_params(KEY, cfg))
+    compiled = compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=32)),
+                           params, with_engine=False)
+    plans = {g: (p.c_blk, p.m_blk, p.oh_blk, p.b_blk, p.vmem_bytes)
+             for g, p in compiled.group_plans.items()
+             if isinstance(p, autotune.ConvPlan)}
+    assert [plans[g][0] for g in ((0,), (1, 2), (3,))] == [27, 576, 576]
+    wide = {
+        (4, 5): (128, 128, 16, 1, 9478144),
+        (6,): (128, 256, 8, 4, 16236544),
+        (7,): (128, 256, 8, 4, 16236544),
+        (8, 9): (256, 256, 8, 2, 13615104),
+        (10,): (256, 256, 4, 8, 16531456),
+        (11,): (256, 256, 4, 8, 16531456),
+        (12, 13): (256, 256, 4, 8, 16007168),
+        (14,): (128, 512, 14, 4, 16482304),
+        (15,): (128, 512, 14, 4, 16482304),
+        (16, 17): (128, 512, 14, 4, 15564800)}
+    assert {g: plans[g] for g in wide} == wide
     assert compiled.verify() == []
 
 
@@ -359,8 +441,7 @@ def test_tile_geometry_covers_output_exactly():
                 if pool and oh <= pk:
                     continue
                 n_h, pr, oh_ext, hp_blk, row_step = conv_tile_geometry(
-                    oh, oh_blk, stride=1, kh=3, pool=pool, pool_k=pk,
-                    pool_s=ps)
+                    oh, oh_blk, kh=3, pool=pool, pool_k=pk, pool_s=ps)
                 out_rows = (oh - pk) // ps + 1 if pool else oh
                 assert n_h * pr >= out_rows          # tiles cover the output
                 assert (n_h - 1) * pr < out_rows     # last tile is needed

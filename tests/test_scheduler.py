@@ -128,10 +128,12 @@ def test_scale_up_charges_restore_latency():
                   autoscale=AutoscalePolicy(min_replicas=1, max_replicas=2,
                                             interval=tr / 8))
     t_restore = eng._versions[eng._cur_version]["t_restore"]
-    # arrivals at exactly the single replica's capacity: slots saturate
-    # (util 1.0 > util_high), and the stream outlives the restore so
-    # later arrivals dispatch onto the scaled-up replica
-    done, rep = eng.serve([_req(i, t=i * tr / 4) for i in range(96)])
+    # arrivals at 1.25x the single replica's capacity: slots saturate
+    # (util 1.0 > util_high), a backlog builds, and the stream outlives
+    # the restore, so later arrivals dispatch onto the scaled-up replica
+    # (at exactly capacity the old replica keeps up, and whether the new
+    # one gets a request turns on a tie in the modeled clock)
+    done, rep = eng.serve([_req(i, t=i * tr / 5) for i in range(96)])
     assert sorted(c.rid for c in done) == list(range(96))
     assert rep.n_scale_up >= 1
     ups = [e for e in rep.scale_events if e["kind"] == "up"]

@@ -190,6 +190,27 @@ def test_kw_fold_counted_per_folded_conv_group(spans):
     assert "conv.kw_fold" not in spans.read()["counters"]
 
 
+@pytest.mark.parametrize("arch,folded", [("alexnet", 1), ("vgg16", 3)])
+def test_kh_fold_counted_per_row_folded_conv_group(spans, arch, folded):
+    """``compile_cnn`` counts ``conv.kh_fold`` once for each conv group
+    whose row taps fold too: AlexNet's conv1; VGG-16's conv1_1, conv1_2
+    and conv2_1; none in a net whose conv channels fill a lane tile."""
+    from repro.models.cnn import init_cnn_params
+
+    cfg = get_config(arch)
+    params = jax.eval_shape(lambda: init_cnn_params(jax.random.key(0), cfg))
+    compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=8)), params,
+                with_engine=False)
+    assert spans.read()["counters"].get("conv.kh_fold") == folded
+    spans.clear()
+    wide = CNNConfig(name="wide", input_hw=9, input_ch=128, n_classes=4,
+                     layers=(ConvLayer("conv", out_ch=128, kernel=3, pad=1),
+                             ConvLayer("fc", out_ch=4, relu=False)))
+    compile_cnn(wide, ExecutionSpec(serving=Serving(batch=2)),
+                key=jax.random.key(0), with_engine=False)
+    assert "conv.kh_fold" not in spans.read()["counters"]
+
+
 @pytest.mark.parametrize("arch,pooled", [("alexnet", 1), ("vgg16", 5)])
 def test_pool_fused_counted_per_pooled_conv_group(spans, arch, pooled):
     """``compile_cnn`` counts ``conv.pool_fused`` once for each conv group
