@@ -16,27 +16,8 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core.pipeline import (bandwidth_model, fusion_savings,
                                  im2col_gemm_traffic, measure_traffic)
-from repro.models.cnn import cnn_forward, init_cnn_params
-
-def _apply_conv(l, p, v, pool=None):
-    """Group-aware fused conv stage (AlexNet conv2/4/5 use groups=2)."""
-    import jax.numpy as jnp
-    from repro.kernels import ops as kops
-    kw = dict(stride=l.stride, pad=l.pad, relu=l.relu,
-              pool=(pool.pool if pool else None),
-              pool_k=(pool.kernel if pool else 2),
-              pool_s=(pool.stride if pool else 2))
-    g = l.groups
-    if g == 1:
-        return kops.fused_conv(v, p["w"], p["b"], **kw)
-    cg = v.shape[-1] // g
-    mg = l.out_ch // g
-    return jnp.concatenate([
-        kops.fused_conv(v[..., i * cg:(i + 1) * cg],
-                        p["w"][..., i * mg:(i + 1) * mg],
-                        p["b"][i * mg:(i + 1) * mg], **kw)
-        for i in range(g)], axis=-1)
-
+from repro.models.cnn import (cnn_forward_stage, fuse_plan,
+                              init_cnn_params, run_group)
 
 
 def main(csv=False):
@@ -71,31 +52,16 @@ def main(csv=False):
         x = jax.random.normal(key, (1, cfg.input_hw, cfg.input_hw,
                                     cfg.input_ch), jnp.float32)
         fused_b = measure_traffic(
-            lambda p, v: cnn_forward(p, v, cfg, fused=True), params, x)
-        # unfused: separate compilation per stage => forced HBM round trips
-        from repro.models.cnn import fuse_plan
-        from repro.kernels import ops as kops
-        from repro.kernels.ref import pool_ref
+            lambda p, v: cnn_forward_stage(p, v, cfg, fuse_plan(cfg)),
+            params, x)
+        # unfused: separate compilation per layer (singleton groups) =>
+        # forced HBM round trips
         unfused_b = 0.0
         h = x
-        for i, l in enumerate(cfg.layers):
-            p = params[i]
-            if l.kind == "conv":
-                fn = lambda v: _apply_conv(l, p, v)
-                unfused_b += measure_traffic(fn, h)
-                h = fn(h)
-            elif l.kind == "pool":
-                fn = lambda v: pool_ref(v, l.pool, l.kernel, l.stride)
-                unfused_b += measure_traffic(fn, h)
-                h = fn(h)
-            elif l.kind == "lrn":
-                unfused_b += measure_traffic(lambda v: kops.lrn(v), h)
-                h = kops.lrn(h)
-            else:
-                hf = h.reshape(1, -1)
-                fn = lambda v, w, b: kops.fc(v, w, b, relu=l.relu)
-                unfused_b += measure_traffic(fn, hf, p["w"], p["b"])
-                h = fn(hf, p["w"], p["b"])
+        for i in range(len(cfg.layers)):
+            fn = lambda p, v, g=(i,): run_group(p, v, cfg, g)
+            unfused_b += measure_traffic(fn, params, h)
+            h = fn(params, h)
         print(f"{name:8s}: fused {fused_b/1e6:7.1f} MB "
               f"unfused {unfused_b/1e6:7.1f} MB "
               f"(fusion saves {1-fused_b/max(unfused_b,1):.1%})")

@@ -15,10 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
-from repro.kernels import ops as kops
-from repro.kernels.ref import pool_ref
-from repro.models.cnn import fuse_plan, init_cnn_params
-from benchmarks.bandwidth import _apply_conv
+from repro.models.cnn import fuse_plan, init_cnn_params, run_group
 
 
 def stage_times(name: str, batch: int = 1, repeats: int = 2):
@@ -29,24 +26,9 @@ def stage_times(name: str, batch: int = 1, repeats: int = 2):
                                 cfg.input_ch), jnp.float32)
     rows = []
     for group in fuse_plan(cfg):
-        l = cfg.layers[group[0]]
-        p = params[group[0]]
         label = "+".join(cfg.layers[i].kind for i in group)
-
-        if l.kind == "conv":
-            pool = cfg.layers[group[1]] if len(group) == 2 else None
-            fn = jax.jit(lambda v: _apply_conv(l, p, v, pool))
-            args = (x,)
-        elif l.kind == "pool":
-            fn = jax.jit(lambda v: pool_ref(v, l.pool, l.kernel, l.stride))
-            args = (x,)
-        elif l.kind == "lrn":
-            fn = jax.jit(lambda v: kops.lrn(v))
-            args = (x,)
-        else:  # fc
-            xf = x.reshape(batch, -1)
-            fn = jax.jit(lambda v, w, b: kops.fc(v, w, b, relu=l.relu))
-            args = (xf, p["w"], p["b"])
+        fn = jax.jit(lambda p, v, g=group: run_group(p, v, cfg, g))
+        args = (params, x)
 
         out = fn(*args)
         jax.block_until_ready(out)
@@ -55,7 +37,7 @@ def stage_times(name: str, batch: int = 1, repeats: int = 2):
             jax.block_until_ready(fn(*args))
         dt = (time.perf_counter() - t0) / repeats
         rows.append({"stage": label, "ms": dt * 1e3})
-        x = out if l.kind != "fc" else out          # feed forward
+        x = out                                     # feed forward
     total = sum(r["ms"] for r in rows)
     for r in rows:
         r["share"] = r["ms"] / total

@@ -36,9 +36,10 @@ def run_arch(name: str, *, smoke: bool, n_calib: int, n_eval: int,
     import numpy as np
 
     from repro.configs import get_config
-    from repro.models.cnn import (_quant_groups, cnn_forward,
-                                  init_cnn_params)
-    from repro.quant import calibrate_cnn, dequantize, group_forward_ref
+    from repro.models.cnn import fuse_plan, init_cnn_params, run_group
+    from repro.pipeline import ExecutionSpec, Precision, Serving, compile_cnn
+    from repro.quant import (calibrate_cnn, dequantize, group_forward_ref,
+                             quantize)
     from repro.quant.ref import conv_fake_quant_ref
 
     cfg = get_config(name)
@@ -55,14 +56,24 @@ def run_arch(name: str, *, smoke: bool, n_calib: int, n_eval: int,
 
     qp = calibrate_cnn(params, calib, cfg)
 
+    def compiled(p, batch):
+        quant = "none" if p is params else "int8"
+        return compile_cnn(cfg, ExecutionSpec(
+            precision=Precision(quant=quant), serving=Serving(batch=batch),
+            use_pallas=use_pallas), p, with_engine=False)
+
     # -- per-layer output error on the calibration batch ------------------
     # (the final group's activations double as the logits for the calib
     # agreement below — no recomputation of either forward)
     fp_acts = {g: a for g, a in group_forward_ref(params, calib, cfg)}
     layer_err = {}
-    logits_q = None
     first_q = None
-    for g, q, s in _quant_groups(qp, calib, cfg, use_pallas=use_pallas):
+    plans = compiled(qp, n_calib).group_plans
+    q, s = quantize(calib, qp.in_scale), qp.in_scale
+    for g in fuse_plan(cfg):
+        q = run_group(qp, q, cfg, g, plans=plans, use_pallas=use_pallas)
+        if cfg.layers[g[0]].kind != "pool":   # pool passes the scale on
+            s = qp.layers[g[0]].y_scale
         got = dequantize(q, s) if s is not None else q
         want = fp_acts[g]
         err = float(jnp.linalg.norm(got - want)
@@ -71,7 +82,7 @@ def run_arch(name: str, *, smoke: bool, n_calib: int, n_eval: int,
         layer_err[f"{kinds}@{g[0]}"] = err
         if first_q is None:
             first_q = (q, s)
-        logits_q = q
+    logits_q = q
 
     # -- end-to-end argmax agreement --------------------------------------
     def agreement(y_fp, y_q):
@@ -79,10 +90,8 @@ def run_arch(name: str, *, smoke: bool, n_calib: int, n_eval: int,
 
     logits_fp = next(reversed(fp_acts.values()))
     agree_calib = agreement(logits_fp, logits_q)
-    agree_held = agreement(cnn_forward(params, held, cfg,
-                                       use_pallas=use_pallas),
-                           cnn_forward(qp, held, cfg,
-                                       use_pallas=use_pallas))
+    agree_held = agreement(compiled(params, n_eval).forward(held),
+                           compiled(qp, n_eval).forward(held))
 
     # -- fake-quant cross-check on the first conv group -------------------
     # fp32 math on fake-quantized operands vs the exact-int path, both

@@ -24,7 +24,8 @@ from repro.configs import get_config
 from repro.core.config import flops_per_image
 from repro.core.pipeline import fusion_savings
 from repro.core.roofline import HBM_BW, PEAK_FLOPS
-from repro.models.cnn import cnn_forward, init_cnn_params
+from repro.models.cnn import init_cnn_params
+from repro.pipeline import ExecutionSpec, Serving, compile_cnn
 
 PAPER = {
     "alexnet": {"ms": 43.0, "gops": 33.9},
@@ -38,11 +39,13 @@ def bench_model(name: str, batch: int = 1, repeats: int = 2):
     params = init_cnn_params(key, cfg)
     x = jax.random.normal(key, (batch, cfg.input_hw, cfg.input_hw,
                                 cfg.input_ch), jnp.float32)
-    fwd = jax.jit(lambda p, v: cnn_forward(p, v, cfg, use_pallas=False))
-    fwd(params, x).block_until_ready()              # compile
+    fwd = compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=batch),
+                                         use_pallas=False), params,
+                      with_engine=False).forward
+    fwd(x).block_until_ready()                      # compile
     t0 = time.perf_counter()
     for _ in range(repeats):
-        fwd(params, x).block_until_ready()
+        fwd(x).block_until_ready()
     cpu_s = (time.perf_counter() - t0) / repeats / batch
 
     ops = flops_per_image(cfg)

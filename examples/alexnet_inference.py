@@ -20,7 +20,8 @@ from repro.configs import get_config
 from repro.core.config import flops_per_image
 from repro.core.pipeline import fusion_savings
 from repro.data.pipeline import image_batches
-from repro.models.cnn import cnn_forward, init_cnn_params
+from repro.models.cnn import init_cnn_params
+from repro.pipeline import ExecutionSpec, Serving, compile_cnn
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--full", action="store_true")
@@ -32,16 +33,18 @@ key = jax.random.key(0)
 params = init_cnn_params(key, cfg)
 stream = image_batches(args.batch, cfg.input_hw, cfg.input_ch, 1000)
 
-fwd = jax.jit(lambda p, x: cnn_forward(p, x, cfg))
+fwd = compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=args.batch),
+                                     use_pallas=False), params,
+                  with_engine=False).forward
 batch = next(stream)
 x = jnp.asarray(batch["images"])
-fwd(params, x).block_until_ready()                    # compile
+fwd(x).block_until_ready()                            # compile
 
 t0 = time.perf_counter()
 n = 3
 for _ in range(n):
     batch = next(stream)
-    preds = jnp.argmax(fwd(params, jnp.asarray(batch["images"])), -1)
+    preds = jnp.argmax(fwd(jnp.asarray(batch["images"])), -1)
     preds.block_until_ready()
 dt = (time.perf_counter() - t0) / (n * args.batch)
 

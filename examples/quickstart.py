@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.kernels import autotune
 from repro.models import lm
-from repro.models.cnn import cnn_forward, init_cnn_params
+from repro.models.cnn import init_cnn_params
 from repro.pipeline import ExecutionSpec, Serving, compile_cnn
 from repro.train.steps import init_train_state, serve_decode, serve_prefill, \
     train_step
@@ -43,7 +43,9 @@ print(f"compiled: {compiled}")
 
 # RUN: the pallas kernel pipeline vs the XLA reference path
 logits_k = compiled.forward(images)
-logits = cnn_forward(aparams, images, acfg)          # legacy shim, XLA path
+logits = compile_cnn(acfg, ExecutionSpec(serving=Serving(batch=4),
+                                         use_pallas=False),
+                     aparams, with_engine=False).forward(images)
 print(f"logits {logits.shape}; pallas-vs-xla max diff "
       f"{float(jnp.max(jnp.abs(logits - logits_k))):.2e}")
 

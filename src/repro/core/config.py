@@ -262,7 +262,7 @@ class CNNConfig:
     # "none" = fp32; "int8" = calibrated symmetric int8 pipeline (int8
     # conv/FC kernels with int32 accumulation + requantize epilogues).
     # "int8" declares the model must be served from QuantizedCNNParams —
-    # cnn_forward raises if handed raw fp32 params (calibrate first).
+    # compile_cnn calibrates raw fp32 params under it.
     quant: str = "none"
     # calibration images the serving path synthesises when quant="int8"
     # and no QuantizedCNNParams / calibration batch is handed in; 0 means

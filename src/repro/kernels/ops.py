@@ -27,8 +27,8 @@ from repro.quant import ref as quant_ref
 # the public kernel-wrapper contract — tests/test_api_surface.py snapshots
 # this list so a refactor cannot silently drop or rename an entry point
 __all__ = [
-    "attention", "fc", "fc_q", "fused_conv", "fused_conv_q",
-    "get_interpret", "interpret_mode", "lrn",
+    "attention", "fc", "fused_conv", "get_interpret", "interpret_mode",
+    "lrn",
 ]
 
 
@@ -48,58 +48,40 @@ def _kernel_wrapper(static_argnames: Tuple[str, ...]) -> Callable:
 
 @_kernel_wrapper((
     "stride", "pad", "relu", "pool", "pool_k", "pool_s", "use_pallas",
-    "c_blk", "m_blk", "oh_blk", "b_blk", "groups", "plan"))
-def fused_conv(x, w, b, *, stride=1, pad=0, relu=True, pool=None,
-               pool_k=2, pool_s=2, use_pallas=False, c_blk=LANE,
-               m_blk=LANE, oh_blk=0, b_blk=1, groups=1, plan=None,
-               interpret=False):
+    "c_blk", "m_blk", "oh_blk", "b_blk", "groups", "plan", "out_scale"))
+def fused_conv(x, w, b, *, scale=None, out_scale=None, stride=1, pad=0,
+               relu=True, pool=None, pool_k=2, pool_s=2, use_pallas=False,
+               c_blk=LANE, m_blk=LANE, oh_blk=0, b_blk=1, groups=1,
+               plan=None, interpret=False):
     """Fused conv(+bias)(+ReLU)(+pool), grouped-conv and batch-fold aware.
 
     ``plan`` (a frozen :class:`repro.kernels.autotune.ConvPlan`) overrides
     the c_blk/m_blk/oh_blk/b_blk knobs with an autotuned point; being
     hashable it rides through jit as a static argument.
+
+    ``scale`` selects the int8 path: x/w are int8 codes, ``scale`` the
+    (M,) combined s_x*s_w requantize multiplier, and ``out_scale``
+    (static float) quantizes the output for the next layer (None emits
+    fp32). Its non-pallas path is the EXACT int32 reference
+    (``quant.ref.conv_int8_ref``), bit-equal to the kernel.
     """
     if plan is not None:
         c_blk, m_blk, oh_blk = plan.c_blk, plan.m_blk, plan.oh_blk
         b_blk = plan.b_blk
     if use_pallas:
-        return conv_pipe(x, w, b, stride=stride, pad=pad, relu=relu,
-                         pool=pool, pool_k=pool_k, pool_s=pool_s,
-                         c_blk=c_blk, m_blk=m_blk, oh_blk=oh_blk,
-                         b_blk=b_blk, groups=groups, interpret=interpret)
-    return ref.conv_pipe_ref(x, w, b, stride=stride, pad=pad, relu=relu,
-                             pool=pool, pool_k=pool_k, pool_s=pool_s,
-                             groups=groups)
-
-
-@_kernel_wrapper((
-    "stride", "pad", "relu", "pool", "pool_k", "pool_s", "use_pallas",
-    "c_blk", "m_blk", "oh_blk", "b_blk", "groups", "plan", "out_scale"))
-def fused_conv_q(x_q, w_q, b, scale, *, stride=1, pad=0, relu=True,
-                 pool=None, pool_k=2, pool_s=2, use_pallas=False,
-                 c_blk=LANE, m_blk=LANE, oh_blk=0, b_blk=1, groups=1,
-                 plan=None, out_scale=None, interpret=False):
-    """int8 fused conv: the fixed-point twin of :func:`fused_conv`.
-
-    x_q/w_q int8; b fp32; ``scale`` the (M,) combined s_x*s_w requantize
-    multiplier; ``out_scale`` (static float) quantizes the output for the
-    next layer, None emits fp32. The non-pallas path is the EXACT int32
-    reference (``quant.ref.conv_int8_ref``) — parity tests assert
-    bit-equality between the two.
-    """
-    if plan is not None:
-        c_blk, m_blk, oh_blk = plan.c_blk, plan.m_blk, plan.oh_blk
-        b_blk = plan.b_blk
-    if use_pallas:
-        return conv_pipe(x_q, w_q, b, scale=scale, out_scale=out_scale,
+        return conv_pipe(x, w, b, scale=scale, out_scale=out_scale,
                          stride=stride, pad=pad, relu=relu, pool=pool,
                          pool_k=pool_k, pool_s=pool_s, c_blk=c_blk,
                          m_blk=m_blk, oh_blk=oh_blk, b_blk=b_blk,
                          groups=groups, interpret=interpret)
-    return quant_ref.conv_int8_ref(x_q, w_q, b, scale, stride=stride,
-                                   pad=pad, relu=relu, pool=pool,
-                                   pool_k=pool_k, pool_s=pool_s,
-                                   groups=groups, out_scale=out_scale)
+    if scale is not None:
+        return quant_ref.conv_int8_ref(x, w, b, scale, stride=stride,
+                                       pad=pad, relu=relu, pool=pool,
+                                       pool_k=pool_k, pool_s=pool_s,
+                                       groups=groups, out_scale=out_scale)
+    return ref.conv_pipe_ref(x, w, b, stride=stride, pad=pad, relu=relu,
+                             pool=pool, pool_k=pool_k, pool_s=pool_s,
+                             groups=groups)
 
 
 @_kernel_wrapper(("use_pallas", "exact"))
@@ -109,29 +91,22 @@ def lrn(x, *, use_pallas=False, exact=False, interpret=False):
     return lrn_pwl(x, interpret=interpret)
 
 
-@_kernel_wrapper(("relu", "use_pallas", "bm", "bn", "bk"))
-def fc(x, w, b=None, *, relu=False, use_pallas=False,
-       bm=128, bn=128, bk=128, interpret=False):
+@_kernel_wrapper(("relu", "use_pallas", "bm", "bn", "bk", "out_scale"))
+def fc(x, w, b=None, *, scale=None, out_scale=None, relu=False,
+       use_pallas=False, bm=128, bn=128, bk=128, interpret=False):
+    """Batched FC: ``x @ w + b`` (+ReLU). ``scale``/``out_scale`` select
+    the int8 path as in :func:`fused_conv` (int32 accumulation, the
+    requantize epilogue; the exact int32 reference off the kernel)."""
     if use_pallas:
         if b is None:
             b = jnp.zeros((w.shape[1],), x.dtype)
-        return matmul_pipe(x, w, b, relu=relu, bm=bm, bn=bn, bk=bk,
-                           interpret=interpret)
-    return ref.matmul_pipe_ref(x, w, b, relu=relu)
-
-
-@_kernel_wrapper(("relu", "use_pallas", "bm", "bn", "bk", "out_scale"))
-def fc_q(x_q, w_q, b, scale, *, relu=False, use_pallas=False,
-         bm=128, bn=128, bk=128, out_scale=None, interpret=False):
-    """int8 batched-FC: int8 x/w, int32 accumulation, requantize epilogue.
-
-    The non-pallas path is the exact int32 reference (bit-equal parity)."""
-    if use_pallas:
-        return matmul_pipe(x_q, w_q, b, scale=scale, out_scale=out_scale,
+        return matmul_pipe(x, w, b, scale=scale, out_scale=out_scale,
                            relu=relu, bm=bm, bn=bn, bk=bk,
                            interpret=interpret)
-    return quant_ref.fc_int8_ref(x_q, w_q, b, scale, relu=relu,
-                                 out_scale=out_scale)
+    if scale is not None:
+        return quant_ref.fc_int8_ref(x, w, b, scale, relu=relu,
+                                     out_scale=out_scale)
+    return ref.matmul_pipe_ref(x, w, b, relu=relu)
 
 
 @_kernel_wrapper(("use_pallas", "bq", "bk"))

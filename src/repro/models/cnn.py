@@ -1,31 +1,36 @@
 """CNN model stack: AlexNet / VGG-16 through the PipeCNN fused pipeline.
 
-``cnn_forward`` executes the layer list with PipeCNN's stage grouping:
+:func:`run_group` executes one fusion group of ``fuse_plan(cfg)``:
 consecutive conv(+relu)+pool pairs run as ONE fused kernel (the paper's
 Conv->Pool channel), LRN runs as its own kernel off the pipeline (the paper
 implements LRN separately because of its multi-map access pattern), and FC
 layers run through the multi-mode engine in batched-FC mode.
+:func:`cnn_forward_stage` folds it over a slice of groups; the whole
+forward is ``repro.pipeline.compile_cnn(cfg, spec, params).forward``,
+which hands every group its compile-time tiling plan.
 
-Fixed-point serving (the paper's precision trade): hand ``cnn_forward`` a
-``repro.quant.QuantizedCNNParams`` (from ``calibrate_cnn``) and the same
-stage grouping executes in int8 — int8 activations flow between stages,
-conv/FC kernels accumulate in int32 and requantize in their epilogues,
-standalone max-pools run directly on the int8 codes, and LRN (the one
-genuinely nonlinear-in-scale stage) dequantizes around its kernel exactly
-as PipeCNN runs LRN off the fixed-point pipeline.
+One runner serves every precision; the params' type selects it. A plain
+param list runs fp32/bf16. A ``repro.quant.QuantizedCNNParams`` (from
+``calibrate_cnn``) runs the paper's fixed-point trade: int8 activations
+flow between stages, conv/FC kernels accumulate in int32 and requantize in
+their epilogues, standalone max-pools run directly on the int8 codes, and
+LRN (the one genuinely nonlinear-in-scale stage) dequantizes around its
+kernel exactly as PipeCNN runs LRN off the fixed-point pipeline.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.config import CNNConfig, ConvLayer
+from repro.core.config import CNNConfig
 from repro.kernels import ops
-from repro.kernels.autotune import plan_for_layer
+from repro.kernels.ref import pool_ref
 from repro.models.layers import dense_init
+from repro.quant.calibrate import QuantizedCNNParams
+from repro.quant.core import dequantize, quantize
 
 
 def init_cnn_params(key, cfg: CNNConfig) -> List[Dict[str, Any]]:
@@ -73,303 +78,76 @@ def fuse_plan(cfg: CNNConfig) -> List[Tuple[int, ...]]:
     return fuse_groups(cfg.layers)
 
 
-def _conv_group_kwargs(cfg: CNNConfig, l: ConvLayer, pool, *,
-                       use_pallas: bool) -> Dict[str, Any]:
-    """The per-conv-group knob dict SHARED by the fp32 and int8 paths —
-    one definition so tiling/plan selection can never diverge between the
-    two (the accuracy harness compares them layer for layer)."""
-    return dict(stride=l.stride, pad=l.pad, relu=l.relu,
-                pool=(pool.pool if pool else None),
-                pool_k=(pool.kernel if pool else 2),
-                pool_s=(pool.stride if pool else 2),
-                use_pallas=use_pallas, c_blk=cfg.vec_size,
-                m_blk=max(8, cfg.cu_num), oh_blk=cfg.oh_blk,
-                b_blk=cfg.b_blk, groups=l.groups)
-
-
-def _conv_group_plan(cfg: CNNConfig, l: ConvLayer, kw: Dict[str, Any],
-                     x_shape, w_shape, dtype: str):
-    """Per-layer DSE lookup: replace the global VEC_SIZE/CU_NUM point with
-    the tuned (b,c,m,oh)_blk plan for this shape. The batch in x_shape and
-    the compute dtype are both part of the cache key, so the serving path
-    retunes per micro-batch size and int8 gets its own plans."""
-    return plan_for_layer(
-        x_shape, w_shape, stride=l.stride, pad=l.pad, groups=l.groups,
-        pool=kw["pool"], pool_k=kw["pool_k"], pool_s=kw["pool_s"],
-        dtype=dtype, vmem_budget=cfg.vmem_budget)
-
-
-def _fc_block_kwargs(cfg: CNNConfig, *, m: int = 0, k: int = 0, n: int = 0,
-                     dtype: str = "float32",
-                     use_pallas: bool = False) -> Dict[str, int]:
-    """Batched-FC GEMM blocks (paper §IV batch-64 mode), shared by both
-    paths: bm covers the whole micro-batch so each weight tile fetched
-    from HBM is applied to every image before the next tile streams in.
-
-    With autotuning on and a concrete (m, k, n) GEMM, the blocks come
-    from the dtype-aware GEMM DSE (``autotune.gemm_plan_for_layer``) —
-    the classifier analogue of the conv plan lookup, closing the ROADMAP
-    item "int8 FC plans are untuned". The CNNConfig heuristics remain
-    the manual fallback.
-    """
-    if use_pallas and cfg.autotune and m and k and n:
-        from repro.kernels.autotune import gemm_plan_for_layer
-        gp = gemm_plan_for_layer(m, k, n, dtype=dtype,
-                                 vmem_budget=cfg.vmem_budget)
-        return dict(bm=gp.bm, bn=gp.bn, bk=gp.bk)
-    return dict(bm=max(128, cfg.serve_batch),
-                bk=128 * max(1, cfg.vec_size // 8),
-                bn=128 * max(1, cfg.cu_num // 8))
-
-
 def run_group(params, x: jax.Array, cfg: CNNConfig,
               group: Tuple[int, ...], *,
-              use_pallas: bool = False, plans=None) -> jax.Array:
-    """Execute ONE fusion group of the fp32 pipeline.
+              plans: Optional[Mapping[Tuple[int, ...], Any]] = None,
+              use_pallas: bool = False) -> jax.Array:
+    """Execute ONE fusion group — the one place a layer kind is run.
 
     This is the stage-sliceable unit the distributed serving engine
-    partitions over pipeline stages (``repro.serve.stage_planner``):
-    ``cnn_forward`` is exactly a fold of this function over
-    ``fuse_plan(cfg)``.
+    partitions over pipeline stages (``repro.serve.stage_planner``).
+    ``params`` is a fp32/bf16 param list, or a ``QuantizedCNNParams``
+    whose group runs on int8 codes: every scale it needs is static inside
+    the params, so a stage can start from any group boundary given that
+    boundary's codes.
 
-    ``plans`` is an optional frozen plan mapping ``group -> ConvPlan |
-    GemmPlan`` (a ``repro.pipeline.CompiledCNN``'s compile-time DSE
-    results); when present it REPLACES the per-trace registry lookup —
-    the compile-once contract. Without it the legacy behaviour stands:
-    the registry is consulted at trace time (memoised, keyed by shape/
-    dtype/batch).
+    With ``use_pallas``, a conv or fc group runs the tiling
+    ``plans[group]`` (a ``ConvPlan``/``GemmPlan`` that ``compile_cnn``
+    froze); the reference path needs no plans.
     """
+    quant = isinstance(params, QuantizedCNNParams)
     l = cfg.layers[group[0]]
-    p = params[group[0]]
-    if l.kind == "conv":
-        pool = cfg.layers[group[1]] if len(group) == 2 else None
-        kw = _conv_group_kwargs(cfg, l, pool, use_pallas=use_pallas)
-        if plans is not None and group in plans:
-            kw["plan"] = plans[group]
-        elif use_pallas and cfg.autotune:
-            kw["plan"] = _conv_group_plan(cfg, l, kw, x.shape,
-                                          p["w"].shape, cfg.dtype)
-        # grouped conv (AlexNet two-tower) runs INSIDE the one kernel:
-        # the M-tile grid axis spans groups, no concat on the hot path
-        return ops.fused_conv(x, p["w"], p["b"], **kw)
-    if l.kind == "pool":
-        from repro.kernels.ref import pool_ref
-        return pool_ref(x, l.pool, l.kernel, l.stride)
-    if l.kind == "lrn":
-        return ops.lrn(x, use_pallas=use_pallas)
-    if l.kind == "fc":
-        B = x.shape[0]
-        xf = x.reshape(B, -1)
-        if plans is not None and group in plans:
-            gp = plans[group]
-            blocks = dict(bm=gp.bm, bn=gp.bn, bk=gp.bk)
-        else:
-            blocks = _fc_block_kwargs(cfg, m=B, k=xf.shape[1],
-                                      n=p["w"].shape[1], dtype=cfg.dtype,
-                                      use_pallas=use_pallas)
-        return ops.fc(xf, p["w"], p["b"], relu=l.relu,
-                      use_pallas=use_pallas, **blocks)
-    raise ValueError(f"unknown layer kind {l.kind!r}")
-
-
-def cnn_forward_stage(params, x: jax.Array, cfg: CNNConfig,
-                      groups, *, use_pallas: bool = False,
-                      plans=None) -> jax.Array:
-    """Run a contiguous slice of fusion groups — one pipeline STAGE."""
-    for group in groups:
-        x = run_group(params, x, cfg, group, use_pallas=use_pallas,
-                      plans=plans)
-    return x
-
-
-def cnn_forward(params, x: jax.Array, cfg: CNNConfig, *,
-                use_pallas: bool = False, fused: bool = True) -> jax.Array:
-    """x (B, H, W, C) -> logits (B, n_classes).
-
-    DEPRECATION SHIM: the compile-once entry point is
-    ``repro.pipeline.compile_cnn(cfg, spec, params).forward(x)``; this
-    free function delegates to an internally-compiled single-replica
-    default (plan resolution at the incoming batch, so the plan choices
-    — and therefore the jit cache keys — are identical to the historical
-    per-trace registry lookups).
-
-    Quantize-then-forward: a ``QuantizedCNNParams`` routes to the int8
-    pipeline; a plain param list runs fp32/bf16. ``cfg.quant="int8"``
-    declares the model SHOULD be served fixed-point, so handing it raw
-    fp32 params is an error (calibrate first).
-    """
-    from repro.quant.calibrate import QuantizedCNNParams  # local: no cycle
-    quantized = isinstance(params, QuantizedCNNParams)
-    if not quantized and cfg.quant == "int8":
-        raise ValueError(
-            "cfg.quant='int8' but params are not QuantizedCNNParams; "
-            "run repro.quant.calibrate_cnn(params, calib_batch, cfg) first")
-    B = x.shape[0]
-    if fused and (cfg.b_blk <= 1 or B % cfg.b_blk == 0):
-        rcfg, plans = _shim_compile(cfg, B, use_pallas, quantized, params)
-        # fold directly over the compiled plans (NOT CompiledCNN.forward):
-        # the per-instance jit there would retrace the whole network on
-        # every shim call, whereas this fold reuses the module-level
-        # jitted ops' caches exactly like the pre-refactor path
-        run = cnn_forward_stage_quant if quantized else cnn_forward_stage
-        return run(params, x, rcfg, fuse_plan(rcfg),
-                   use_pallas=use_pallas, plans=plans)
-    # legacy direct fold: unfused layer-by-layer execution, or a manual
-    # b_blk that doesn't divide this batch (the kernel pads it)
-    if quantized:
-        return cnn_forward_quant(params, x, cfg, use_pallas=use_pallas)
-    plan = fuse_plan(cfg) if fused else [(i,) for i in range(len(cfg.layers))]
-    return cnn_forward_stage(params, x, cfg, plan, use_pallas=use_pallas)
-
-
-_SHIM_COMPILES: Dict[Tuple[Any, ...], Tuple[CNNConfig, dict]] = {}
-
-
-def _shim_compile(cfg: CNNConfig, B: int, use_pallas: bool,
-                  quantized: bool, params) -> Tuple[CNNConfig, dict]:
-    """The cnn_forward shim's internally-compiled default, memoised.
-
-    The frozen group plans depend only on (cfg shapes, batch, dtype,
-    use_pallas) — never on the parameter values — so one compile per
-    distinct key serves every forward (two dict lookups on the hot
-    path, like the pre-refactor registry); ``params`` ride through to
-    the compile on a miss but are NOT part of the key. The spec is
-    built from ONLY the precision/tiling fields a plain forward
-    consults: a plain forward must not be rejected over
-    serving/placement knobs it never runs (e.g. ``serve_microbatches``
-    on a single-replica cfg).
-    """
-    key = (cfg, B, use_pallas, quantized)
-    hit = _SHIM_COMPILES.get(key)
-    if hit is None:
-        from repro.pipeline import (ExecutionSpec, Precision, Serving,
-                                    Tiling, compile_cnn)
-        spec = ExecutionSpec(
-            # dtype pins float32 when quantized: the int8 pipeline's fp
-            # boundary is fp32 by construction and its plans key "int8"
-            precision=Precision(
-                dtype="float32" if quantized else cfg.dtype,
-                quant="int8" if quantized else "none",
-                calib=max(1, cfg.calib)),
-            tiling=Tiling(autotune=cfg.autotune,
-                          vmem_budget=cfg.vmem_budget,
-                          vec_size=cfg.vec_size, cu_num=cfg.cu_num,
-                          oh_blk=cfg.oh_blk, b_blk=cfg.b_blk),
-            serving=Serving(batch=B),
-            use_pallas=use_pallas)
-        compiled = compile_cnn(cfg, spec, params, with_engine=False)
-        hit = (compiled.cfg, compiled.group_plans)
-        _SHIM_COMPILES[key] = hit
-    return hit
-
-
-def run_group_quant(qp, q: jax.Array, cfg: CNNConfig,
-                    group: Tuple[int, ...], *,
-                    use_pallas: bool = False, plans=None) -> jax.Array:
-    """Execute ONE fusion group of the int8 pipeline on int8 codes.
-
-    The fixed-point twin of :func:`run_group` (and the quantized
-    stage-sliceable unit): every scale it needs is static inside ``qp``,
-    so a stage can start from any group boundary given that boundary's
-    int8 codes. ``plans`` as in :func:`run_group` (frozen compile-time
-    plans override the registry lookup).
-    """
-    from repro.kernels.ref import pool_ref
-    from repro.quant.core import dequantize, quantize
-
-    l = cfg.layers[group[0]]
-    ql = qp.layers[group[0]]
-    if l.kind == "conv":
-        pool = cfg.layers[group[1]] if len(group) == 2 else None
-        kw = _conv_group_kwargs(cfg, l, pool, use_pallas=use_pallas)
-        if plans is not None and group in plans:
-            kw["plan"] = plans[group]
-        elif use_pallas and cfg.autotune:
-            # dtype rides in the plan-cache key: int8 tiles are 4x
-            # smaller, so the tuner picks different (b,c,m,oh)_blk
-            # points than the fp32 plans for the same layer
-            kw["plan"] = _conv_group_plan(cfg, l, kw, q.shape,
-                                          ql.w_q.shape, "int8")
-        return ops.fused_conv_q(q, ql.w_q, ql.b, ql.scale,
-                                out_scale=ql.y_scale, **kw)
+    p = params.layers[group[0]] if quant else params[group[0]]
     if l.kind == "pool":
         # max-pool commutes with the int8 map: pool the codes, keep scale
-        return pool_ref(q, l.pool, l.kernel, l.stride)
+        return pool_ref(x, l.pool, l.kernel, l.stride)
     if l.kind == "lrn":
+        if not quant:
+            return ops.lrn(x, use_pallas=use_pallas)
         # LRN is nonlinear in scale — run it off the fixed-point
         # pipeline (as PipeCNN does) and requantize its output
-        xf = ops.lrn(dequantize(q, ql.x_scale), use_pallas=use_pallas)
-        return quantize(xf, ql.y_scale)
+        y = ops.lrn(dequantize(x, p.x_scale), use_pallas=use_pallas)
+        return quantize(y, p.y_scale)
+    plan = plans[group] if use_pallas else None
+    if quant:
+        w, b, epilogue = p.w_q, p.b, dict(scale=p.scale,
+                                           out_scale=p.y_scale)
+    else:
+        w, b, epilogue = p["w"], p["b"], {}
+    if l.kind == "conv":
+        pool = cfg.layers[group[1]] if len(group) == 2 else None
+        # grouped conv (AlexNet two-tower) runs INSIDE the one kernel:
+        # the M-tile grid axis spans groups, no concat on the hot path
+        return ops.fused_conv(x, w, b, stride=l.stride, pad=l.pad,
+                              relu=l.relu,
+                              pool=(pool.pool if pool else None),
+                              pool_k=(pool.kernel if pool else 2),
+                              pool_s=(pool.stride if pool else 2),
+                              use_pallas=use_pallas, groups=l.groups,
+                              plan=plan, **epilogue)
     if l.kind == "fc":
-        B = q.shape[0]
-        qf = q.reshape(B, -1)
-        if plans is not None and group in plans:
-            gp = plans[group]
-            blocks = dict(bm=gp.bm, bn=gp.bn, bk=gp.bk)
-        else:
-            blocks = _fc_block_kwargs(cfg, m=B, k=qf.shape[1],
-                                      n=ql.w_q.shape[1], dtype="int8",
-                                      use_pallas=use_pallas)
-        return ops.fc_q(qf, ql.w_q, ql.b, ql.scale,
-                        relu=l.relu, use_pallas=use_pallas,
-                        out_scale=ql.y_scale, **blocks)
+        blocks = ({} if plan is None
+                  else dict(bm=plan.bm, bn=plan.bn, bk=plan.bk))
+        return ops.fc(x.reshape(x.shape[0], -1), w, b, relu=l.relu,
+                      use_pallas=use_pallas, **blocks, **epilogue)
     raise ValueError(f"unknown layer kind {l.kind!r}")
 
 
-def cnn_forward_stage_quant(qp, q: jax.Array, cfg: CNNConfig,
-                            groups, *, use_pallas: bool = False,
-                            plans=None) -> jax.Array:
-    """Run a contiguous slice of int8 fusion groups — one pipeline STAGE.
-
-    ``q`` is the boundary activation: int8 codes (any interior boundary)
-    or the raw fp32 image batch for the first stage, which this function
-    quantizes at the network edge exactly like ``cnn_forward_quant``.
-    """
-    from repro.quant.core import quantize
-    if q.dtype != jnp.int8:
-        q = quantize(q, qp.in_scale)
-    for group in groups:
-        q = run_group_quant(qp, q, cfg, group, use_pallas=use_pallas,
-                            plans=plans)
-    return q
-
-
-def _quant_groups(qp, x: jax.Array, cfg: CNNConfig, *,
-                  use_pallas: bool = False):
-    """Run the int8 pipeline one fusion group at a time.
-
-    Yields ``(group, activation, scale)`` after every group — activation
-    is int8 codes with quantization step ``scale``, except the final
-    classifier group which emits fp32 logits with ``scale=None``. The
-    accuracy harness consumes the intermediates; ``cnn_forward_quant``
-    keeps only the last.
-    """
-    from repro.quant.core import quantize
-
-    q = quantize(x, qp.in_scale)
-    s = qp.in_scale
-    for group in fuse_plan(cfg):
-        l = cfg.layers[group[0]]
-        ql = qp.layers[group[0]]
-        q = run_group_quant(qp, q, cfg, group, use_pallas=use_pallas)
-        if l.kind != "pool":           # pool passes the scale through
-            s = ql.y_scale
-        yield group, q, s
-
-
-def cnn_forward_quant(qp, x: jax.Array, cfg: CNNConfig, *,
+def cnn_forward_stage(params, x: jax.Array, cfg: CNNConfig, groups, *,
+                      plans: Optional[Mapping[Tuple[int, ...], Any]] = None,
                       use_pallas: bool = False) -> jax.Array:
-    """int8 pipeline forward: x (B, H, W, C) fp32 -> fp32 logits.
+    """Run a contiguous slice of fusion groups — one pipeline STAGE.
 
-    ``qp`` is a :class:`repro.quant.QuantizedCNNParams` from
-    ``calibrate_cnn``. The input is quantized once at the network edge;
-    every inter-stage tensor is int8 until the final classifier, whose
-    ``y_scale=None`` keeps the logits fp32.
+    With ``QuantizedCNNParams``, ``x`` is the boundary activation: int8
+    codes (any interior boundary) or the raw fp32 image batch for the
+    first stage, which this function quantizes at the network edge.
     """
-    out = None
-    for _, out, _ in _quant_groups(qp, x, cfg, use_pallas=use_pallas):
-        pass
-    return out
+    if isinstance(params, QuantizedCNNParams) and x.dtype != jnp.int8:
+        x = quantize(x, params.in_scale)
+    for group in groups:
+        x = run_group(params, x, cfg, group, plans=plans,
+                      use_pallas=use_pallas)
+    return x
 
 
 def classification_flops(cfg: CNNConfig) -> int:

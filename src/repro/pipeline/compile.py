@@ -7,9 +7,11 @@ mesh placement — into a fixed execution plan, and a thin *run* phase
 that only enqueues work. ``compile_cnn(cfg, spec, params)`` is that
 compile step:
 
-  * runs the conv + GEMM DSE for every fusion group at the declared
-    serving batch and dtype (and at the DP super-batch / every GPipe
-    microbatch candidate, so no plan lookup is left for runtime);
+  * freezes one tiling plan per conv and fc fusion group at the declared
+    serving batch and dtype (the conv + GEMM DSE's, or ``Tiling``'s
+    manual knobs with autotune off), and tunes the DP super-batch and
+    every GPipe microbatch candidate too: ``models.cnn.run_group`` only
+    reads these plans, so no lookup is left for runtime;
   * runs int8 calibration when ``spec.precision.quant == "int8"`` and
     the params are not already quantized;
   * runs the stage planner and constructs the ``(data, pipe)`` device
@@ -20,9 +22,7 @@ compile step:
     performs ZERO sweeps (``autotune.sweep_stats`` proves it).
 
 ``CompiledCNN`` then exposes the whole runtime surface: ``.forward``,
-``.forward_stage``, ``.serve``, ``.plans``. The legacy free functions
-(``models.cnn.cnn_forward``, ``launch.serve_cnn.serve``) survive as
-shims delegating here.
+``.forward_stage``, ``.serve``, ``.plans``.
 """
 from __future__ import annotations
 
@@ -82,19 +82,31 @@ def _note_trace(x, placement: str) -> None:
     SPANS.count("cnn.retrace")
 
 
-def _resolve_group_plans(cfg: CNNConfig, batch: int,
-                         dtype: str) -> Dict[Tuple[int, ...], Any]:
-    """One DSE lookup per fusion group at (batch, dtype) — the frozen
-    plan mapping ``CompiledCNN.forward`` executes with. Registry-memoised:
-    a second compile over the same spec is pure cache hits."""
+def resolve_group_plans(cfg: CNNConfig, batch: int,
+                        dtype: str) -> Dict[Tuple[int, ...], Any]:
+    """The plan of every conv and fc fusion group at (batch, dtype) — the
+    frozen mapping ``run_group`` executes with. With ``cfg.autotune`` on,
+    one DSE lookup per group (registry-memoised: a second compile over
+    the same spec is pure cache hits); off, the manual VEC_SIZE/CU_NUM
+    knobs, as plans."""
     plans: Dict[Tuple[int, ...], Any] = {}
     for group, kind, shape in _group_shapes(cfg, batch, dtype):
         if kind == "conv":
-            plans[group] = autotune.get_plan(
-                shape, vmem_budget=cfg.vmem_budget)
+            plans[group] = (
+                autotune.get_plan(shape, vmem_budget=cfg.vmem_budget)
+                if cfg.autotune else autotune.ConvPlan(
+                    c_blk=cfg.vec_size, m_blk=max(8, cfg.cu_num),
+                    oh_blk=cfg.oh_blk, b_blk=cfg.b_blk))
         else:
-            plans[group] = autotune.get_gemm_plan(
-                shape, vmem_budget=cfg.vmem_budget)
+            # manual batched-FC blocks (paper §IV batch-64 mode): bm
+            # covers the micro-batch, so each weight tile fetched from
+            # HBM is applied to every image before the next streams in
+            plans[group] = (
+                autotune.get_gemm_plan(shape, vmem_budget=cfg.vmem_budget)
+                if cfg.autotune else autotune.GemmPlan(
+                    bm=max(128, cfg.serve_batch),
+                    bn=128 * max(1, cfg.cu_num // 8),
+                    bk=128 * max(1, cfg.vec_size // 8)))
     return plans
 
 
@@ -187,14 +199,13 @@ class CompiledCNN:
     def _single_forward(self):
         """The jitted whole-network fold over the frozen plan table."""
         if self._fwd is None:
-            from repro.models.cnn import (cnn_forward_stage,
-                                          cnn_forward_stage_quant)
+            from repro.models.cnn import cnn_forward_stage
             cfg, groups, plans = self.cfg, self._fuse, self.group_plans
-            up, quant, mode = self.spec.use_pallas, self.quant, self.mode
+            up, mode = self.spec.use_pallas, self.mode
 
             def fold(p, x):
-                run = cnn_forward_stage_quant if quant else cnn_forward_stage
-                return run(p, x, cfg, groups, use_pallas=up, plans=plans)
+                return cnn_forward_stage(p, x, cfg, groups, plans=plans,
+                                         use_pallas=up)
 
             if self.spec.placement.replicas > 1 and self.mesh is not None:
                 from repro.parallel.sharding import data_parallel
@@ -214,13 +225,13 @@ class CompiledCNN:
             from repro.serve.engine import pipeline_logits
             cfg, mesh, sp = self.cfg, self.mesh, self.stage_plan
             n_micro, up = self.engine.n_micro, self.spec.use_pallas
-            quant, mode = self.quant, self.mode
+            plans, mode = self.engine.micro_plans, self.mode
 
             def f(p, x):
                 _note_trace(x, mode)
                 return pipeline_logits(p, x, cfg, mesh, sp,
                                        n_microbatches=n_micro,
-                                       use_pallas=up, quant=quant,
+                                       plans=plans, use_pallas=up,
                                        dp_axis="data")
 
             self._pp_fwd = jax.jit(f)
@@ -303,15 +314,12 @@ class CompiledCNN:
         boundaries of a quantized pipeline (the raw fp32 batch for stage
         0, which quantizes at the network edge), fp32 otherwise.
         """
-        from repro.models.cnn import (cnn_forward_stage,
-                                      cnn_forward_stage_quant)
-        groups = self.stages[i]
+        from repro.models.cnn import cnn_forward_stage
         with self._ctx():
-            run = cnn_forward_stage_quant if self.quant else \
-                cnn_forward_stage
-            return run(self.params, h, self.cfg, groups,
-                       use_pallas=self.spec.use_pallas,
-                       plans=self.group_plans)
+            return cnn_forward_stage(self.params, h, self.cfg,
+                                     self.stages[i],
+                                     plans=self.group_plans,
+                                     use_pallas=self.spec.use_pallas)
 
     def serve(self, requests: List, *, faults=None, trace=None,
               metrics=None):
@@ -333,7 +341,8 @@ class CompiledCNN:
         if self.engine is None:
             from repro.serve.engine import ServeEngine
             self.engine = ServeEngine.from_spec(self.cfg, self.params,
-                                                self.spec)
+                                                self.spec,
+                                                plans=self.group_plans)
         if trace is not None:
             trace.set_meta("compiled", repr(self))
             trace.set_meta("plan_provenance", self.plan_table.provenance)
@@ -501,9 +510,9 @@ def compile_cnn(cfg: CNNConfig, spec: Optional[ExecutionSpec] = None,
     resolve, one ``measure`` span per profiled plan — the compile-side
     half of the serving timeline.
 
-    ``with_engine=False`` skips serving-engine/mesh construction (used
-    by the ``cnn_forward`` shim, which only needs ``.forward``); the
-    engine is then built lazily on first ``.serve``.
+    ``with_engine=False`` skips serving-engine/mesh construction (for a
+    single-device forward, which needs none); the engine is then built
+    lazily on first ``.serve``.
     """
     import time as _time
 
@@ -565,23 +574,22 @@ def compile_cnn(cfg: CNNConfig, spec: Optional[ExecutionSpec] = None,
         group_plans: Dict[Tuple[int, ...], Any] = {}
         if spec.use_pallas:
             _count_conv_paths(rcfg, spec.serving.batch, spec.run_dtype)
-        if spec.use_pallas and spec.tiling.autotune:
-            group_plans = _resolve_group_plans(
+            group_plans = resolve_group_plans(
                 rcfg, spec.serving.batch, spec.run_dtype)
             R, S = spec.placement.replicas, spec.placement.pp_stages
             if R > 1 and S == 1:
-                # the dp gang round runs the fold on the packed
-                # (R * batch) super-batch — resolve those plans now too,
-                # not at first-serve trace time
-                _resolve_group_plans(rcfg, R * spec.serving.batch,
-                                     spec.run_dtype)
+                # the packed (R * batch) super-batch's tuned plans are
+                # part of a dp compile's plan table
+                resolve_group_plans(rcfg, R * spec.serving.batch,
+                                    spec.run_dtype)
 
         engine = None
         if with_engine:
             from repro.serve.engine import ServeEngine
             # stage planning (incl. the GPipe microbatch sweep) and mesh
             # construction happen HERE, inside the compile
-            engine = ServeEngine.from_spec(rcfg, params, spec)
+            engine = ServeEngine.from_spec(rcfg, params, spec,
+                                           plans=group_plans)
 
     sweeps_after = autotune.sweep_stats()
     sweep_delta = {k: sweeps_after[k] - sweeps_before[k]

@@ -254,9 +254,9 @@ class ExecutionSpec:
 def spec_from_config(cfg: CNNConfig, **overrides) -> ExecutionSpec:
     """Lift a legacy knob-sprawl CNNConfig into an ExecutionSpec.
 
-    The inverse of :func:`resolve_config`; the deprecation shims
-    (``models.cnn.cnn_forward``, ``launch.serve_cnn.serve``) use it to
-    route old call sites through the compile-once path unchanged.
+    The inverse of :func:`resolve_config`; ``compile_cnn`` without a
+    spec and the ``launch.serve_cnn.serve`` shim use it to route
+    config-only call sites through the compile-once path.
     ``overrides`` replace top-level ExecutionSpec fields (e.g.
     ``use_pallas=...``) or whole sub-specs.
     """

@@ -16,7 +16,7 @@ This package holds the one quantization codepath repo-wide:
 The execution side lives with the kernels: ``kernels.conv_pipe`` /
 ``kernels.matmul_pipe`` take int8 operands with a ``scale`` vector and a
 static ``out_scale`` and fuse the requantize -> bias -> ReLU -> pool
-epilogue; ``models.cnn.cnn_forward`` auto-routes when handed a
+epilogue; ``models.cnn.run_group`` runs a group in int8 when handed a
 :class:`QuantizedCNNParams`.
 """
 from repro.quant.calibrate import (QuantizedCNNParams, QuantLayer,

@@ -26,9 +26,8 @@ serving path.
 """
 from __future__ import annotations
 
-import dataclasses
-from dataclasses import dataclass, field
-from typing import Any, Iterable, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,32 +89,18 @@ jax.tree_util.register_pytree_node(QuantizedCNNParams, _qp_flatten,
 
 def group_forward_ref(params, x: jax.Array, cfg
                       ) -> Iterable[Tuple[Tuple[int, ...], jax.Array]]:
-    """fp32 reference forward, one fusion group at a time.
+    """Reference forward (no Pallas), one fusion group at a time.
 
     Yields ``(group, activation_after_group)`` for every group of
     ``fuse_plan(cfg)`` — the boundaries the activation observers watch
-    (and the per-layer comparison points of the accuracy harness).
+    (and the per-layer comparison points of the accuracy harness). With
+    ``QuantizedCNNParams`` the activations are the int8 pipeline's
+    (codes, fp32 logits after the classifier).
     """
-    from repro.kernels import ref
-    from repro.models.cnn import fuse_plan
+    from repro.models.cnn import cnn_forward_stage, fuse_plan
 
     for group in fuse_plan(cfg):
-        l = cfg.layers[group[0]]
-        p = params[group[0]]
-        if l.kind == "conv":
-            pool = cfg.layers[group[1]] if len(group) == 2 else None
-            x = ref.conv_pipe_ref(
-                x, p["w"], p["b"], stride=l.stride, pad=l.pad, relu=l.relu,
-                pool=(pool.pool if pool else None),
-                pool_k=(pool.kernel if pool else 2),
-                pool_s=(pool.stride if pool else 2), groups=l.groups)
-        elif l.kind == "pool":
-            x = ref.pool_ref(x, l.pool, l.kernel, l.stride)
-        elif l.kind == "lrn":
-            x = ref.lrn_ref(x)
-        elif l.kind == "fc":
-            x = ref.matmul_pipe_ref(x.reshape(x.shape[0], -1), p["w"],
-                                    p["b"], relu=l.relu)
+        x = cnn_forward_stage(params, x, cfg, (group,))
         yield group, x
 
 

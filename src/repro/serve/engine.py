@@ -22,9 +22,15 @@ device mesh:
 CNN stages change activation shape, so pipeline activations travel in a
 canonical flat fp32 buffer (max boundary elements wide); each stage's
 branch (``jax.lax.switch`` on the stage index) unflattens its static
-input shape, runs its fusion groups (``models.cnn.cnn_forward_stage`` /
-``..._stage_quant``), and re-flattens. int8 codes ride the fp32 buffer
-exactly (|code| <= 127), keeping the quantized pipeline bit-exact.
+input shape, runs its fusion groups (``models.cnn.cnn_forward_stage``)
+at the plans the stage planner tuned for the microbatch, and re-flattens.
+int8 codes ride the fp32 buffer exactly (|code| <= 127), keeping the
+quantized pipeline bit-exact.
+
+Every forward the engine runs folds ``models.cnn.cnn_forward_stage`` over
+compile-time plans: the serving batch's come from ``compile_cnn`` (or are
+resolved when the engine is built directly), the microbatch's from the
+stage plan, and a hot-swapped version brings its own.
 
 Scheduling reuses the single-replica launcher's simulated clock as a
 fleet discrete-event loop: arrivals are admitted to the least-loaded
@@ -71,12 +77,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.config import CNNConfig
-from repro.models.cnn import (cnn_forward, cnn_forward_stage,
-                              cnn_forward_stage_quant)
+from repro.models.cnn import cnn_forward_stage, fuse_plan
 from repro.obs.metrics import MetricsRegistry, record_report
 from repro.obs.trace import (CAT_REQUEST, FLEET_TRACK, TraceRecorder)
 from repro.parallel.pipeline_par import pipeline_forward_stages
 from repro.parallel.sharding import batch_sharding, data_parallel
+from repro.quant.calibrate import QuantizedCNNParams
 from repro.serve.faults import FaultSchedule
 from repro.serve.report import FleetReport, fleet_report
 from repro.serve.router import Completion, Request, Router
@@ -153,29 +159,28 @@ def _prod(shape) -> int:
 
 
 def make_stage_branches(params, cfg: CNNConfig, stage_plan: StagePlan, *,
-                        use_pallas: bool, quant: bool, maxe: int):
+                        plans, use_pallas: bool, maxe: int):
     """One ``buf (rows, maxe) -> buf`` branch per pipeline stage.
 
     Each branch has static interior shapes (its stage's boundary
     activation); `jax.lax.switch` over the traced stage index dispatches
-    among them inside the shard_map body.
+    among them inside the shard_map body. ``plans`` are the groups'
+    plans at the microbatch.
     """
+    quant = isinstance(params, QuantizedCNNParams)
+
     def branch_for(si: int, stage):
         n_in = _prod(stage.in_shape)
 
         def br(buf):
             x = buf[:, :n_in].reshape(buf.shape[0], *stage.in_shape)
-            if quant:
+            if quant and si > 0:
                 # interior boundaries carry int8 codes in the fp32
                 # buffer (exact: |code| <= 127); the first stage gets
                 # the raw fp32 image and quantizes at the network edge
-                if si > 0:
-                    x = x.astype(jnp.int8)
-                out = cnn_forward_stage_quant(params, x, cfg, stage.groups,
-                                              use_pallas=use_pallas)
-            else:
-                out = cnn_forward_stage(params, x, cfg, stage.groups,
-                                        use_pallas=use_pallas)
+                x = x.astype(jnp.int8)
+            out = cnn_forward_stage(params, x, cfg, stage.groups,
+                                    plans=plans, use_pallas=use_pallas)
             flat = out.reshape(out.shape[0], -1).astype(jnp.float32)
             return jnp.pad(flat, ((0, 0), (0, maxe - flat.shape[1])))
 
@@ -186,21 +191,24 @@ def make_stage_branches(params, cfg: CNNConfig, stage_plan: StagePlan, *,
 
 def pipeline_logits(params, x: jax.Array, cfg: CNNConfig, mesh,
                     stage_plan: StagePlan, *, n_microbatches: int,
-                    use_pallas: bool = True, quant: bool = False,
+                    plans=None, use_pallas: bool = True,
                     dp_axis: Optional[str] = None,
                     axis: str = "pipe") -> jax.Array:
     """Run a (B, H, W, C) batch through device-resident pipeline stages.
 
     Returns (B, n_classes) logits — numerically identical to the
-    unsharded ``cnn_forward`` (fp32 allclose; int8 bit-exact, since the
-    stage slicing changes scheduling, never math). ``B`` must divide
-    into ``n_microbatches`` (times the dp_axis size, if given).
+    unsharded forward (fp32 allclose; int8 bit-exact, since the stage
+    slicing changes scheduling, never math). ``B`` must divide into
+    ``n_microbatches`` (times the dp_axis size, if given). ``plans``
+    (default: the ones ``stage_plan`` tuned) tile the groups at the
+    microbatch.
     """
     n_out = _prod(stage_plan.stages[-1].out_shape)
     maxe = max(stage_plan.max_boundary_elems(), n_out)
-    branches = make_stage_branches(params, cfg, stage_plan,
-                                   use_pallas=use_pallas, quant=quant,
-                                   maxe=maxe)
+    if plans is None:
+        plans = stage_plan.group_plans()
+    branches = make_stage_branches(params, cfg, stage_plan, plans=plans,
+                                   use_pallas=use_pallas, maxe=maxe)
 
     def stage_fn(idx, h):
         return jax.lax.switch(idx, branches, h)
@@ -217,8 +225,9 @@ class ServeEngine:
     """Routes request traffic onto a mesh of CNN replicas.
 
     ``params`` may be the fp32 param list or a ``QuantizedCNNParams``
-    (the engine auto-detects and serves fixed-point, like
-    ``cnn_forward``).
+    (the engine auto-detects and serves fixed-point). ``plans`` are the
+    groups' tiling plans at ``batch`` (``CompiledCNN.group_plans``);
+    without them an executing Pallas engine resolves its own here.
     """
 
     def __init__(self, cfg: CNNConfig, params, *, batch: int = 8,
@@ -228,8 +237,7 @@ class ServeEngine:
                  execute: bool = True, retries: int = 0,
                  backoff: float = 0.0, slo: float = 0.0,
                  scheduler: str = "gang", steal_threshold: int = 0,
-                 autoscale=None):
-        from repro.quant.calibrate import QuantizedCNNParams
+                 autoscale=None, plans=None):
         from repro.serve.scheduler import AutoscalePolicy
         if clock not in ("measured", "modeled"):
             raise ValueError(f"unknown clock {clock!r}")
@@ -319,6 +327,11 @@ class ServeEngine:
             # one replica's micro-batch; dp replicas run concurrently
             self.t_round_model = total_cost(cfg, batch, dtype=self.dtype)
         self.mb = batch // self.n_micro
+        if plans is None:
+            plans = self._resolve_plans(cfg, batch, self.dtype)
+        self.plans = plans
+        self.micro_plans = self._micro_plans(cfg, self.stage_plan,
+                                             self.dtype, plans)
         # the elastic fleet pre-builds queues up to max_replicas; the
         # scheduler's active mask decides which ones receive dispatch
         n_queues = (autoscale.max_replicas if autoscale is not None
@@ -330,6 +343,8 @@ class ServeEngine:
         self._cur_version = 0
         self._n_versions = 1
         self._versions = {0: dict(params=params, quant=self.quant, cfg=cfg,
+                                  plans=self.plans,
+                                  micro_plans=self.micro_plans,
                                   stage_plan=self.stage_plan,
                                   t_round=self.t_round_model,
                                   t_restore=self.t_restore_model)}
@@ -356,12 +371,14 @@ class ServeEngine:
         # elastically scale past the device count
 
     @classmethod
-    def from_spec(cls, cfg: CNNConfig, params, spec) -> "ServeEngine":
+    def from_spec(cls, cfg: CNNConfig, params, spec, *,
+                  plans=None) -> "ServeEngine":
         """Build the engine from a ``repro.pipeline.ExecutionSpec`` —
         the placement/serving sub-specs are the engine's whole
-        constructor surface (``compile_cnn`` calls this so the mesh and
-        stage plan are resolved at compile time)."""
-        return cls(cfg, params, batch=spec.serving.batch,
+        constructor surface (``compile_cnn`` calls this, with its group
+        plans, so the mesh and stage plan are resolved at compile
+        time)."""
+        return cls(cfg, params, plans=plans, batch=spec.serving.batch,
                    replicas=spec.placement.replicas,
                    pp_stages=spec.placement.pp_stages,
                    n_microbatches=spec.placement.microbatches,
@@ -376,30 +393,48 @@ class ServeEngine:
                                            "steal_threshold", 0),
                    autoscale=getattr(spec.serving, "autoscale", None))
 
+    # -- plans -------------------------------------------------------------
+
+    def _resolve_plans(self, cfg: CNNConfig, batch: int, dtype: str):
+        """Group plans at ``batch`` for an engine that runs Pallas
+        forwards; none for the reference path or a device-free run."""
+        if not (self.execute and self.use_pallas):
+            return {}
+        from repro.pipeline.compile import resolve_group_plans
+        return resolve_group_plans(cfg, batch, dtype)
+
+    def _micro_plans(self, cfg: CNNConfig, sp: Optional[StagePlan],
+                     dtype: str, plans):
+        """Group plans at the pipeline microbatch: those the stage
+        planner tuned, or the manual knobs with autotune off; without
+        pipeline stages, the serving batch's ``plans``."""
+        if sp is None:
+            return plans
+        if cfg.autotune:
+            return sp.group_plans()
+        return self._resolve_plans(cfg, self.mb, dtype)
+
     # -- forward builders --------------------------------------------------
 
-    def _build_round_fn(self, params=None, quant=None, stage_plan=None,
-                        cfg=None):
-        """Gang-round fn ``imgs -> preds`` for one params version.
+    def _build_round_fn(self, v: int = 0):
+        """Gang-round fn ``imgs -> preds`` for params version ``v``.
 
-        With no arguments this builds the originally-compiled version;
-        ``hot_swap`` builds the replacement's fn from its own params /
-        stage plan (same mesh, same microbatch split — only the weights
-        and their dtype change under a rolling upgrade).
+        ``hot_swap`` builds the replacement's fn from its own params,
+        plans and stage plan (same mesh, same microbatch split — only
+        the weights and their dtype change under a rolling upgrade).
         """
-        params = self.params if params is None else params
-        quant = self.quant if quant is None else quant
-        cfg = self.cfg if cfg is None else cfg
-        R = self.replicas
+        rec = self._versions[v]
+        params, cfg = rec["params"], rec["cfg"]
 
         # params are jit ARGUMENTS, never closed over: a closure would
         # bake every weight into the program as a constant
         if self.pp_stages == 1:
+            groups, plans = fuse_plan(cfg), rec["plans"]
+
             def preds(p, imgs):          # (R*batch, H, W, C)
-                # repro: allow[RPA201] the shim IS the parity oracle here
-                return jnp.argmax(cnn_forward(p, imgs, cfg,
-                                              use_pallas=self.use_pallas),
-                                  -1)
+                return jnp.argmax(cnn_forward_stage(
+                    p, imgs, cfg, groups, plans=plans,
+                    use_pallas=self.use_pallas), -1)
             if self.mesh is None:
                 fn = jax.jit(preds)
                 return lambda imgs: fn(params, imgs)
@@ -411,13 +446,13 @@ class ServeEngine:
                 return fn(params, sharded)
             return dp_round
 
-        sp = self.stage_plan if stage_plan is None else stage_plan
+        sp, plans = rec["stage_plan"], rec["micro_plans"]
 
         def pp_fn(p, imgs_flat):        # (n_micro*R*mb, H, W, C)
             logits = pipeline_logits(
                 p, imgs_flat, cfg, self.mesh, sp,
-                n_microbatches=self.n_micro, use_pallas=self.use_pallas,
-                quant=quant, dp_axis="data")
+                n_microbatches=self.n_micro, plans=plans,
+                use_pallas=self.use_pallas, dp_axis="data")
             return jnp.argmax(logits, -1)
         pp = jax.jit(pp_fn)
         return lambda imgs: pp(params, imgs)
@@ -427,16 +462,17 @@ class ServeEngine:
         for params version ``v`` — the continuous scheduler's execution
         unit. No mesh: one admission group runs the full (batched/int8)
         pipeline on the default device, row-independent, so predictions
-        match ``cnn_forward`` exactly while replica count floats free
-        of the device count."""
+        match the unsharded forward exactly while replica count floats
+        free of the device count."""
         if v not in self._slot_fns:
             rec = self._versions[v]
             params, cfg = rec["params"], rec["cfg"]
+            groups, plans = fuse_plan(cfg), rec["plans"]
 
             def fn(p, imgs):
-                # repro: allow[RPA201] the shim IS the parity oracle here
-                logits = cnn_forward(p, imgs, cfg,
-                                     use_pallas=self.use_pallas)
+                logits = cnn_forward_stage(p, imgs, cfg, groups,
+                                           plans=plans,
+                                           use_pallas=self.use_pallas)
                 return jnp.argmax(logits, -1)
             jitted = jax.jit(fn)
             self._slot_fns[v] = lambda imgs: jitted(params, imgs)
@@ -444,10 +480,7 @@ class ServeEngine:
 
     def _version_fn(self, v: int):
         if v not in self._round_fns:
-            rec = self._versions[v]
-            self._round_fns[v] = self._build_round_fn(
-                params=rec["params"], quant=rec["quant"],
-                stage_plan=rec["stage_plan"], cfg=rec["cfg"])
+            self._round_fns[v] = self._build_round_fn(v)
         return self._round_fns[v]
 
     def _pack(self, round_items) -> np.ndarray:
@@ -482,8 +515,8 @@ class ServeEngine:
     def hot_swap(self, artifact, *, at: float = 0.0) -> int:
         """Register a rolling upgrade to ``artifact``'s params.
 
-        ``artifact`` is a ``CompiledCNN`` (``.quant`` params win over
-        ``.params`` when present, matching how it serves) or a bare
+        ``artifact`` is a ``CompiledCNN`` (its params, and its group
+        plans where it was compiled for this engine's batch) or a bare
         params pytree. The next ``serve`` call executes the roll inside
         its discrete-event loop, starting at simulated time ``at``:
         replicas leave dispatch one at a time, finish their in-flight
@@ -494,7 +527,6 @@ class ServeEngine:
         ``version``; once every replica has rolled, the engine adopts
         the new params as its compiled state. Returns the version id.
         """
-        from repro.quant.calibrate import QuantizedCNNParams
         if self._pending_swap is not None:
             raise RuntimeError("a hot_swap is already registered; serve a "
                                "stream to complete it first")
@@ -508,6 +540,9 @@ class ServeEngine:
                     f"{getattr(self.cfg, f)}")
         quant = isinstance(new_params, QuantizedCNNParams)
         dtype = "int8" if quant else new_cfg.dtype
+        plans = getattr(artifact, "group_plans", None)
+        if not plans or artifact.spec.serving.batch != self.batch:
+            plans = self._resolve_plans(new_cfg, self.batch, dtype)
         if self.pp_stages > 1:
             # same microbatch split, rebalanced stages for the new dtype
             sp = plan_stages(new_cfg, self.pp_stages, batch=self.mb,
@@ -519,7 +554,10 @@ class ServeEngine:
         v = self._n_versions
         self._n_versions += 1
         self._versions[v] = dict(params=new_params, quant=quant,
-                                 cfg=new_cfg, stage_plan=sp,
+                                 cfg=new_cfg, plans=plans,
+                                 micro_plans=self._micro_plans(
+                                     new_cfg, sp, dtype, plans),
+                                 stage_plan=sp,
                                  t_round=t_round,
                                  t_restore=restore_latency_model(
                                      params_nbytes(new_params)))
@@ -537,6 +575,8 @@ class ServeEngine:
         self.quant = rec["quant"]
         self.cfg = rec["cfg"]
         self.dtype = "int8" if rec["quant"] else rec["cfg"].dtype
+        self.plans = rec["plans"]
+        self.micro_plans = rec["micro_plans"]
         self.stage_plan = rec["stage_plan"]
         self.t_round_model = rec["t_round"]
         self.t_restore_model = rec["t_restore"]

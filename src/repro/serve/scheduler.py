@@ -41,7 +41,7 @@ or rejection — never stranded — and the modeled clock keeps runs
 deterministic and device-free under ``execute=False``. With
 ``execute=True`` each admission group runs a padded single-replica
 forward (no mesh needed), so predictions stay fp32-allclose /
-int8-bit-exact with ``cnn_forward`` while the fleet scales past the
+int8-bit-exact with the unsharded forward while the fleet scales past the
 device count.
 """
 from __future__ import annotations
@@ -288,7 +288,7 @@ class ContinuousScheduler:
 
         def admit_preds(take, v):
             # one padded single-replica forward per admission group —
-            # row-independent, so preds match cnn_forward exactly
+            # row-independent, so preds match the unsharded forward exactly
             if not eng.execute or not take:
                 return [-1] * len(take)
             imgs = np.stack([q.image for q in take])

@@ -12,17 +12,19 @@ plans with:
   * conv groups — the tuned :class:`~repro.kernels.autotune.ConvPlan`'s
     ``t_model`` (per image, times the microbatch);
   * fc layers — the dtype-aware GEMM DSE
-    (:func:`~repro.kernels.autotune.gemm_plan_for_layer`);
+    (:func:`~repro.kernels.autotune.get_gemm_plan`);
   * standalone pool / LRN — bandwidth-bound read+write traffic over the
     HBM roofline (they do negligible math).
 
 The exact min-max contiguous partition is solved by dynamic programming
-(group counts are ~16, stages <= 8 — trivially small).
+(group counts are ~16, stages <= 8 — trivially small). Each stage keeps
+the plans its groups were costed with: they are the tilings the pipeline
+stages run at the microbatch.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.config import CNNConfig
 from repro.core.roofline import HBM_BW, VMEM_BYTES, pipeline_bubble_fraction
@@ -65,6 +67,16 @@ def group_cost(cfg: CNNConfig, group: Tuple[int, ...],
                in_shape: Tuple[int, ...], out_shape: Tuple[int, ...],
                batch: int, *, dtype: Optional[str] = None) -> float:
     """Modeled seconds to run one fusion group over ``batch`` images."""
+    return _plan_and_cost(cfg, group, in_shape, out_shape, batch,
+                          dtype=dtype)[1]
+
+
+def _plan_and_cost(cfg: CNNConfig, group: Tuple[int, ...],
+                   in_shape: Tuple[int, ...], out_shape: Tuple[int, ...],
+                   batch: int, *, dtype: Optional[str] = None
+                   ) -> Tuple[Any, float]:
+    """The group's tuned plan (None for pool/LRN) and its modeled
+    seconds over ``batch`` images."""
     dtype = dtype or cfg.dtype
     dt = _DTYPE_BYTES.get(dtype, 4)
     l = cfg.layers[group[0]]
@@ -77,7 +89,8 @@ def group_cost(cfg: CNNConfig, group: Tuple[int, ...],
             pool=(pool.pool if pool else None),
             pool_k=(pool.kernel if pool else 2),
             pool_s=(pool.stride if pool else 2), dtype=dtype, b=batch)
-        return get_plan(shape, vmem_budget=cfg.vmem_budget).t_model * batch
+        plan = get_plan(shape, vmem_budget=cfg.vmem_budget)
+        return plan, plan.t_model * batch
     if l.kind == "fc":
         k = 1
         for d in in_shape:
@@ -85,7 +98,7 @@ def group_cost(cfg: CNNConfig, group: Tuple[int, ...],
         gp = get_gemm_plan(GemmShape(m=batch, k=k, n=out_shape[-1],
                                      dtype=dtype),
                            vmem_budget=cfg.vmem_budget)
-        return gp.t_model
+        return gp, gp.t_model
     # standalone pool / LRN: bandwidth-bound (read in, write out); LRN
     # runs off the fixed-point pipeline, so its traffic is fp32 always
     n_in = n_out = 1
@@ -94,7 +107,7 @@ def group_cost(cfg: CNNConfig, group: Tuple[int, ...],
     for d in out_shape:
         n_out *= d
     el = 4 if l.kind == "lrn" else dt
-    return batch * (n_in + n_out) * el / HBM_BW
+    return None, batch * (n_in + n_out) * el / HBM_BW
 
 
 @dataclass(frozen=True)
@@ -104,6 +117,7 @@ class Stage:
     in_shape: Tuple[int, ...]          # per-image boundary entering
     out_shape: Tuple[int, ...]
     t_model: float                     # modeled seconds per microbatch
+    plans: Tuple[Any, ...] = ()        # per group: ConvPlan/GemmPlan/None
 
 
 @dataclass(frozen=True)
@@ -129,6 +143,12 @@ class StagePlan:
     def balance(self) -> float:
         """mean/max stage time — 1.0 is a perfectly level pipeline."""
         return self.t_sum / (self.n_stages * self.t_stage_max)
+
+    def group_plans(self) -> Dict[Tuple[int, ...], Any]:
+        """Every conv/fc group's plan at the stage batch — the tilings
+        the pipeline-parallel forward runs."""
+        return {g: p for s in self.stages for g, p in zip(s.groups, s.plans)
+                if p is not None}
 
     def max_boundary_elems(self) -> int:
         """Largest per-image activation crossing any stage boundary (or
@@ -194,8 +214,9 @@ def plan_stages(cfg: CNNConfig, n_stages: int, *, batch: int = 1,
         raise ValueError(
             f"n_stages={n_stages} not in [1, {len(shapes)}] "
             f"(the network has {len(shapes)} indivisible fusion groups)")
-    costs = [group_cost(cfg, g, i, o, batch, dtype=dtype)
+    tuned = [_plan_and_cost(cfg, g, i, o, batch, dtype=dtype)
              for g, i, o in shapes]
+    costs = [c for _, c in tuned]
     starts = _min_max_partition(costs, n_stages)
     stages = []
     for si, s in enumerate(starts):
@@ -204,7 +225,8 @@ def plan_stages(cfg: CNNConfig, n_stages: int, *, batch: int = 1,
         stages.append(Stage(
             groups=tuple(g for g, _, _ in chunk),
             in_shape=chunk[0][1], out_shape=chunk[-1][2],
-            t_model=sum(costs[s:e])))
+            t_model=sum(costs[s:e]),
+            plans=tuple(p for p, _ in tuned[s:e])))
     return StagePlan(stages=tuple(stages), batch=batch, dtype=dtype)
 
 

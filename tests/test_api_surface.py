@@ -110,9 +110,7 @@ AUTOTUNE_SURFACE = {
 OPS_SURFACE = {
     "attention",
     "fc",
-    "fc_q",
     "fused_conv",
-    "fused_conv_q",
     "get_interpret",
     "interpret_mode",
     "lrn",

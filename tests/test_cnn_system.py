@@ -8,36 +8,39 @@ import pytest
 from repro.configs import get_config
 from repro.core.config import flops_per_image
 from repro.core.pipeline import bandwidth_model, fusion_savings
-from repro.models.cnn import cnn_forward, fuse_plan, init_cnn_params
+from repro.models.cnn import cnn_forward_stage, fuse_plan, init_cnn_params
 
 KEY = jax.random.key(7)
 
 
 @pytest.mark.parametrize("name", ["alexnet", "vgg16"])
-def test_cnn_smoke_forward(name):
+def test_cnn_smoke_forward(name, forward):
     cfg = get_config(name).smoke()
     params = init_cnn_params(KEY, cfg)
     x = jax.random.normal(KEY, (2, cfg.input_hw, cfg.input_hw,
                                 cfg.input_ch), jnp.float32)
-    y = cnn_forward(params, x, cfg)
+    y = forward(params, x, cfg)
     assert y.ndim == 2 and y.shape[0] == 2
     assert np.isfinite(np.asarray(y)).all()
 
 
 @pytest.mark.parametrize("name", ["alexnet", "vgg16"])
 def test_fused_equals_unfused(name):
-    """PipeCNN's fusion is a dataflow change, not a math change."""
+    """PipeCNN's fusion is a dataflow change, not a math change: the
+    reference fold over the fusion groups equals the fold over singleton
+    (one layer each) groups."""
     cfg = get_config(name).smoke()
     params = init_cnn_params(KEY, cfg)
     x = jax.random.normal(KEY, (1, cfg.input_hw, cfg.input_hw,
                                 cfg.input_ch), jnp.float32)
-    y_f = cnn_forward(params, x, cfg, fused=True)
-    y_u = cnn_forward(params, x, cfg, fused=False)
+    y_f = cnn_forward_stage(params, x, cfg, fuse_plan(cfg))
+    y_u = cnn_forward_stage(params, x, cfg,
+                            [(i,) for i in range(len(cfg.layers))])
     np.testing.assert_allclose(np.asarray(y_f), np.asarray(y_u),
                                rtol=1e-4, atol=1e-4)
 
 
-def test_pallas_pipeline_matches_ref_alexnet():
+def test_pallas_pipeline_matches_ref_alexnet(forward):
     """Kernel path vs XLA path. The Pallas path uses the paper's PWL LRN
     (<=0.5% by design) while the ref path is exact LRN, so the tolerance
     accounts for the documented approximation propagating through layers."""
@@ -45,8 +48,8 @@ def test_pallas_pipeline_matches_ref_alexnet():
     params = init_cnn_params(KEY, cfg)
     x = jax.random.normal(KEY, (1, cfg.input_hw, cfg.input_hw,
                                 cfg.input_ch), jnp.float32)
-    y_ref = cnn_forward(params, x, cfg, use_pallas=False)
-    y_pal = cnn_forward(params, x, cfg, use_pallas=True)
+    y_ref = forward(params, x, cfg, use_pallas=False)
+    y_pal = forward(params, x, cfg, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=5e-2, atol=5e-2)
     # and with LRN exactness isolated (VGG has no LRN): tight tolerance
@@ -55,8 +58,8 @@ def test_pallas_pipeline_matches_ref_alexnet():
     xv = jax.random.normal(KEY, (1, cfgv.input_hw, cfgv.input_hw,
                                  cfgv.input_ch), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(cnn_forward(pv, xv, cfgv, use_pallas=True)),
-        np.asarray(cnn_forward(pv, xv, cfgv, use_pallas=False)),
+        np.asarray(forward(pv, xv, cfgv, use_pallas=True)),
+        np.asarray(forward(pv, xv, cfgv, use_pallas=False)),
         rtol=5e-4, atol=5e-4)
 
 

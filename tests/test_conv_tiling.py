@@ -417,15 +417,15 @@ def test_plan_registry_keyed_by_batch():
     autotune.clear_registry()
 
 
-def test_cnn_forward_batched_matches_ref():
+def test_cnn_forward_batched_matches_ref(forward):
     """Whole-model check at a serving batch the plans don't divide: the
     autotuned pallas path (batch in the plan key) vs the XLA reference."""
-    from repro.models.cnn import cnn_forward, init_cnn_params
+    from repro.models.cnn import init_cnn_params
     cfg = get_config("vgg16").smoke()
     params = init_cnn_params(KEY, cfg)
     x = _rand((5, cfg.input_hw, cfg.input_hw, cfg.input_ch))
-    y_ref = cnn_forward(params, x, cfg, use_pallas=False)
-    y_pal = cnn_forward(params, x, cfg, use_pallas=True)
+    y_ref = forward(params, x, cfg, use_pallas=False)
+    y_pal = forward(params, x, cfg, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=5e-4, atol=5e-4)
 
@@ -531,25 +531,25 @@ def test_tuned_plan_runs_and_matches_oracle():
            oh_blk=plan.oh_blk, c_blk=plan.c_blk, m_blk=plan.m_blk)
 
 
-def test_cnn_forward_autotuned_matches_ref():
+def test_cnn_forward_autotuned_matches_ref(forward):
     """The full model path with autotuned plans (use_pallas) vs XLA ref."""
-    from repro.models.cnn import cnn_forward, init_cnn_params
+    from repro.models.cnn import init_cnn_params
     cfg = get_config("vgg16").smoke()
     params = init_cnn_params(KEY, cfg)
     x = _rand((1, cfg.input_hw, cfg.input_hw, cfg.input_ch))
-    y_ref = cnn_forward(params, x, cfg, use_pallas=False)
-    y_pal = cnn_forward(params, x, cfg, use_pallas=True)
+    y_ref = forward(params, x, cfg, use_pallas=False)
+    y_pal = forward(params, x, cfg, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=5e-4, atol=5e-4)
 
 
-def test_grouped_cnn_forward_alexnet_smoke():
+def test_grouped_cnn_forward_alexnet_smoke(forward):
     """AlexNet smoke through the pallas path exercises in-kernel groups."""
-    from repro.models.cnn import cnn_forward, init_cnn_params
+    from repro.models.cnn import init_cnn_params
     cfg = get_config("alexnet").smoke()
     params = init_cnn_params(KEY, cfg)
     x = _rand((1, cfg.input_hw, cfg.input_hw, cfg.input_ch))
-    y_ref = cnn_forward(params, x, cfg, use_pallas=False)
-    y_pal = cnn_forward(params, x, cfg, use_pallas=True)
+    y_ref = forward(params, x, cfg, use_pallas=False)
+    y_pal = forward(params, x, cfg, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=5e-2, atol=5e-2)   # PWL LRN tolerance

@@ -15,9 +15,7 @@ import pytest
 from repro.configs import get_config
 from repro.core.config import CNNConfig
 from repro.kernels import autotune, ops
-from repro.models.cnn import (cnn_forward, cnn_forward_quant,
-                              cnn_forward_stage, fuse_plan,
-                              init_cnn_params)
+from repro.models.cnn import cnn_forward_stage, fuse_plan, init_cnn_params
 from repro.pipeline import (CompiledCNN, ExecutionSpec, Placement, PlanTable,
                             Precision, Serving, Tiling, compile_cnn,
                             load_plan, resolve_config, spec_from_config)
@@ -220,18 +218,18 @@ def test_plan_table_measured_roundtrip_byte_stable():
 # ---------------------------------------------------------------------------
 
 def test_compiled_forward_matches_legacy_fold_fp32():
-    """CompiledCNN.forward (frozen plans) vs the pre-refactor direct
-    fold over fuse_plan — identical math, pallas and ref paths."""
+    """CompiledCNN.forward (one jitted program over the frozen plans) vs
+    the direct op-by-op fold over fuse_plan with the same plans —
+    identical math, pallas and ref paths."""
     cfg, params, x = _setup("alexnet")
     c = compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=4)), params)
+    assert set(c.group_plans) == {
+        g for g in fuse_plan(cfg) if cfg.layers[g[0]].kind in ("conv",
+                                                              "fc")}
     want = cnn_forward_stage(params, x, cfg, fuse_plan(cfg),
-                             use_pallas=True)
+                             plans=c.group_plans, use_pallas=True)
     np.testing.assert_allclose(np.asarray(c.forward(x)),
                                np.asarray(want), rtol=1e-5, atol=1e-5)
-    # the deprecation shim must agree exactly (same plans, same kernels)
-    np.testing.assert_array_equal(
-        np.asarray(cnn_forward(params, x, cfg, use_pallas=True)),
-        np.asarray(c.forward(x)))
 
 
 def test_compiled_forward_matches_legacy_fold_vgg_ref_path():
@@ -246,15 +244,15 @@ def test_compiled_forward_matches_legacy_fold_vgg_ref_path():
 
 def test_compiled_forward_int8_bit_exact_vs_legacy():
     """The quantized compile (calibration inside the compile phase) is
-    BIT-exact vs the pre-refactor cnn_forward_quant on the same
-    calibrated params."""
+    BIT-exact vs the direct int8 fold over calibrate_cnn's params."""
     from repro.quant import calibrate_cnn
     cfg, params, x = _setup("alexnet")
     spec = ExecutionSpec(precision=Precision(quant="int8"),
                          serving=Serving(batch=4))
     c = compile_cnn(cfg, spec, (params, x))
     qp = calibrate_cnn(params, x, cfg)
-    want = cnn_forward_quant(qp, x, cfg, use_pallas=True)
+    want = cnn_forward_stage(qp, x, cfg, fuse_plan(cfg),
+                             plans=c.group_plans, use_pallas=True)
     np.testing.assert_array_equal(np.asarray(c.forward(x)),
                                   np.asarray(want))
 
@@ -303,7 +301,8 @@ def test_compile_accepts_prequantized_params():
     spec = ExecutionSpec(precision=Precision(quant="int8"),
                          serving=Serving(batch=4))
     c = compile_cnn(cfg, spec, qp)
-    want = cnn_forward_quant(qp, x, cfg, use_pallas=True)
+    want = cnn_forward_stage(qp, x, cfg, fuse_plan(cfg),
+                             plans=c.group_plans, use_pallas=True)
     np.testing.assert_array_equal(np.asarray(c.forward(x)),
                                   np.asarray(want))
 
@@ -449,8 +448,8 @@ def test_all_four_modes_parity_on_8_devices():
     bit-exact, dp/pp predictions identical to the unsharded forward."""
     run_in_mesh_subprocess("""
         from repro.configs import get_config
-        from repro.models.cnn import (cnn_forward_quant, cnn_forward_stage,
-                                      fuse_plan, init_cnn_params)
+        from repro.models.cnn import (cnn_forward_stage, fuse_plan,
+                                      init_cnn_params)
         from repro.pipeline import (ExecutionSpec, Placement, Precision,
                                     Serving, compile_cnn)
         from repro.quant import calibrate_cnn
@@ -461,23 +460,25 @@ def test_all_four_modes_parity_on_8_devices():
         params = init_cnn_params(key, cfg)
         x = jax.random.normal(key, (8, cfg.input_hw, cfg.input_hw,
                                     cfg.input_ch), jnp.float32)
-        want = np.asarray(cnn_forward_stage(params, x, cfg, fuse_plan(cfg),
-                                            use_pallas=True))
-
         # fp32 single
         c1 = compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=8)),
                          params)
+        want = np.asarray(cnn_forward_stage(params, x, cfg, fuse_plan(cfg),
+                                            plans=c1.group_plans,
+                                            use_pallas=True))
         np.testing.assert_allclose(np.asarray(c1.forward(x)), want,
                                    rtol=1e-5, atol=1e-5)
 
-        # int8 single: bit-exact vs the pre-refactor quant path
+        # int8 single: bit-exact vs the direct int8 fold
         qp = calibrate_cnn(params, x, cfg)
         c8 = compile_cnn(cfg, ExecutionSpec(
             precision=Precision(quant='int8'),
             serving=Serving(batch=8)), qp)
         np.testing.assert_array_equal(
             np.asarray(c8.forward(x)),
-            np.asarray(cnn_forward_quant(qp, x, cfg, use_pallas=True)))
+            np.asarray(cnn_forward_stage(qp, x, cfg, fuse_plan(cfg),
+                                         plans=c8.group_plans,
+                                         use_pallas=True)))
 
         # dp4: served predictions == unsharded argmax
         cdp = compile_cnn(cfg, ExecutionSpec(

@@ -15,7 +15,7 @@ from repro.configs import get_config
 from repro.kernels import autotune, ops
 from repro.kernels.conv_pipe import conv_pipe
 from repro.kernels.matmul_pipe import matmul_pipe
-from repro.models.cnn import cnn_forward, cnn_forward_quant, init_cnn_params
+from repro.models.cnn import cnn_forward_stage, fuse_plan, init_cnn_params
 from repro.quant import (QMAX, abs_max_scale, calibrate_cnn, dequantize,
                          dequantize_blocks, fake_quant, quantize,
                          quantize_blocks, quantize_channelwise)
@@ -169,15 +169,14 @@ def test_fc_int8_bit_exact_vs_reference():
 
 
 def test_ops_wrappers_route_quant_paths():
-    """ops.fused_conv_q / ops.fc_q: pallas and reference paths agree
-    bit-for-bit through the jit'd public wrappers."""
+    """ops.fused_conv with a requantize scale: pallas and reference
+    paths agree bit-for-bit through the jit'd public wrapper."""
     xq, wq, b, scale = _quant_conv_operands(3, 12, 4, 3, 8)
-    kw = dict(pad=1, pool="max", out_scale=0.05)
+    kw = dict(scale=scale, pad=1, pool="max", out_scale=0.05)
     np.testing.assert_array_equal(
-        np.asarray(ops.fused_conv_q(xq, wq, b, scale, use_pallas=True,
-                                    c_blk=2, m_blk=4, oh_blk=4, **kw)),
-        np.asarray(ops.fused_conv_q(xq, wq, b, scale, use_pallas=False,
-                                    **kw)))
+        np.asarray(ops.fused_conv(xq, wq, b, use_pallas=True, c_blk=2,
+                                  m_blk=4, oh_blk=4, **kw)),
+        np.asarray(ops.fused_conv(xq, wq, b, use_pallas=False, **kw)))
 
 
 # ---------------------------------------------------------------------------
@@ -300,7 +299,7 @@ def test_tuned_int8_plan_runs_and_matches_reference():
 # whole-model quantized forward
 # ---------------------------------------------------------------------------
 
-def test_quantized_vgg_pallas_bit_equals_reference():
+def test_quantized_vgg_pallas_bit_equals_reference(forward):
     """VGG has no LRN, so the quantized pallas path and the exact-int
     reference path agree on every int8 code — the whole model is
     integer-deterministic (logits: tight fp32 allclose)."""
@@ -308,23 +307,23 @@ def test_quantized_vgg_pallas_bit_equals_reference():
     qp = calibrate_cnn(params, calib, cfg)
     x = _rand((3, cfg.input_hw, cfg.input_hw, cfg.input_ch),
               key=jax.random.key(9))
-    y_ref = cnn_forward_quant(qp, x, cfg, use_pallas=False)
-    y_pal = cnn_forward_quant(qp, x, cfg, use_pallas=True)
+    y_ref = forward(qp, x, cfg, use_pallas=False)
+    y_pal = forward(qp, x, cfg, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_quantized_alexnet_forward_smoke():
+def test_quantized_alexnet_forward_smoke(forward):
     """Whole-model quantized AlexNet (groups + LRN + fused pool) through
-    both paths: finite logits, near-fp32 argmax, auto-routing via
-    cnn_forward on a QuantizedCNNParams."""
+    both paths: finite logits, near-fp32 argmax; the params' type
+    selects the int8 pipeline."""
     cfg, params, calib = _smoke_setup("alexnet")
     qp = calibrate_cnn(params, calib, cfg)
     x = _rand((8, cfg.input_hw, cfg.input_hw, cfg.input_ch),
               key=jax.random.key(9))
-    y_fp = cnn_forward(params, x, cfg)
-    y_q = cnn_forward(qp, x, cfg)                  # auto-routes to quant
-    y_qp = cnn_forward(qp, x, cfg, use_pallas=True)
+    y_fp = forward(params, x, cfg)
+    y_q = forward(qp, x, cfg)                      # int8 by params type
+    y_qp = forward(qp, x, cfg, use_pallas=True)
     for y in (y_q, y_qp):
         assert y.shape == y_fp.shape and y.dtype == jnp.float32
         assert np.isfinite(np.asarray(y)).all()
@@ -337,18 +336,39 @@ def test_quantized_alexnet_forward_smoke():
     assert rel < 0.15
 
 
-def test_quant_config_rejects_uncalibrated_params():
-    """cfg.quant='int8' declares fixed-point serving; handing cnn_forward
-    raw fp32 params must fail loudly, not silently run fp32."""
-    import dataclasses
+def test_quant_config_rejects_uncalibrated_params(forward):
+    """A spec's precision must match the params: compiling calibrated
+    int8 params under quant='none' fails loudly instead of silently
+    serving them as something else."""
+    from repro.pipeline import ExecutionSpec, Serving, compile_cnn
     cfg, params, calib = _smoke_setup()
-    qcfg = dataclasses.replace(cfg, quant="int8")
     x = _rand((2, cfg.input_hw, cfg.input_hw, cfg.input_ch))
-    with pytest.raises(ValueError, match="calibrate"):
-        cnn_forward(params, x, qcfg)
-    # calibrated params serve fine under the same config
-    qp = calibrate_cnn(params, calib, qcfg)
-    assert np.isfinite(np.asarray(cnn_forward(qp, x, qcfg))).all()
+    qp = calibrate_cnn(params, calib, cfg)
+    with pytest.raises(ValueError, match="quant='int8'"):
+        compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=2)), qp,
+                    with_engine=False)
+    # the same params serve fine under the int8 precision
+    assert np.isfinite(np.asarray(forward(qp, x, cfg))).all()
+
+
+def test_int8_forward_calls_the_one_wrapper_per_kernel():
+    """The int8 compiled forward runs the same jitted kernel wrappers as
+    the float one — ``fused_conv``, ``fc`` and ``lrn``, the kernel
+    families the benchmark's device trace reads — and no int8 twin."""
+    import re
+
+    from repro.pipeline import ExecutionSpec, Precision, Serving, compile_cnn
+    cfg, params, calib = _smoke_setup("alexnet")
+    qp = calibrate_cnn(params, calib, cfg)
+    c = compile_cnn(cfg, ExecutionSpec(precision=Precision(quant="int8"),
+                                       serving=Serving(batch=2)), qp,
+                    with_engine=False)
+    x = jax.ShapeDtypeStruct((2, cfg.input_hw, cfg.input_hw, cfg.input_ch),
+                             jnp.float32)
+    names = set(re.findall(r"\bname=(\w+)",
+                           str(jax.make_jaxpr(c._single_forward())(qp, x))))
+    assert {"fused_conv", "fc", "lrn"} <= names, names
+    assert not names & {"fused_conv_q", "fc_q"}, names
 
 
 def test_quantized_forward_under_jit():
@@ -356,7 +376,8 @@ def test_quantized_forward_under_jit():
     static scales; compile once, run twice."""
     cfg, params, calib = _smoke_setup()
     qp = calibrate_cnn(params, calib, cfg)
-    fwd = jax.jit(lambda p, x: jnp.argmax(cnn_forward(p, x, cfg), -1))
+    fwd = jax.jit(lambda p, x: jnp.argmax(
+        cnn_forward_stage(p, x, cfg, fuse_plan(cfg)), -1))
     x = _rand((4, cfg.input_hw, cfg.input_hw, cfg.input_ch))
     np.testing.assert_array_equal(np.asarray(fwd(qp, x)),
                                   np.asarray(fwd(qp, x)))

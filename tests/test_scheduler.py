@@ -298,11 +298,20 @@ def test_spec_dict_roundtrip_with_autoscale():
 def test_cb_parity_with_steals_and_autoscale_8dev():
     """ISSUE acceptance: under continuous batching with stealing and
     autoscaling enabled, every served prediction still matches the
-    unsharded ``cnn_forward`` bit-for-bit (admission groups run padded
+    unsharded forward bit-for-bit (admission groups run padded
     row-independent forwards, so scheduling cannot change outputs)."""
     run_in_mesh_subprocess("""
         from repro.configs import get_config
-        from repro.models.cnn import cnn_forward, init_cnn_params
+        from repro.models.cnn import init_cnn_params
+        from repro.pipeline import (ExecutionSpec, Precision, Serving,
+                                    compile_cnn)
+
+        def fwd(p, x, quant='none'):
+            # the compiled forward at x's batch (the Pallas path)
+            return compile_cnn(cfg, ExecutionSpec(
+                precision=Precision(quant=quant),
+                serving=Serving(batch=x.shape[0])), p,
+                with_engine=False).forward(x)
         from repro.serve import AutoscalePolicy, Request, ServeEngine
         cfg = get_config('alexnet').smoke()
         key = jax.random.key(5)
@@ -324,7 +333,7 @@ def test_cb_parity_with_steals_and_autoscale_8dev():
         assert sorted(c.rid for c in done) == list(range(N))
         assert rep.scheduler == 'continuous'
         want = np.asarray(jnp.argmax(
-            cnn_forward(params, x, cfg, use_pallas=True), -1))
+            fwd(params, x), -1))
         for c in done:
             if c.status == 'ok':
                 assert c.pred == int(want[c.rid]), (c.rid, c.pred)

@@ -164,14 +164,14 @@ def test_gemm_dse_int8_models_faster():
     assert int8.t_model <= 0.5 * fp32.t_model
 
 
-def test_fc_layers_route_through_gemm_dse():
-    from repro.models.cnn import cnn_forward, init_cnn_params
+def test_fc_layers_route_through_gemm_dse(forward):
+    from repro.models.cnn import init_cnn_params
     autotune.clear_registry()
     cfg = get_config("alexnet").smoke()
     params = init_cnn_params(KEY, cfg)
     x = jax.random.normal(KEY, (2, cfg.input_hw, cfg.input_hw,
                                 cfg.input_ch), jnp.float32)
-    cnn_forward(params, x, cfg, use_pallas=True)
+    forward(params, x, cfg, use_pallas=True)
     gemm = autotune.gemm_registry_snapshot()
     n_fc = sum(1 for l in cfg.layers if l.kind == "fc")
     assert len(gemm) == n_fc                      # one plan per FC layer
@@ -259,7 +259,16 @@ def test_engine_needs_devices_for_mesh_modes():
 def test_dp_engine_preds_match_single_device():
     run_in_mesh_subprocess("""
         from repro.configs import get_config
-        from repro.models.cnn import cnn_forward, init_cnn_params
+        from repro.models.cnn import init_cnn_params
+        from repro.pipeline import (ExecutionSpec, Precision, Serving,
+                                    compile_cnn)
+
+        def fwd(p, x, quant='none'):
+            # the compiled forward at x's batch (the Pallas path)
+            return compile_cnn(cfg, ExecutionSpec(
+                precision=Precision(quant=quant),
+                serving=Serving(batch=x.shape[0])), p,
+                with_engine=False).forward(x)
         from repro.serve import Request, ServeEngine
         cfg = get_config('alexnet').smoke()
         key = jax.random.key(3)
@@ -273,7 +282,7 @@ def test_dp_engine_preds_match_single_device():
         done, rep = eng.serve(reqs)
         assert rep.n_done == 16 and rep.rounds == 1
         want = np.asarray(jnp.argmax(
-            cnn_forward(params, x, cfg, use_pallas=True), -1))
+            fwd(params, x), -1))
         preds = {c.rid: c.pred for c in done}
         assert all(preds[i] == int(want[i]) for i in range(16))
     """)
@@ -281,17 +290,26 @@ def test_dp_engine_preds_match_single_device():
 
 def test_pipeline_stages_cnn_fp32_matches_unsharded():
     """Satellite: pipeline_forward with a CNN stage function on 8 virtual
-    devices — fp32 parity with the unsharded cnn_forward."""
+    devices — fp32 parity with the unsharded forward."""
     run_in_mesh_subprocess("""
         from repro.configs import get_config
-        from repro.models.cnn import cnn_forward, init_cnn_params
+        from repro.models.cnn import init_cnn_params
+        from repro.pipeline import (ExecutionSpec, Precision, Serving,
+                                    compile_cnn)
+
+        def fwd(p, x, quant='none'):
+            # the compiled forward at x's batch (the Pallas path)
+            return compile_cnn(cfg, ExecutionSpec(
+                precision=Precision(quant=quant),
+                serving=Serving(batch=x.shape[0])), p,
+                with_engine=False).forward(x)
         from repro.serve import plan_stages, pipeline_logits
         cfg = get_config('alexnet').smoke()
         key = jax.random.key(3)
         params = init_cnn_params(key, cfg)
         x = jax.random.normal(key, (8, cfg.input_hw, cfg.input_hw,
                                     cfg.input_ch), jnp.float32)
-        want = np.asarray(cnn_forward(params, x, cfg, use_pallas=True))
+        want = np.asarray(fwd(params, x))
         # pure pipeline (1x4) and hybrid (2x4) must both match
         for dp, mb_n in ((1, 4), (2, 2)):
             mesh = jax.make_mesh((dp, 4), ('data', 'pipe'),
@@ -311,7 +329,16 @@ def test_pipeline_stages_cnn_int8_bit_exact():
     changes scheduling, never math)."""
     run_in_mesh_subprocess("""
         from repro.configs import get_config
-        from repro.models.cnn import cnn_forward, init_cnn_params
+        from repro.models.cnn import init_cnn_params
+        from repro.pipeline import (ExecutionSpec, Precision, Serving,
+                                    compile_cnn)
+
+        def fwd(p, x, quant='none'):
+            # the compiled forward at x's batch (the Pallas path)
+            return compile_cnn(cfg, ExecutionSpec(
+                precision=Precision(quant=quant),
+                serving=Serving(batch=x.shape[0])), p,
+                with_engine=False).forward(x)
         from repro.quant import calibrate_cnn
         from repro.serve import plan_stages, pipeline_logits
         cfg = get_config('alexnet').smoke()
@@ -320,12 +347,12 @@ def test_pipeline_stages_cnn_int8_bit_exact():
         x = jax.random.normal(key, (8, cfg.input_hw, cfg.input_hw,
                                     cfg.input_ch), jnp.float32)
         qp = calibrate_cnn(params, x, cfg)
-        want = np.asarray(cnn_forward(qp, x, cfg, use_pallas=True))
+        want = np.asarray(fwd(qp, x, 'int8'))
         mesh = jax.make_mesh((1, 4), ('data', 'pipe'),
                              axis_types=(jax.sharding.AxisType.Auto,) * 2)
         sp = plan_stages(cfg, 4, batch=2, dtype='int8')
         got = pipeline_logits(qp, x, cfg, mesh, sp, n_microbatches=4,
-                              use_pallas=True, quant=True, dp_axis='data')
+                              use_pallas=True, dp_axis='data')
         np.testing.assert_array_equal(np.asarray(got), want)
     """)
 
@@ -527,7 +554,16 @@ def test_chaos_parity_fail_recover_8dev():
     completed prediction matches the unsharded forward."""
     run_in_mesh_subprocess("""
         from repro.configs import get_config
-        from repro.models.cnn import cnn_forward, init_cnn_params
+        from repro.models.cnn import init_cnn_params
+        from repro.pipeline import (ExecutionSpec, Precision, Serving,
+                                    compile_cnn)
+
+        def fwd(p, x, quant='none'):
+            # the compiled forward at x's batch (the Pallas path)
+            return compile_cnn(cfg, ExecutionSpec(
+                precision=Precision(quant=quant),
+                serving=Serving(batch=x.shape[0])), p,
+                with_engine=False).forward(x)
         from repro.serve import FaultSchedule, Request, ServeEngine
         cfg = get_config('alexnet').smoke()
         key = jax.random.key(3)
@@ -547,7 +583,7 @@ def test_chaos_parity_fail_recover_8dev():
         assert rep.n_failures == 1 and rep.n_recoveries == 1
         assert rep.degraded_rounds > 0
         want = np.asarray(jnp.argmax(
-            cnn_forward(params, x, cfg, use_pallas=True), -1))
+            fwd(params, x), -1))
         for c in done:
             if c.status == 'ok':
                 assert c.pred == int(want[c.rid]), (c.rid, c.pred)
@@ -561,7 +597,16 @@ def test_hot_swap_under_load_fp32_to_int8_parity_8dev():
     int8 forward."""
     run_in_mesh_subprocess("""
         from repro.configs import get_config
-        from repro.models.cnn import cnn_forward, init_cnn_params
+        from repro.models.cnn import init_cnn_params
+        from repro.pipeline import (ExecutionSpec, Precision, Serving,
+                                    compile_cnn)
+
+        def fwd(p, x, quant='none'):
+            # the compiled forward at x's batch (the Pallas path)
+            return compile_cnn(cfg, ExecutionSpec(
+                precision=Precision(quant=quant),
+                serving=Serving(batch=x.shape[0])), p,
+                with_engine=False).forward(x)
         from repro.quant import calibrate_cnn
         from repro.serve import Request, ServeEngine
         cfg = get_config('alexnet').smoke()
@@ -586,9 +631,9 @@ def test_hot_swap_under_load_fp32_to_int8_parity_8dev():
         versions = {c.version for c in done}
         assert versions == {0, v}, versions
         want_fp = np.asarray(jnp.argmax(
-            cnn_forward(params, x, cfg, use_pallas=True), -1))
+            fwd(params, x), -1))
         want_q = np.asarray(jnp.argmax(
-            cnn_forward(qp, x, cfg, use_pallas=True), -1))
+            fwd(qp, x, 'int8'), -1))
         for c in done:
             want = want_fp if c.version == 0 else want_q
             assert c.pred == int(want[c.rid]), (c.rid, c.version)
