@@ -5,10 +5,10 @@ HIGHEST, one chip, random weights), runs ``--iters`` forwards under the
 JAX profiler with the host and Python tracers off, and sums the device
 durations of the trace's ``XLA Ops`` events by HLO instruction. Prints
 one JSON line: milliseconds a forward per op family (``fused_conv``,
-``pad``, ``fc``, ``lrn``) and, per ``fused_conv`` instruction in the
-order the forward runs them, its output shape and milliseconds a
-forward. The benchmark's trace sums a family only; this splits it by
-conv group:
+``pad``, ``copy``, ``fc``, ``lrn``) and, per ``fused_conv``, ``pad`` and
+``copy`` instruction in the order the forward runs them, its output
+shape and milliseconds a forward. The benchmark's trace sums a family
+only; this splits it by conv group and by the glue around each:
 
     PYTHONPATH=src python benchmarks/kernel_times.py --arch vgg16 --batch 32
 """
@@ -20,12 +20,13 @@ import json
 import os
 import tempfile
 
-FAMILIES = ("fused_conv", "pad", "fc", "lrn")
+FAMILIES = ("fused_conv", "pad", "copy", "fc", "lrn")
+LISTED = ("fused_conv", "pad", "copy")      # split by instruction
 
 
 def kernel_times(path: str, iters: int) -> dict:
-    """Per-family and per-``fused_conv`` device ms a forward from the
-    ``.xplane.pb`` at ``path``."""
+    """Per-family device ms a forward, and per instruction of the
+    :data:`LISTED` families, from the ``.xplane.pb`` at ``path``."""
     from jax.profiler import ProfileData
 
     total, first, shape = {}, {}, {}
@@ -49,10 +50,11 @@ def kernel_times(path: str, iters: int) -> dict:
     for head, ns in total.items():
         fam = head.split(".")[0]
         family_ms[fam] = family_ms.get(fam, 0.0) + ns / iters * 1e-6
-    convs = [[head, shape[head], total[head] / iters * 1e-6]
-             for head in sorted(total, key=first.get)
-             if head.startswith("fused_conv")]
-    return {"family_ms": family_ms, "fused_conv": convs}
+    listed = {fam: [[head, shape[head], total[head] / iters * 1e-6]
+                    for head in sorted(total, key=first.get)
+                    if head.split(".")[0] == fam]
+              for fam in LISTED}
+    return {"family_ms": family_ms, **listed}
 
 
 def main() -> None:

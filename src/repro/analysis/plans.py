@@ -147,7 +147,7 @@ def _check_conv_row(loc: str, row: dict, shape: ConvShape, plan: ConvPlan,
                 f"the {out_rows} output rows exactly once"))
     # -- VMEM budget (RPA301/302) -----------------------------------------
     vmem = conv_vmem_bytes(shape, plan.c_blk, plan.m_blk, plan.oh_blk,
-                           plan.b_blk)
+                           plan.b_blk, budget)
     if not plan_fits(shape, plan, budget):
         findings.append(Finding(
             "RPA301", loc, 0,

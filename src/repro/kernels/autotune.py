@@ -152,7 +152,8 @@ def _conv_geometry(shape: ConvShape, c_blk: int, m_blk: int,
 
 
 def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
-                    oh_blk: int, b_blk: int = 1) -> int:
+                    oh_blk: int, b_blk: int = 1,
+                    vmem_budget: int = VMEM_BYTES) -> int:
     """VMEM working set of one grid step of the tiled conv_pipe kernel.
 
     Every slab is counted as Mosaic lays it out: minor dim padded to 128
@@ -170,8 +171,39 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
     tile, out tile, accumulator and temporaries scale with ``b_blk``; the
     weight tile does not — that asymmetry is the whole point of batching.
     int8 shrinks the streamed tiles (1-byte tensors) but bias/scale stay
-    fp32.
+    fp32. Where the kernel makes the halo itself within ``vmem_budget``
+    (:func:`halo_in_kernel`), the pipelined x block is the unpadded
+    image's rows and the padded tile is a single-buffered line buffer.
     """
+    return _conv_vmem(shape, c_blk, m_blk, oh_blk, b_blk,
+                      halo=halo_in_kernel(shape, c_blk, m_blk, oh_blk,
+                                          b_blk, vmem_budget))
+
+
+def halo_in_kernel(shape: ConvShape, c_blk: int, m_blk: int, oh_blk: int,
+                   b_blk: int = 1, vmem_budget: int = VMEM_BYTES) -> bool:
+    """Whether ``conv_pipe`` makes this conv's halo (the pad rows and
+    columns, the columns out to ``ow_p + kw - 1``, the last tile's rows)
+    in VMEM rather than taking a zero-padded copy from the wrapper: a
+    stride-1 conv with a halo to make, where the plan's working set with
+    the line buffer still fits ``vmem_budget``. So a plan fits a budget
+    exactly when it did with the halo in the wrapper. A strided conv's
+    halo stays in its space-to-depth copy."""
+    if shape.stride != 1:
+        return False
+    g, _, _, b_blk, tiles, _ = _conv_geometry(shape, c_blk, m_blk, oh_blk,
+                                             b_blk)
+    n_h, _, _, hp_blk, row_step = tiles
+    if not (shape.pad or g.w > shape.w
+            or (n_h - 1) * row_step + hp_blk > shape.h):
+        return False
+    return _conv_vmem(shape, c_blk, m_blk, oh_blk, b_blk,
+                      halo=True) <= vmem_budget
+
+
+def _conv_vmem(shape: ConvShape, c_blk: int, m_blk: int, oh_blk: int,
+               b_blk: int, *, halo: bool) -> int:
+    """:func:`conv_vmem_bytes` with the halo's place given."""
     dt = _DTYPE_BYTES.get(shape.dtype, 4)
     quantized = shape.dtype == "int8"
     g, c_blk, m_blk, b_blk, tiles, pw = _conv_geometry(
@@ -181,6 +213,10 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
     cx = c_blk // g.taps_folded                  # channels of the x tile
     cj = c_blk // g.kh_fold                      # of the x window
     x_tile = b_blk * hp_blk * _tile_bytes(g.w, cx, dt)
+    # the halo made in VMEM: the x block DMA'd is the image's own rows and
+    # columns, copied into the padded x tile (the line buffer)
+    x_block = (b_blk * min(hp_blk, shape.h) * _tile_bytes(shape.w, cx, dt)
+               if halo else x_tile)
     w_tile = g.kh * g.kw * _tile_bytes(c_blk, m_blk, dt)
     vec = _tile_bytes(1, m_blk, 4)               # fp32 (1, M_BLK) row
     o_tile = b_blk * pr * _tile_bytes(pw, m_blk, dt)
@@ -194,8 +230,9 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
              + row_pieces * b_blk * oh_ext * _tile_bytes(g.ow_p, cj, dt)
              + _tile_bytes(rows, c_blk, dt)                    # patch
              + 2 * acc)                        # tap result + epilogue
-    pipelined = x_tile + w_tile + vec * (2 if quantized else 1) + o_tile
-    return 2 * pipelined + acc + pool + temps
+    pipelined = x_block + w_tile + vec * (2 if quantized else 1) + o_tile
+    return (2 * pipelined + acc + pool + temps
+            + (x_tile if halo else 0))
 
 
 def plan_fits(shape: ConvShape, plan: ConvPlan,
@@ -209,7 +246,7 @@ def plan_fits(shape: ConvShape, plan: ConvPlan,
     sweep-counter bump.
     """
     return conv_vmem_bytes(shape, plan.c_blk, plan.m_blk, plan.oh_blk,
-                           plan.b_blk) <= vmem_budget
+                           plan.b_blk, vmem_budget) <= vmem_budget
 
 
 def score_plan(shape: ConvShape, c_blk: int, m_blk: int,
@@ -300,7 +337,8 @@ def enumerate_plans(shape: ConvShape,
         for cb in c_cands:
             for mb in m_cands:
                 for ob in oh_cands:
-                    vmem = conv_vmem_bytes(shape, cb, mb, ob, bb)
+                    vmem = conv_vmem_bytes(shape, cb, mb, ob, bb,
+                                           vmem_budget)
                     if vmem > vmem_budget:
                         continue
                     tc, tm = score_plan(shape, cb, mb, ob, bb)

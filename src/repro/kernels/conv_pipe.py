@@ -49,6 +49,24 @@ blocked BlockSpecs cannot express, so the x spec indexes H by element
 fp32 accumulator shrinks from (OH, OW, m_blk) to (oh_ext, OW, m_blk), which is what lets
 paper-scale layers (VGG-16 conv1: 224x224x64) fit a 16 MiB VMEM budget.
 
+The halo in VMEM (the line buffer's zero border): a stride-1 conv's
+zero pad rows and columns, the columns out to ``ow_p + kw - 1`` that
+every tap reads and the rows the last H-tile reads past the image are
+made by the kernel, not by an XLA pad of the whole activation in HBM.
+x goes into the ``pallas_call`` as the image itself; each step's x
+block holds the image rows its tile needs (the offset clamped into the
+image) and all its columns, and whenever the block changes the kernel
+lays it into a line-buffer scratch shaped like the padded tile, zeroing
+the rest (:func:`_make_halo`). The taps read that tile as before, so
+the sums and their order are the wrapper-padded kernel's. The line
+buffer costs one x tile of VMEM, so where a plan's working set with it
+would pass the budget the plan was tuned under (VGG-16 conv3_1, conv3_2,
+conv4), and for a strided conv, whose space-to-depth copy holds its pad
+anyway, the wrapper pads x instead; :func:`repro.kernels.autotune
+.halo_in_kernel` decides for kernel, tuner and counter alike. (A manual
+DMA from HBM cannot replace the pipeline here: Mosaic slices an HBM
+array only where its W is a multiple of 8 and its C of 128.)
+
 Grouped convolution (AlexNet's two towers) is folded into the grid: groups
 get a leading array axis of their own, the M-tile axis spans all groups'
 output tiles and the index maps select the owning group — one
@@ -78,6 +96,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.roofline import VMEM_BYTES
 from repro.kernels.mode import resolve_interpret
 
 LANE = 128          # VMEM lane width: the minor block dim is a multiple of it
@@ -225,25 +244,83 @@ def _space_to_depth(x: jax.Array, w: jax.Array, s: int, g: S2DGeometry
     return x, w.reshape(G, khs, kws, s * s * C, M)
 
 
+def _pad(x: jax.Array, widths) -> jax.Array:
+    """``jnp.pad`` with zeros, or ``x`` itself where every width is 0."""
+    return jnp.pad(x, widths) if any(lo or hi for lo, hi in widths) else x
+
+
+class Halo(NamedTuple):
+    """Where the image lies in a stride-1 conv's padded x tile, for a
+    kernel that makes the tile's halo in VMEM: the x block DMA'd for
+    H-tile ``t`` holds the image's rows ``[r, r + hb)``, ``r`` = ``t *
+    row_step - pad`` clamped into the image, and all its columns."""
+    pad: int        # zero rows above the image, and columns left of it
+    h: int          # image rows
+    hb: int         # rows of the x block: min(hp_blk, h)
+    row_step: int
+    n_h: int
+
+    def block_row(self, t):
+        """First image row of H-tile ``t``'s x block."""
+        return jnp.clip(t * self.row_step - self.pad, 0, self.h - self.hb)
+
+
+def _make_halo(x_ref, line_ref, halo: Halo, t) -> None:
+    """Lay H-tile ``t``'s x block into the line buffer at its place in
+    the padded tile (``pad`` rows down, ``pad`` columns in) and zero the
+    rest: the pad rows and columns, the columns out to the tile's width,
+    the rows past the image's last."""
+    b, hp, wp, c = line_ref.shape
+    p, w = halo.pad, x_ref.shape[2]
+    zeros = lambda n: jnp.zeros((b, hp, n, c), line_ref.dtype)
+    if p:
+        line_ref[:, :, pl.ds(0, p)] = zeros(p)
+    if wp > p + w:
+        line_ref[:, :, pl.ds(p + w, wp - p - w)] = zeros(wp - p - w)
+    start = t * halo.row_step - p             # image row of tile row 0
+    r0 = halo.block_row(t)
+    for k in range(hp):
+        row = start + k
+        src = jnp.clip(row - r0, 0, halo.hb - 1)
+        line_ref[:, k, pl.ds(p, w)] = jnp.where(
+            (row >= 0) & (row < halo.h), x_ref[:, src],
+            jnp.zeros((), line_ref.dtype))
+
+
 def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
                       ow_p: int, kw_fold: int, kh_fold: int, relu: bool,
                       pool: Optional[str],
                       pool_k: int, pool_s: int, pr: int, n_c_tiles: int,
                       quantized: bool = False,
-                      out_scale: Optional[float] = None):
+                      out_scale: Optional[float] = None,
+                      halo: Optional[Halo] = None, n_mg: int = 1):
     """One (B-block, H-tile, M-tile) output block; accumulates over C-tiles.
 
     ``quantized`` inserts the per-channel scale ref after the bias and
     switches the accumulator to int32 (the fixed-point pipeline); the
     epilogue then requantizes (scale -> bias -> ReLU -> pool -> round).
+    ``halo`` (:class:`Halo`) means x is the unpadded image: the taps read
+    a line-buffer scratch (the last one) that :func:`_make_halo` fills
+    whenever the x block changes.
     """
     if quantized:
         s_ref, refs = refs[0], refs[1:]
+    o_ref, acc_ref, *refs = refs
     if pool is not None:
-        o_ref, acc_ref, y_ref = refs
-    else:
-        o_ref, acc_ref = refs
+        y_ref, *refs = refs
     c_idx = pl.program_id(2)
+
+    if halo is not None:
+        line_ref, = refs
+        make = functools.partial(_make_halo, x_ref, line_ref, halo,
+                                 pl.program_id(0) % halo.n_h)
+        # a new x block comes with every C-tile, and else with the first
+        # M-tile of each group (the pipeline skips an unchanged block)
+        if n_c_tiles > 1:
+            make()
+        else:
+            pl.when(pl.program_id(1) % n_mg == 0)(make)
+        x_ref = line_ref
 
     @pl.when(c_idx == 0)
     def _init():
@@ -336,6 +413,7 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
               pool: Optional[str] = None, pool_k: int = 2, pool_s: int = 2,
               c_blk: int = LANE, m_blk: int = LANE, oh_blk: int = 0,
               b_blk: int = 1, groups: int = 1,
+              vmem_budget: int = VMEM_BYTES,
               interpret: Optional[bool] = None) -> jax.Array:
     """Fused conv(+bias)(+ReLU)(+pool). x (B,H,W,C); w (KH,KW,C/G,M); b (M,).
 
@@ -345,6 +423,9 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     output channels;
     oh_blk is the line-buffer depth in conv-output rows (0 = full height);
     b_blk is the number of images per grid step (0 = whole batch).
+    ``vmem_budget`` is the VMEM the plan was tuned under: a stride-1
+    conv's halo is made in VMEM where its line buffer still fits it, else
+    padded here.
     ``groups`` runs grouped convolution inside the one kernel: groups get
     an array axis of their own, so a narrow per-group slab (AlexNet conv2:
     48 channels) is a whole-dim block rather than a misaligned lane window.
@@ -378,14 +459,6 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     # groups -> a leading array axis: x (G,B,H,W,cg), w (G,KH,KW,cg,mg)
     x = x.reshape(B, H, W, groups, cg).transpose(3, 0, 1, 2, 4)
     w = w.reshape(KH, KW, cg, groups, mg).transpose(3, 0, 1, 2, 4)
-    x = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad), (0, 0)))
-    if stride > 1:
-        x, w = _space_to_depth(x, w, stride, g)
-    # right-pad W so every tap reads ow_p columns in bounds
-    x = jnp.pad(x, ((0, 0),) * 3 + ((0, g.w - x.shape[3]), (0, 0)))
-    # folded taps lie side by side in the contraction, (row tap, column
-    # tap, channel) major to minor; a no-op where nothing folds
-    w = w.reshape(groups, g.kh, g.kw, g.c, mg)
 
     c_blk = contraction_block(c_blk, g)
     m_blk = min(m_blk, mg)
@@ -395,46 +468,74 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
             f"c_blk={c_blk}/m_blk={m_blk} cannot lower: a lane block must "
             f"be a multiple of {LANE} or the whole per-group dim "
             f"({g.c}/{mg})")
-    # pad channels PER GROUP so group slabs stay c_blk/m_blk aligned
-    cgp, mgp = _round_up(g.c, c_blk), _round_up(mg, m_blk)
-    x = jnp.pad(x, ((0, 0),) * 4 + ((0, cgp - g.c),))
-    w = jnp.pad(w, ((0, 0),) * 3 + ((0, cgp - g.c), (0, mgp - mg)))
-    b = jnp.pad(b.reshape(groups, 1, mg), ((0, 0), (0, 0), (0, mgp - mg)))
-    n_c, n_mg = cgp // c_blk, mgp // m_blk
-    n_m = groups * n_mg
-
     n_h, pr, oh_ext, hp_blk, row_step = g.tiles(
         oh_blk, pool=pool, pool_k=pool_k, pool_s=pool_s)
-
-    # batch folding: b_blk images share each grid step (0 = whole batch);
-    # pad B up so the image-block axis tiles evenly (zero images, dropped)
+    # batch folding: b_blk images share each grid step (0 = whole batch)
     b_blk = min(b_blk, B) if b_blk else B
     b_blk = max(1, b_blk)
     n_b = -(-B // b_blk)
-    # bottom-pad the input so the last tile's halo read stays in bounds
-    # (its surplus conv rows are garbage-from-zeros, sliced off below)
-    need_h = (n_h - 1) * row_step + hp_blk
-    x = jnp.pad(x, ((0, 0), (0, n_b * b_blk - B),
-                    (0, max(0, need_h - x.shape[2])), (0, 0), (0, 0)))
+
+    from repro.kernels import autotune      # its VMEM model imports this
+    in_kernel = autotune.halo_in_kernel(
+        autotune.ConvShape(h=H, w=W, c=C, kh=KH, kw=KW, m=M, stride=stride,
+                           pad=pad, groups=groups, pool=pool, pool_k=pool_k,
+                           pool_s=pool_s, dtype=jnp.dtype(x.dtype).name,
+                           b=B),
+        c_blk, m_blk, oh_blk, b_blk, vmem_budget)
+    halo = None
+    if in_kernel:
+        # the kernel makes the halo: x goes in as the image itself
+        halo = Halo(pad=pad, h=H, hb=min(hp_blk, H), row_step=row_step,
+                    n_h=n_h)
+    else:
+        x = _pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad), (0, 0)))
+        if stride > 1:
+            x, w = _space_to_depth(x, w, stride, g)
+        # right-pad W so every tap reads ow_p columns in bounds
+        x = _pad(x, ((0, 0),) * 3 + ((0, g.w - x.shape[3]), (0, 0)))
+    # folded taps lie side by side in the contraction, (row tap, column
+    # tap, channel) major to minor; a no-op where nothing folds
+    w = w.reshape(groups, g.kh, g.kw, g.c, mg)
+
+    # pad channels PER GROUP so group slabs stay c_blk/m_blk aligned
+    cgp, mgp = _round_up(g.c, c_blk), _round_up(mg, m_blk)
+    x = _pad(x, ((0, 0),) * 4 + ((0, cgp - g.c),))
+    w = _pad(w, ((0, 0),) * 3 + ((0, cgp - g.c), (0, mgp - mg)))
+    b = _pad(b.reshape(groups, 1, mg), ((0, 0), (0, 0), (0, mgp - mg)))
+    n_c, n_mg = cgp // c_blk, mgp // m_blk
+    n_m = groups * n_mg
+
+    # pad B up so the image-block axis tiles evenly (zero images,
+    # dropped), and where x was padded here, the bottom so the last
+    # tile's halo read stays in bounds (its surplus conv rows are
+    # garbage-from-zeros, sliced off below)
+    need_h = 0 if halo else (n_h - 1) * row_step + hp_blk
+    x = _pad(x, ((0, 0), (0, n_b * b_blk - B),
+                 (0, max(0, need_h - x.shape[2])), (0, 0), (0, 0)))
 
     kernel = functools.partial(
         _conv_pipe_kernel, oh_ext=oh_ext, ow=g.ow, ow_p=g.ow_p,
         kw_fold=g.kw_fold, kh_fold=g.kh_fold, relu=relu,
         pool=pool, pool_k=pool_k, pool_s=pool_s, pr=pr, n_c_tiles=n_c,
-        quantized=quantized, out_scale=out_scale)
+        quantized=quantized, out_scale=out_scale, halo=halo, n_mg=n_mg)
 
     # x tiles overlap by the halo rows => element-offset indexing on H
     # only (B, W and C stay blocked); the folded leading axis decomposes
     # into (image block, H-tile); the group of M-tile mi selects the
-    # group axis of x and w.
+    # group axis of x and w. Where the kernel makes the halo, a tile's
+    # rows start at its first image row, clamped into the image.
     # Mosaic takes element offsets on every dim or on none, so B, W and C
     # return block-aligned offsets (a literal 0 where the block is the
     # whole dim, so the lane offset is provably tile-aligned).
+    if halo is None:
+        x_rows, first_row = hp_blk, lambda t: t * row_step
+    else:
+        x_rows, first_row = halo.hb, halo.block_row
     x_spec = pl.BlockSpec(
-        (None, pl.Element(b_blk), pl.Element(hp_blk), pl.Element(g.w),
-         pl.Element(c_blk // g.taps_folded)),
+        (None, pl.Element(b_blk), pl.Element(x_rows),
+         pl.Element(x.shape[3]), pl.Element(c_blk // g.taps_folded)),
         lambda bh, mi, ci: (mi // n_mg, (bh // n_h) * b_blk,
-                            (bh % n_h) * row_step, 0,
+                            first_row(bh % n_h), 0,
                             ci * c_blk if n_c > 1 else 0))
     # bias and the requantize multiplier ride as (1, M_BLK) lane rows
     vec_spec = pl.BlockSpec((None, 1, m_blk),
@@ -448,8 +549,8 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     args = [x, w, b]
     if quantized:
         in_specs.append(vec_spec)
-        args.append(jnp.pad(scale.astype(jnp.float32).reshape(groups, 1, mg),
-                            ((0, 0), (0, 0), (0, mgp - mg))))
+        args.append(_pad(scale.astype(jnp.float32).reshape(groups, 1, mg),
+                         ((0, 0), (0, 0), (0, mgp - mg))))
     out_spec = pl.BlockSpec(
         (None, b_blk, pr, pw, m_blk),
         lambda bh, mi, ci: (mi // n_mg, bh // n_h, bh % n_h, 0, mi % n_mg))
@@ -468,6 +569,13 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
         lanes = LANE if m_blk % LANE == 0 else m_blk
         scratch.append(pltpu.VMEM((m_blk // lanes, b_blk, oh_ext, g.ow_p,
                                    lanes), jnp.float32))
+    semantics = ("parallel", "parallel", "arbitrary")
+    if halo is not None:
+        # the line buffer: the padded x tile, made in VMEM (filled at the
+        # first M-tile of a group, so that axis runs in order)
+        scratch.append(pltpu.VMEM(
+            (b_blk, hp_blk, g.w, c_blk // g.taps_folded), x.dtype))
+        semantics = ("parallel", "arbitrary", "arbitrary")
 
     out = pl.pallas_call(
         kernel,
@@ -477,7 +585,7 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
         out_shape=out_shape,
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=semantics),
         interpret=interpret,
     )(*args)
     out = out[:, :B, :ph, :, :mg]                  # (G, B, PH, PW, mg)

@@ -16,6 +16,7 @@ from typing import Callable, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.roofline import VMEM_BYTES
 from repro.kernels import ref
 from repro.kernels.conv_pipe import LANE, conv_pipe
 from repro.kernels.flash_attention import flash_attention
@@ -48,16 +49,19 @@ def _kernel_wrapper(static_argnames: Tuple[str, ...]) -> Callable:
 
 @_kernel_wrapper((
     "stride", "pad", "relu", "pool", "pool_k", "pool_s", "use_pallas",
-    "c_blk", "m_blk", "oh_blk", "b_blk", "groups", "plan", "out_scale"))
+    "c_blk", "m_blk", "oh_blk", "b_blk", "groups", "plan", "out_scale",
+    "vmem_budget"))
 def fused_conv(x, w, b, *, scale=None, out_scale=None, stride=1, pad=0,
                relu=True, pool=None, pool_k=2, pool_s=2, use_pallas=False,
                c_blk=LANE, m_blk=LANE, oh_blk=0, b_blk=1, groups=1,
-               plan=None, interpret=False):
+               plan=None, vmem_budget=VMEM_BYTES, interpret=False):
     """Fused conv(+bias)(+ReLU)(+pool), grouped-conv and batch-fold aware.
 
     ``plan`` (a frozen :class:`repro.kernels.autotune.ConvPlan`) overrides
     the c_blk/m_blk/oh_blk/b_blk knobs with an autotuned point; being
-    hashable it rides through jit as a static argument.
+    hashable it rides through jit as a static argument. ``vmem_budget``
+    is the VMEM the plan was tuned under: the kernel makes the conv's
+    halo in VMEM where that still fits it.
 
     ``scale`` selects the int8 path: x/w are int8 codes, ``scale`` the
     (M,) combined s_x*s_w requantize multiplier, and ``out_scale``
@@ -73,7 +77,8 @@ def fused_conv(x, w, b, *, scale=None, out_scale=None, stride=1, pad=0,
                          stride=stride, pad=pad, relu=relu, pool=pool,
                          pool_k=pool_k, pool_s=pool_s, c_blk=c_blk,
                          m_blk=m_blk, oh_blk=oh_blk, b_blk=b_blk,
-                         groups=groups, interpret=interpret)
+                         groups=groups, vmem_budget=vmem_budget,
+                         interpret=interpret)
     if scale is not None:
         return quant_ref.conv_int8_ref(x, w, b, scale, stride=stride,
                                        pad=pad, relu=relu, pool=pool,

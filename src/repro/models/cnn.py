@@ -124,7 +124,8 @@ def run_group(params, x: jax.Array, cfg: CNNConfig,
                               pool_k=(pool.kernel if pool else 2),
                               pool_s=(pool.stride if pool else 2),
                               use_pallas=use_pallas, groups=l.groups,
-                              plan=plan, **epilogue)
+                              plan=plan, vmem_budget=cfg.vmem_budget,
+                              **epilogue)
     if l.kind == "fc":
         blocks = ({} if plan is None
                   else dict(bm=plan.bm, bn=plan.bn, bk=plan.bk))

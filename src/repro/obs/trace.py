@@ -63,22 +63,24 @@ nothing. ``REPRO_SPANS=0`` in the environment at import, or
 spans and counters; :meth:`SpanLog.export` writes them through a
 :class:`TraceRecorder`, one ``wall`` track, for Perfetto.
 
-  ===============  =====  ===============================================
-  name             kind   meaning
-  ===============  =====  ===============================================
-  cnn.forward      span   one ``CompiledCNN.forward``: batch, images, bytes
-  cnn.h2d          span   the host's time in the batch's copy call
-  cnn.dispatch     span   the jitted call, up to its return (not-ready)
-  cnn.retrace      inst.  + counter: ``jax.jit`` traced the forward
-  py.gc            span   one generation-1 or -2 collection
-  py.gc.gen0       count  generation-0 collections (counted only)
-  conv.kw_fold     count  ``compile_cnn``: a conv group whose column taps
-                          ``conv_pipe`` folds into its contraction
-  conv.kh_fold     count  ``compile_cnn``: a conv group whose row taps
-                          fold into that contraction too
-  conv.pool_fused  count  ``compile_cnn``: a conv group whose pool runs in
-                          ``conv_pipe``'s epilogue
-  ===============  =====  ===============================================
+  ===================  =====  ===============================================
+  name                 kind   meaning
+  ===================  =====  ===============================================
+  cnn.forward          span   one ``CompiledCNN.forward``: batch, images, bytes
+  cnn.h2d              span   the host's time in the batch's copy call
+  cnn.dispatch         span   the jitted call, up to its return (not-ready)
+  cnn.retrace          inst.  + counter: ``jax.jit`` traced the forward
+  py.gc                span   one generation-1 or -2 collection
+  py.gc.gen0           count  generation-0 collections (counted only)
+  conv.kw_fold         count  ``compile_cnn``: a conv group whose column taps
+                              ``conv_pipe`` folds into its contraction
+  conv.kh_fold         count  ``compile_cnn``: a conv group whose row taps
+                              fold into that contraction too
+  conv.pool_fused      count  ``compile_cnn``: a conv group whose pool runs in
+                              ``conv_pipe``'s epilogue
+  conv.halo_in_kernel  count  ``compile_cnn``: a conv group whose zero halo
+                              ``conv_pipe`` makes in VMEM, not the wrapper
+  ===================  =====  ===============================================
 
 An instant is a span whose end equals its start.
 """

@@ -110,15 +110,21 @@ def resolve_group_plans(cfg: CNNConfig, batch: int,
     return plans
 
 
-def _count_conv_paths(cfg: CNNConfig, batch: int, dtype: str) -> None:
+def _count_conv_paths(cfg: CNNConfig, batch: int, dtype: str,
+                      plans: Dict[Tuple[int, ...], Any]) -> None:
     """Count (``conv.kw_fold``) each conv group whose column taps
     ``conv_pipe`` folds into its MXU contraction, (``conv.kh_fold``) each
     whose row taps fold too (:func:`~repro.kernels.conv_pipe.s2d_geometry`
-    decides), and (``conv.pool_fused``) each whose pool runs in its
-    epilogue."""
-    for _, kind, s in _group_shapes(cfg, batch, dtype):
+    decides), (``conv.pool_fused``) each whose pool runs in its epilogue,
+    and (``conv.halo_in_kernel``) each whose halo it makes in VMEM under
+    its plan (:func:`~repro.kernels.autotune.halo_in_kernel`)."""
+    for group, kind, s in _group_shapes(cfg, batch, dtype):
         if kind != "conv":
             continue
+        p = plans[group]
+        if autotune.halo_in_kernel(s, p.c_blk, p.m_blk, p.oh_blk, p.b_blk,
+                                   cfg.vmem_budget):
+            SPANS.count("conv.halo_in_kernel")
         g = s2d_geometry(s.h, s.w, s.c // s.groups, s.kh, s.kw,
                          stride=s.stride, pad=s.pad)
         if g.kw_fold > 1:
@@ -573,9 +579,10 @@ def compile_cnn(cfg: CNNConfig, spec: Optional[ExecutionSpec] = None,
 
         group_plans: Dict[Tuple[int, ...], Any] = {}
         if spec.use_pallas:
-            _count_conv_paths(rcfg, spec.serving.batch, spec.run_dtype)
             group_plans = resolve_group_plans(
                 rcfg, spec.serving.batch, spec.run_dtype)
+            _count_conv_paths(rcfg, spec.serving.batch, spec.run_dtype,
+                              group_plans)
             R, S = spec.placement.replicas, spec.placement.pp_stages
             if R > 1 and S == 1:
                 # the packed (R * batch) super-batch's tuned plans are

@@ -278,19 +278,126 @@ def test_kh_fold_rule_on_vgg16():
              for g, p in compiled.group_plans.items()
              if isinstance(p, autotune.ConvPlan)}
     assert [plans[g][0] for g in ((0,), (1, 2), (3,))] == [27, 576, 576]
+    # the working set of a group whose halo the kernel makes counts its
+    # line buffer: (4, 5), (8, 9) and conv5 (the others' would not fit)
     wide = {
-        (4, 5): (128, 128, 16, 1, 9478144),
+        (4, 5): (128, 128, 16, 1, 10436608),
         (6,): (128, 256, 8, 4, 16236544),
         (7,): (128, 256, 8, 4, 16236544),
-        (8, 9): (256, 256, 8, 2, 13615104),
+        (8, 9): (256, 256, 8, 2, 14598144),
         (10,): (256, 256, 4, 8, 16531456),
         (11,): (256, 256, 4, 8, 16531456),
         (12, 13): (256, 256, 4, 8, 16007168),
-        (14,): (128, 512, 14, 4, 16482304),
-        (15,): (128, 512, 14, 4, 16482304),
-        (16, 17): (128, 512, 14, 4, 15564800)}
+        (14,): (128, 512, 14, 4, 16613376),
+        (15,): (128, 512, 14, 4, 16613376),
+        (16, 17): (128, 512, 14, 4, 15695872)}
     assert {g: plans[g] for g in wide} == wide
     assert compiled.verify() == []
+
+
+# ---------------------------------------------------------------------------
+# the halo made in VMEM: a stride-1 conv's x goes in unpadded
+# ---------------------------------------------------------------------------
+
+def _halo_engaged(B, H, C, K, M, dtype="float32", **kw):
+    s = autotune.ConvShape(h=H, w=H, c=C, kh=K, kw=K, m=M,
+                           pad=kw.get("pad", 0), groups=kw.get("groups", 1),
+                           pool=kw.get("pool"), pool_k=kw.get("pool_k", 2),
+                           pool_s=kw.get("pool_s", 2), dtype=dtype, b=B)
+    return autotune.halo_in_kernel(s, kw["c_blk"], kw["m_blk"],
+                                   kw.get("oh_blk", 0), kw.get("b_blk", 1))
+
+
+@pytest.mark.parametrize("B,H,C,K,M,kw", [
+    # pad 1 over four H-tiles: a first, two interior, and a last whose
+    # rows run past the pad (oh_blk 5 does not divide 16)
+    (1, 16, 8, 3, 8, dict(pad=1, oh_blk=5, c_blk=8, m_blk=8)),
+    # pad 2, 5x5 taps, over five H-tiles
+    (1, 13, 4, 5, 8, dict(pad=2, oh_blk=3, c_blk=4, m_blk=8)),
+    # one full-height tile taller than the image (VGG conv5: H 14,
+    # hp_blk 16), two images a step
+    (2, 14, 8, 3, 8, dict(pad=1, oh_blk=14, b_blk=2, c_blk=8, m_blk=8)),
+    # columns out to g.w 34 for W 28, wider than the pad (VGG conv4)
+    (1, 28, 8, 3, 8, dict(pad=1, oh_blk=8, c_blk=8, m_blk=8)),
+    # the row- and column-folded layers: C 3 (conv1_1) and C 64 (conv1_2)
+    (2, 12, 3, 3, 16, dict(pad=1, oh_blk=4, c_blk=27, m_blk=16)),
+    (1, 10, 64, 3, 16, dict(pad=1, oh_blk=4, c_blk=576, m_blk=16)),
+    # a fused 3x3/2 pool over H-tiles
+    (1, 17, 8, 3, 8, dict(pad=1, pool="max", pool_k=3, pool_s=2,
+                          oh_blk=4, c_blk=8, m_blk=8)),
+    # AlexNet conv2's layout: two groups of 48, 5x5 taps, pad 2
+    (2, 9, 96, 5, 16, dict(pad=2, groups=2, c_blk=240, m_blk=8)),
+    # two C-tiles and two M-tiles: the tile is made again per C-tile;
+    # b_blk 2 does not divide a batch of 3
+    (3, 9, 96, 3, 16, dict(pad=1, oh_blk=4, b_blk=2, c_blk=48, m_blk=8)),
+], ids=["pad1_tiles", "pad2_tiles", "full_height", "right_overhang",
+        "fold_c3", "fold_c64", "pool", "grouped_pad2", "c_tiles"])
+def test_halo_in_kernel_matches_oracle(B, H, C, K, M, kw):
+    """The kernel makes the pad rows and columns, the columns out to
+    ``ow_p + kw - 1`` and the last tile's rows in VMEM: the oracle's
+    answer, and the wrapper-padded kernel's sums bit for bit."""
+    assert _halo_engaged(B, H, C, K, M, **kw)
+    _check(B, H, C, K, M, **kw)
+    x = _rand((B, H, H, C))
+    w = _rand((K, K, C // kw.get("groups", 1), M), scale=0.2)
+    b = _rand((M,))
+    got = conv_pipe(x, w, b, **kw)
+    padded = conv_pipe(x, w, b, **kw, vmem_budget=0)   # the wrapper's pad
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(padded))
+
+
+def test_halo_in_kernel_int8_bit_equal():
+    """int8 tiles take the halo made in VMEM too: AlexNet conv2's grouped
+    pad-2 layout with its pool, code for code with the exact-int
+    reference."""
+    x = _rand((3, 11, 11, 96))
+    w = _rand((5, 5, 48, 16), scale=0.2)
+    b = _rand((16,), scale=0.1)
+    sx = float(abs_max_scale(x))
+    wq, ws = quantize_channelwise(w, axis=-1)
+    xq = quantize(x, sx)
+    kw = dict(pad=2, groups=2, pool="max", pool_k=3, pool_s=2)
+    blocks = dict(c_blk=240, m_blk=8, oh_blk=4, b_blk=2)
+    assert _halo_engaged(3, 11, 96, 5, 16, dtype="int8", **kw, **blocks)
+    got = conv_pipe(xq, wq, b, scale=ws * sx, out_scale=0.05, **blocks,
+                    **kw)
+    want = qref.conv_int8_ref(xq, wq, b, ws * sx, out_scale=0.05, **kw)
+    assert got.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_halo_follows_the_vmem_budget():
+    """A plan whose working set with the line buffer would pass its
+    budget keeps the wrapper's pad, so every plan fits a budget exactly
+    when it did before: VGG-16 conv4_2 at its tuned batch-32 plan."""
+    s = autotune.ConvShape(h=28, w=28, c=512, kh=3, kw=3, m=512, pad=1,
+                           b=32)
+    plan = (256, 256, 4, 8)
+    assert not autotune.halo_in_kernel(s, *plan)
+    assert autotune.conv_vmem_bytes(s, *plan) == 16531456
+    assert autotune.halo_in_kernel(s, *plan, vmem_budget=32 * 2 ** 20)
+    assert autotune.conv_vmem_bytes(s, *plan, 32 * 2 ** 20) > 16531456
+    conv1_2 = autotune.ConvShape(h=224, w=224, c=64, kh=3, kw=3, m=64,
+                                 pad=1, pool="max", b=32)
+    assert autotune.halo_in_kernel(conv1_2, 576, 64, 4, 1)
+    assert not autotune.halo_in_kernel(
+        autotune.ConvShape(h=227, w=227, c=3, kh=11, kw=11, m=96,
+                           stride=4, b=32), 432, 96, 8, 2)
+
+
+def test_stride1_conv_no_pad_outside_kernel():
+    """Acceptance: a stride-1 conv's input reaches the ``pallas_call``
+    unpadded — no ``pad`` primitive in the wrapper."""
+    x = _rand((1, 15, 15, 8))
+    w = _rand((3, 3, 8, 16), scale=0.2)
+    b = _rand((16,))
+    jaxpr = jax.make_jaxpr(
+        lambda x, w, b: ops.fused_conv(
+            x, w, b, pad=1, pool="max", pool_k=2, pool_s=2,
+            use_pallas=True, oh_blk=4))(x, w, b).jaxpr
+    prims = _prims_outside_kernels(jaxpr)
+    assert prims.count("pallas_call") == 1
+    assert "pad" not in prims
 
 
 # ---------------------------------------------------------------------------

@@ -225,6 +225,22 @@ def test_pool_fused_counted_per_pooled_conv_group(spans, arch, pooled):
     assert spans.read()["counters"].get("conv.pool_fused") == pooled
 
 
+@pytest.mark.parametrize("arch,halo", [("alexnet", 4), ("vgg16", 8)])
+def test_halo_in_kernel_counted_per_conv_group(spans, arch, halo):
+    """``compile_cnn`` counts ``conv.halo_in_kernel`` once for each conv
+    group whose zero halo ``conv_pipe`` makes in VMEM: AlexNet conv2-5
+    (conv1 is strided, its space-to-depth copy holds the halo); VGG-16's
+    groups whose plan still fits the budget with the line buffer, all
+    but conv3_1, conv3_2 and conv4_1-4_3, which keep the wrapper's pad."""
+    from repro.models.cnn import init_cnn_params
+
+    cfg = get_config(arch)
+    params = jax.eval_shape(lambda: init_cnn_params(jax.random.key(0), cfg))
+    compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=8)), params,
+                with_engine=False)
+    assert spans.read()["counters"].get("conv.halo_in_kernel") == halo
+
+
 def test_chrome_export_validates(compiled, spans):
     c, cfg = compiled
     np.asarray(c.forward(_images(cfg, 4)))         # traced: one instant
