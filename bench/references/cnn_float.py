@@ -1,9 +1,13 @@
-"""Plain float32 reference of a CNN layer chain, built from the
+"""Plain float32 reference of a CNN layer graph, built from the
 configuration alone (it imports nothing of the program).
 
 Layers: conv (``lax.conv_general_dilated``, groups as feature groups)
-+ bias + ReLU; max pool over VALID windows; LRN across channels; FC
-(NHWC flatten) + bias (+ ReLU). Every dot runs at ``highest``.
++ bias + ReLU; max pool (padded with -inf where ``pad``) and average
+pool (VALID windows, divided by k * k); LRN across channels; FC (NHWC
+flatten) + bias (+ ReLU); ``add``, the sum of the layers it names, then
+ReLU where ``relu``; ``gap``, the mean over H x W. A layer reads the
+layers its ``in`` names (-1 is the image), else the one before it; the
+logits are the last layer's output. Every dot runs at ``highest``.
 
 LRN is the one the configuration states. For ``"form": "pwl"`` that is
 the paper's piecewise-linear z**-beta (PipeCNN, section III): z =
@@ -103,8 +107,15 @@ def _lrn(x, p: dict):
 def _forward(cfg: dict, passes: str, params: List[Any], x):
     import jax
     import jax.numpy as jnp
-    for l, p in zip(cfg["layers"], params):
+    layers = cfg["layers"]
+    # outputs some later layer reads by index (-1: the image)
+    named = {j for l in layers for j in l.get("in", ())}
+    kept = {-1: x} if -1 in named else {}
+    for i, (l, p) in enumerate(zip(layers, params)):
         kind = l["kind"]
+        ins = [kept[j] for j in l.get("in", ())]
+        if ins:
+            x = ins[0]
         if kind == "conv":
             conv = functools.partial(
                 jax.lax.conv_general_dilated,
@@ -116,11 +127,17 @@ def _forward(cfg: dict, passes: str, params: List[Any], x):
             if l["relu"]:
                 x = jnp.maximum(x, 0.0)
         elif kind == "pool":
-            if l["op"] != "max":
-                raise ValueError(f"pool op {l['op']!r} not in the reference")
-            k, s = l["k"], l["stride"]
-            x = jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                      (1, k, k, 1), (1, s, s, 1), "VALID")
+            k, s, pad = l["k"], l["stride"], l.get("pad", 0)
+            if l["op"] == "max":
+                x = jax.lax.reduce_window(
+                    x, -jnp.inf, jax.lax.max, (1, k, k, 1), (1, s, s, 1),
+                    [(0, 0), (pad, pad), (pad, pad), (0, 0)])
+            elif l["op"] == "avg" and not pad:
+                x = jax.lax.reduce_window(x, 0.0, jax.lax.add, (1, k, k, 1),
+                                          (1, s, s, 1), "VALID") / (k * k)
+            else:
+                raise ValueError(f"layer {i}: pool op {l['op']!r} with pad "
+                                 f"{pad} not in the reference")
         elif kind == "lrn":
             x = _lrn(x, cfg["lrn"])
         elif kind == "fc":
@@ -128,8 +145,17 @@ def _forward(cfg: dict, passes: str, params: List[Any], x):
             x = _dot(jnp.dot, x, p["w"], passes) + p["b"]
             if l["relu"]:
                 x = jnp.maximum(x, 0.0)
+        elif kind == "add":
+            for y in ins[1:]:
+                x = x + y
+            if l["relu"]:
+                x = jnp.maximum(x, 0.0)
+        elif kind == "gap":
+            x = jnp.mean(x, axis=(1, 2), keepdims=True)
         else:
-            raise ValueError(f"unknown layer kind {kind!r}")
+            raise ValueError(f"layer {i}: unknown layer kind {kind!r}")
+        if i in named:
+            kept[i] = x
     return x
 
 

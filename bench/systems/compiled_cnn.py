@@ -19,20 +19,44 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 
 def _program_layers(pcfg) -> List[dict]:
-    """The program's layer list in the configuration file's terms."""
+    """The program's layer list in the configuration file's terms.
+
+    Each program layer has ``kind``; a conv ``out_ch``, ``kernel``,
+    ``stride``, ``pad``, ``groups``, ``relu``; a pool ``pool`` (its op),
+    ``kernel``, ``stride``, ``pad``; an FC ``out_ch``, ``relu``; an
+    ``add`` (the sum of its inputs, then ReLU where set) ``relu``; an
+    ``lrn`` and a ``gap`` (the mean over H x W) nothing more. Any layer
+    may have ``inputs``: a tuple of earlier layer indices it reads, -1
+    for the image, ``()`` (or no attribute) for the layer before it.
+    They map to ``in``, ``out``, ``k``, ``stride``, ``pad``, ``groups``,
+    ``relu`` and ``op``; ``in`` only where ``inputs`` is not empty, a
+    pool's ``pad`` only where it is not 0, so a chain maps to a chain.
+    A kind not named here is an error.
+    """
     out = []
-    for l in pcfg.layers:
+    for i, l in enumerate(pcfg.layers):
         if l.kind == "conv":
-            out.append({"kind": "conv", "out": l.out_ch, "k": l.kernel,
-                        "stride": l.stride, "pad": l.pad,
-                        "groups": l.groups, "relu": l.relu})
+            d = {"kind": "conv", "out": l.out_ch, "k": l.kernel,
+                 "stride": l.stride, "pad": l.pad, "groups": l.groups,
+                 "relu": l.relu}
         elif l.kind == "pool":
-            out.append({"kind": "pool", "op": l.pool, "k": l.kernel,
-                        "stride": l.stride})
-        elif l.kind == "lrn":
-            out.append({"kind": "lrn"})
+            d = {"kind": "pool", "op": l.pool, "k": l.kernel,
+                 "stride": l.stride}
+            if l.pad:
+                d["pad"] = l.pad
+        elif l.kind in ("lrn", "gap"):
+            d = {"kind": l.kind}
+        elif l.kind == "fc":
+            d = {"kind": "fc", "out": l.out_ch, "relu": l.relu}
+        elif l.kind == "add":
+            d = {"kind": "add", "relu": l.relu}
         else:
-            out.append({"kind": "fc", "out": l.out_ch, "relu": l.relu})
+            raise ValueError(f"the program's layer {i} has kind {l.kind!r}, "
+                             f"which the benchmark does not know")
+        inputs = getattr(l, "inputs", ())
+        if inputs:
+            d["in"] = list(inputs)
+        out.append(d)
     return out
 
 

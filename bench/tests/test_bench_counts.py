@@ -29,6 +29,91 @@ def _vgg16():
             "precision": {"dtype": "float32"}}
 
 
+def _resnet50():
+    """ResNet-50 v1.5: arXiv:1512.03385 Table 1, the 50-layer column,
+    with each bottleneck's stride on its 3x3 conv (torchvision's
+    ``resnet50``). Batch-norm is folded into each conv's weight and bias.
+    A stage's first block reads its input through a projection, a 1x1
+    conv at the block's stride with no ReLU; every block ends in an add
+    with ReLU."""
+    conv = lambda m, k, s, relu=True, **kw: dict(
+        {"kind": "conv", "out": m, "k": k, "stride": s, "pad": k // 2,
+         "groups": 1, "relu": relu}, **kw)
+    layers = [conv(64, 7, 2),
+              {"kind": "pool", "op": "max", "k": 3, "stride": 2, "pad": 1}]
+    for n, width, stride in ((3, 64, 1), (4, 128, 2), (6, 256, 2),
+                             (3, 512, 2)):
+        for b in range(n):
+            x = len(layers) - 1            # the block's input
+            s = stride if b == 0 else 1
+            layers += [conv(width, 1, 1), conv(width, 3, s),
+                       conv(4 * width, 1, 1, relu=False)]
+            short = x
+            if b == 0:
+                layers.append(conv(4 * width, 1, s, relu=False, **{"in": [x]}))
+                short = len(layers) - 1
+            main = len(layers) - 2 if b == 0 else len(layers) - 1
+            layers.append({"kind": "add", "in": [main, short], "relu": True})
+    layers += [{"kind": "gap"}, {"kind": "fc", "out": 1000, "relu": False}]
+    return {"input": {"hw": 224, "ch": 3}, "layers": layers,
+            "precision": {"dtype": "float32"}}
+
+
+def test_resnet50_v1_5_counts_pinned():
+    cfg = _resnet50()
+    costs = layer_costs(cfg)
+    kinds = [c.kind for c in costs]
+    assert len(costs) == 72
+    assert kinds.count("conv") == 53 and kinds.count("add") == 16
+    # 4.089 G multiply-accumulates: the adds and the pools count none
+    assert flops_per_image(cfg) == 8_178_368_512
+    # torchvision's 25,557,032 less batch-norm's second parameter per
+    # channel (26,560 channels), folded into the conv's weight and bias
+    assert sum(c.weight_elems for c in costs) == 25_530_472
+    assert costs[1].out_shape == (56, 56, 64)        # the padded pool
+    assert costs[-2].out_shape == (1, 1, 2048)       # gap
+    assert costs[-1].out_shape == (1, 1, 1000)
+
+
+def test_add_and_gap_count_their_bytes_and_no_operations():
+    costs = layer_costs(_resnet50())
+    add = next(c for c in costs if c.kind == "add")
+    assert add.flops == add.weight_elems == 0
+    assert add.in_shape == add.out_shape == (56, 56, 256)
+    assert add.act_elems == 3 * 56 * 56 * 256        # two inputs, one output
+    gap = costs[-2]
+    assert gap.flops == gap.weight_elems == 0
+    assert gap.act_elems == 7 * 7 * 2048 + 2048
+
+
+def _graph(*layers):
+    return {"input": {"hw": 8, "ch": 4}, "layers": list(layers),
+            "precision": {"dtype": "float32"}}
+
+
+_CONV = {"kind": "conv", "out": 4, "k": 3, "stride": 1, "pad": 1,
+         "groups": 1, "relu": True}
+
+
+@pytest.mark.parametrize("cfg,layer", [
+    (_graph(_CONV, dict(_CONV, **{"in": [2]}), _CONV), 1),       # forward
+    (_graph(_CONV, dict(_CONV, **{"in": [1]})), 1),              # itself
+    (_graph(_CONV, dict(_CONV, **{"in": [-2]})), 1),             # before -1
+    (_graph(_CONV, dict(_CONV, stride=2),
+            {"kind": "add", "in": [0, 1], "relu": True}), 2),    # shapes
+    (_graph(_CONV, dict(_CONV, out=8),
+            {"kind": "add", "in": [0, 1], "relu": True}), 2),    # channels
+    (_graph(_CONV, {"kind": "add", "in": [0], "relu": True}), 1),  # one in
+    (_graph(_CONV, _CONV, dict(_CONV, **{"in": [0, 1]})), 2),    # not add
+    (_graph(_CONV, {"kind": "pool", "op": "avg", "k": 3, "stride": 2,
+                    "pad": 1}), 1),                              # avg pad
+    (_graph(_CONV, {"kind": "softmax"}), 1),                     # kind
+])
+def test_malformed_graph_names_its_layer(cfg, layer):
+    with pytest.raises(ValueError, match=rf"^layer {layer}\b"):
+        layer_costs(cfg)
+
+
 def test_alexnet_flops_pinned():
     # 2 x MACs over 5 convs (conv2/4/5 in two groups) and 3 FC layers
     assert flops_per_image(_config("alexnet")) == 1_448_813_632

@@ -4,7 +4,8 @@ these; neither makes its own.
 Weights are made on the device in one jitted call, in the layout the
 program serves them in: per layer ``{"w": (k, k, in/groups, out), "b":
 (out,)}`` for conv, ``{"w": (h*w*c, out), "b": (out,)}`` for FC (rows in
-NHWC flatten order), ``None`` for pool and LRN.
+NHWC flatten order), ``None`` for the layers with no weights (pool, LRN,
+add, gap), which draw no key: the keys follow the conv and FC layers.
 """
 from __future__ import annotations
 
@@ -31,7 +32,8 @@ def jax_key(seed: int, stream: int):
 
 
 def param_shapes(cfg: dict) -> List[Optional[tuple]]:
-    """``(w_shape, fan_in, relu)`` per layer, ``None`` for pool / LRN."""
+    """``(w_shape, fan_in, relu)`` per conv or FC layer, ``None`` for
+    the others; a layer's input shape follows its ``in``."""
     from bench.counts import layer_costs
     out: List[Optional[tuple]] = []
     for l, cost in zip(cfg["layers"], layer_costs(cfg)):
