@@ -36,7 +36,6 @@ unchanged by a verification pass.
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 from pathlib import Path
 from typing import List, Optional
@@ -288,14 +287,14 @@ def _check_coverage(table, cfg: CNNConfig, spec, path: str,
                 json.dumps(row["plan"], sort_keys=True))
     for group, kind, shape in _group_shapes(cfg, spec.serving.batch,
                                             spec.run_dtype):
-        k = (json.dumps(dataclasses.asdict(shape), sort_keys=True),
+        k = (json.dumps(shape.to_dict(), sort_keys=True),
              cfg.vmem_budget)
         plans = index.get(k, [])
         if not plans:
             findings.append(Finding(
                 "RPA305", path, 0,
                 f"fusion group {tuple(group)} ({kind}, "
-                f"{dataclasses.asdict(shape)}) has no plan row at the "
+                f"{shape.to_dict()}) has no plan row at the "
                 f"serving key (batch={spec.serving.batch}, "
                 f"dtype={spec.run_dtype!r}, budget={cfg.vmem_budget})"))
         elif len(set(plans)) > 1:

@@ -18,7 +18,7 @@ ARCH_IDS = [
     "zamba2_1p2b",
     "musicgen_medium",
 ]
-CNN_IDS = ["alexnet", "vgg16"]
+CNN_IDS = ["alexnet", "vgg16", "resnet50"]
 
 _ALIASES = {
     "internvl2-26b": "internvl2_26b",

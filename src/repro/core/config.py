@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field, replace
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 # ---------------------------------------------------------------------------
 # LM-family model configuration
@@ -212,38 +212,103 @@ def applicable_shapes(cfg: ModelConfig) -> Tuple[ShapeSpec, ...]:
 
 @dataclass(frozen=True)
 class ConvLayer:
-    kind: str                         # "conv" | "pool" | "lrn" | "fc"
+    kind: str                         # "conv" | "pool" | "lrn" | "fc" |
+    #                                   "add" | "gap"
     out_ch: int = 0
     kernel: int = 0
     stride: int = 1
-    pad: int = 0
+    pad: int = 0                      # a max pool pads with -inf
     groups: int = 1                   # AlexNet conv2/4/5 use groups=2
     pool: str = "max"                 # for kind == "pool": "max" | "avg"
-    relu: bool = True
+    relu: bool = True                 # an add's ReLU comes after the sum
     # PipeCNN fusion: pooling fused into the preceding conv's pipeline
     fuse_pool: Optional["ConvLayer"] = None
+    # the earlier layers this one reads (-1: the image); () reads the
+    # layer before it. Only an add reads two: their sum (ResNet's
+    # shortcut); gap is the mean over H x W
+    inputs: Tuple[int, ...] = ()
+
+
+def layer_inputs(layers: Sequence[ConvLayer], i: int) -> Tuple[int, ...]:
+    """The indices layer ``i`` reads (-1: the image)."""
+    return layers[i].inputs or (i - 1,)
+
+
+def _readers(layers: Sequence[ConvLayer]) -> List[List[int]]:
+    """Per layer, the later layers that read its output."""
+    out: List[List[int]] = [[] for _ in layers]
+    for i in range(len(layers)):
+        for j in layer_inputs(layers, i):
+            if j >= 0:
+                out[j].append(i)
+    return out
 
 
 def fuse_groups(layers: Sequence["ConvLayer"]) -> List[Tuple[int, ...]]:
     """Group layer indices into PipeCNN pipeline stages.
 
-    conv immediately followed by pool -> fused (conv+pool) kernel
-    launch; lrn stays standalone (off-pipeline, as in the paper); fc
-    standalone. THE one grouping implementation: ``models.cnn.fuse_plan``
-    executes it and ``CNNConfig.__post_init__`` validates against it, so
-    the two can never disagree.
+    conv immediately followed by an unpadded pool that reads it -> fused
+    (conv+pool) kernel launch; a conv with no ReLU immediately followed
+    by an add that reads it -> fused (conv+add): the add's other input is
+    the kernel's residual operand, summed in its epilogue before the
+    add's ReLU. Either only where nothing else reads the conv's output.
+    lrn, gap, a padded pool and a lone add stay standalone (LRN
+    off-pipeline, as in the paper); fc standalone. THE one grouping
+    implementation: ``models.cnn.fuse_plan`` executes it and
+    ``CNNConfig.__post_init__`` validates against it, so the two can
+    never disagree.
     """
+    readers = _readers(layers)
     plan: List[Tuple[int, ...]] = []
     i = 0
     while i < len(layers):
-        if (layers[i].kind == "conv" and i + 1 < len(layers)
-                and layers[i + 1].kind == "pool"):
+        nxt = layers[i + 1] if i + 1 < len(layers) else None
+        if (layers[i].kind == "conv" and nxt is not None
+                and readers[i] == [i + 1]
+                and ((nxt.kind == "pool" and not nxt.pad
+                      and not nxt.inputs)
+                     or (nxt.kind == "add" and not layers[i].relu))):
             plan.append((i, i + 1))
             i += 2
         else:
             plan.append((i,))
             i += 1
     return plan
+
+
+def group_inputs(layers: Sequence[ConvLayer], group: Tuple[int, ...]
+                 ) -> Tuple[int, ...]:
+    """The layers whose outputs a fusion group reads (-1: the image):
+    its first layer's inputs, then, for a conv fused with an add, the
+    add's other input (the residual operand)."""
+    ins = layer_inputs(layers, group[0])
+    if len(group) == 2 and layers[group[1]].kind == "add":
+        ins += tuple(j for j in layer_inputs(layers, group[1])
+                     if j != group[0])
+    return ins
+
+
+def layer_shapes(cfg: "CNNConfig") -> Dict[int, Tuple[int, int, int]]:
+    """Layer index -> its output ``(H, W, C)`` per image (an fc's is
+    ``(1, 1, out_ch)``; -1, the image), following each layer's
+    inputs."""
+    shapes = {-1: (cfg.input_hw, cfg.input_hw, cfg.input_ch)}
+    for i, l in enumerate(cfg.layers):
+        h, w, c = shapes[layer_inputs(cfg.layers, i)[0]]
+        if l.kind == "conv":
+            shapes[i] = ((h + 2 * l.pad - l.kernel) // l.stride + 1,
+                         (w + 2 * l.pad - l.kernel) // l.stride + 1,
+                         l.out_ch)
+        elif l.kind == "pool":
+            shapes[i] = ((h + 2 * l.pad - l.kernel) // l.stride + 1,
+                         (w + 2 * l.pad - l.kernel) // l.stride + 1, c)
+        elif l.kind == "fc":
+            shapes[i] = (1, 1, l.out_ch)
+        elif l.kind == "gap":
+            shapes[i] = (1, 1, c)
+        else:                                    # lrn, add
+            shapes[i] = (h, w, c)
+    return shapes
 
 
 @dataclass(frozen=True)
@@ -308,6 +373,7 @@ class CNNConfig:
                 "CNNConfig.replicas",
                 f"CNNConfig.replicas={self.replicas} / "
                 f"pp_stages={self.pp_stages}: both must be >= 1")
+        self._check_graph()
         n_groups = self.n_fuse_groups
         if self.layers and self.pp_stages > n_groups:
             raise SpecError(
@@ -325,6 +391,54 @@ class CNNConfig:
                 f"must divide it (pick b_blk in "
                 f"{[d for d in range(1, self.serve_batch + 1) if self.serve_batch % d == 0]})")
 
+    def _check_graph(self) -> None:
+        """Each layer reads earlier layers; only an add reads two, of one
+        shape; only a max pool pads. int8 calibration and pipeline
+        stages walk a chain, so a graph takes neither."""
+        for i, l in enumerate(self.layers):
+            ins = layer_inputs(self.layers, i)
+            if any(not -1 <= j < i for j in ins):
+                raise SpecError(
+                    "CNNConfig.layers",
+                    f"layer {i} ({l.kind}) reads {ins}: inputs must be "
+                    f"earlier layers (-1 is the image)")
+            if len(ins) != (2 if l.kind == "add" else 1):
+                raise SpecError(
+                    "CNNConfig.layers",
+                    f"layer {i} ({l.kind}) reads {len(ins)} layers: an "
+                    f"add reads two, every other kind one")
+            if l.kind == "pool" and l.pad and l.pool != "max":
+                raise SpecError(
+                    "CNNConfig.layers",
+                    f"layer {i}: only a max pool takes a pad")
+        if self.is_graph:
+            shapes = layer_shapes(self)
+            for i, l in enumerate(self.layers):
+                ins = layer_inputs(self.layers, i)
+                if l.kind == "add" and len({shapes[j] for j in ins}) != 1:
+                    raise SpecError(
+                        "CNNConfig.layers",
+                        f"layer {i} (add): its inputs {ins} differ in "
+                        f"shape")
+            if self.quant != "none":
+                raise SpecError(
+                    "CNNConfig.quant",
+                    f"CNNConfig.quant={self.quant!r}: int8 calibration "
+                    f"walks a chain of layers, and {self.name!r} is a "
+                    f"layer graph; serve it in float")
+            if self.pp_stages > 1:
+                raise SpecError(
+                    "CNNConfig.pp_stages",
+                    f"CNNConfig.pp_stages={self.pp_stages}: pipeline "
+                    f"stages pass one activation between them, and "
+                    f"{self.name!r} is a layer graph; place it with "
+                    f"replicas instead")
+
+    @property
+    def is_graph(self) -> bool:
+        """Some layer reads other than the layer before it."""
+        return any(l.inputs for l in self.layers)
+
     @property
     def n_fuse_groups(self) -> int:
         """Count of indivisible pipeline fusion groups (the grouping
@@ -341,22 +455,16 @@ class CNNConfig:
 
 
 def flops_per_image(cfg: CNNConfig) -> int:
-    """Multiply-accumulate op count (2 ops per MAC), as GOPS in the paper."""
-    h = w = cfg.input_hw
-    c = cfg.input_ch
+    """Multiply-accumulate op count (2 ops per MAC), as GOPS in the paper;
+    a layer's input follows its ``inputs``."""
+    shapes = layer_shapes(cfg)
     total = 0
-    for l in cfg.layers:
+    for i, l in enumerate(cfg.layers):
+        h, w, c = shapes[layer_inputs(cfg.layers, i)[0]]
         if l.kind == "conv":
-            h = (h + 2 * l.pad - l.kernel) // l.stride + 1
-            w = (w + 2 * l.pad - l.kernel) // l.stride + 1
-            total += 2 * h * w * l.out_ch * l.kernel * l.kernel \
+            oh, ow, _ = shapes[i]
+            total += 2 * oh * ow * l.out_ch * l.kernel * l.kernel \
                 * (c // l.groups)
-            c = l.out_ch
-        elif l.kind == "pool":
-            h = (h - l.kernel) // l.stride + 1
-            w = (w - l.kernel) // l.stride + 1
         elif l.kind == "fc":
             total += 2 * c * h * w * l.out_ch
-            h = w = 1
-            c = l.out_ch
     return total

@@ -38,7 +38,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import warnings
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from repro.core.roofline import (MXU_DIM, VMEM_BYTES, mxu_utilization,
@@ -104,6 +104,17 @@ class ConvShape:
     pool_s: int = 2
     dtype: str = "float32"
     b: int = 1                  # serving batch (images per launch)
+    # a residual operand summed in the epilogue (ResNet's shortcut add).
+    # Left out of the repr and, while False, of the serialised row, so a
+    # chain's plan rows and registry order read as they always have
+    residual: bool = field(default=False, repr=False)
+
+    def to_dict(self) -> dict:
+        """The serialised shape of a plan row."""
+        d = dataclasses.asdict(self)
+        if not self.residual:
+            del d["residual"]
+        return d
 
     @property
     def oh(self) -> int:
@@ -171,7 +182,8 @@ def conv_vmem_bytes(shape: ConvShape, c_blk: int, m_blk: int,
     tile, out tile, accumulator and temporaries scale with ``b_blk``; the
     weight tile does not — that asymmetry is the whole point of batching.
     int8 shrinks the streamed tiles (1-byte tensors) but bias/scale stay
-    fp32. Where the kernel makes the halo itself within ``vmem_budget``
+    fp32. A residual operand streams one output-shaped tile more. Where
+    the kernel makes the halo itself within ``vmem_budget``
     (:func:`halo_in_kernel`), the pipelined x block is the unpadded
     image's rows and the padded tile is a single-buffered line buffer.
     """
@@ -230,7 +242,8 @@ def _conv_vmem(shape: ConvShape, c_blk: int, m_blk: int, oh_blk: int,
              + row_pieces * b_blk * oh_ext * _tile_bytes(g.ow_p, cj, dt)
              + _tile_bytes(rows, c_blk, dt)                    # patch
              + 2 * acc)                        # tap result + epilogue
-    pipelined = x_block + w_tile + vec * (2 if quantized else 1) + o_tile
+    pipelined = (x_block + w_tile + vec * (2 if quantized else 1)
+                 + o_tile * (2 if shape.residual else 1))
     return (2 * pipelined + acc + pool + temps
             + (x_tile if halo else 0))
 
@@ -258,7 +271,7 @@ def score_plan(shape: ConvShape, c_blk: int, m_blk: int,
       x  — re-fetched for every M-tile; halo rows re-fetched per H-tile
       w  — re-fetched for every (image-block, H-tile): batch folding
            divides the per-image weight traffic by ``b_blk``
-      out — written once
+      out — written once (and a residual operand read once)
     Channel padding waste (Fig. 7's VEC_SIZE argument) shows up through
     the padded c/m tile counts; batch padding waste (a trailing partial
     image block computes zero images) through the padded image count;
@@ -284,7 +297,8 @@ def score_plan(shape: ConvShape, c_blk: int, m_blk: int,
     cx = c_blk // g.taps_folded            # channels of the x tile
     x_bytes = bp * n_h * n_m * n_c * hp_blk * g.w * cx * dt
     w_bytes = n_b * n_h * n_m * n_c * g.kh * g.kw * c_blk * m_blk * dt
-    o_bytes = bp * n_h * pr * pw * (n_m * m_blk) * dt
+    o_bytes = (bp * n_h * pr * pw * (n_m * m_blk) * dt
+               * (2 if shape.residual else 1))
     # padded-lane compute: the kernel multiplies the padded tiles
     flops = 2 * bp * n_h * oh_ext * g.ow_p * (n_m * m_blk) \
         * g.kh * g.kw * cgp
@@ -319,7 +333,8 @@ def enumerate_plans(shape: ConvShape,
 
     ``b_blk`` candidates are powers of two up to the serving batch
     ``shape.b`` (plus the batch itself); for b=1 this degenerates to the
-    PR 1 three-axis sweep.
+    PR 1 three-axis sweep. A residual conv's ``oh_blk`` divides its
+    output rows, so its residual operand needs no padded copy.
     """
     g = s2d_geometry(shape.h, shape.w, shape.c // shape.groups, shape.kh,
                      shape.kw, stride=shape.stride, pad=shape.pad)
@@ -331,6 +346,8 @@ def enumerate_plans(shape: ConvShape,
     step = shape.pool_s if shape.pool else 1
     oh_cands = sorted({min(_round_up(v, step), _round_up(shape.oh, step))
                        for v in (1, 2, 4, 8, 16, 32, 64, shape.oh)})
+    if shape.residual:
+        oh_cands = [v for v in range(1, shape.oh + 1) if shape.oh % v == 0]
     b_cands = sorted({min(v, shape.b) for v in _pow2_upto(shape.b, lo=1)})
     plans = []
     for bb in b_cands:
@@ -413,6 +430,9 @@ def measure_plan(shape: ConvShape, plan: ConvPlan, *, iters: int = 3,
     else:
         dt = jnp.float32 if shape.dtype == "float32" else jnp.bfloat16
         x, w, b = x.astype(dt), w.astype(dt), b.astype(dt)
+    if shape.residual:
+        qkw = dict(residual=jnp.ones((shape.b, shape.oh, shape.ow, shape.m),
+                                     x.dtype))
 
     def run():
         return conv_pipe(x, w, b, stride=shape.stride,
@@ -453,6 +473,10 @@ class GemmShape:
     @property
     def macs(self) -> int:
         return self.m * self.k * self.n
+
+    def to_dict(self) -> dict:
+        """The serialised shape of a plan row."""
+        return dataclasses.asdict(self)
 
 
 @dataclass(frozen=True)
@@ -687,7 +711,7 @@ def record_lookups() -> Iterator[Dict[str, list]]:
 
 def _record(kind: str, shape, backend: str, vmem_budget: int, plan) -> None:
     if _RECORDERS:
-        row = {"shape": dataclasses.asdict(shape), "backend": backend,
+        row = {"shape": shape.to_dict(), "backend": backend,
                "vmem_budget": vmem_budget, "plan": plan.to_dict()}
         for rec in _RECORDERS:
             rec[kind].append(row)
@@ -720,7 +744,7 @@ def gemm_plan_for_layer(m: int, k: int, n: int, *, dtype: str = "float32",
 
 def gemm_registry_snapshot() -> List[dict]:
     """JSON-serialisable view of every tuned GEMM (for BENCH_conv.json)."""
-    return [{"shape": dataclasses.asdict(k[0]), "backend": k[1],
+    return [{"shape": k[0].to_dict(), "backend": k[1],
              "vmem_budget": k[2], "plan": p.to_dict()} for k, p in sorted(
                  _GEMM_REGISTRY.items(), key=lambda kv: repr(kv[0]))]
 
@@ -776,7 +800,7 @@ def clear_registry() -> None:
 
 def registry_snapshot() -> List[dict]:
     """JSON-serialisable view of every tuned layer (for BENCH_conv.json)."""
-    return [{"shape": dataclasses.asdict(k[0]), "backend": k[1],
+    return [{"shape": k[0].to_dict(), "backend": k[1],
              "vmem_budget": k[2], "plan": p.to_dict()} for k, p in sorted(
                  _REGISTRY.items(), key=lambda kv: repr(kv[0]))]
 

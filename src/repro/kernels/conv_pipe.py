@@ -23,6 +23,10 @@ inter-stage data never touches DDR. On TPU the same dataflow is one
   * bias + ReLU + line-buffer pooling run in the epilogue while the tile is
     still in VMEM (the Conv->Pool channel), one 128-lane slab of a wide
     output tile at a time (VGG-16 conv5_3 + pool: four slabs of 512).
+    Or, in place of the pool, a residual operand's matching block is
+    added between the bias and the ReLU (ResNet's shortcut add).
+  * A strided 1x1 conv runs stride-1 over the subsampled image
+    (:func:`subsamples`), not over space-to-depth's zero taps.
 
 Grid: ``(B_tiles * H_tiles, M_tiles, C_tiles)`` with the input-channel axis
 LAST and "arbitrary" semantics — the fp32 VMEM scratch accumulates partial
@@ -159,11 +163,22 @@ class S2DGeometry(NamedTuple):
                                   pool_k=pool_k, pool_s=pool_s)
 
 
+def subsamples(kh: int, kw: int, stride: int) -> bool:
+    """A strided 1x1 conv reads every ``stride``-th pixel: the wrapper
+    takes those (``x[:, ::s, ::s]``) and runs a stride-1 1x1 conv, where
+    space-to-depth would run one over ``s*s`` times the channels, all
+    but one in ``s*s`` of its taps zero (ResNet's 1x1/2 projections)."""
+    return kh == kw == 1 and stride > 1
+
+
 def s2d_geometry(h: int, w: int, cg: int, kh: int, kw: int, *,
                  stride: int, pad: int) -> S2DGeometry:
     """Resolve the stride-1 geometry shared by the kernel, the tuner and
     the static verifier, with the tap folds decided here alone."""
     hp, wp = h + 2 * pad, w + 2 * pad
+    if subsamples(kh, kw, stride):
+        return s2d_geometry(-(-hp // stride), -(-wp // stride), cg, 1, 1,
+                            stride=1, pad=0)
     oh = (hp - kh) // stride + 1
     ow = (wp - kw) // stride + 1
     s = stride
@@ -293,18 +308,24 @@ def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
                       pool_k: int, pool_s: int, pr: int, n_c_tiles: int,
                       quantized: bool = False,
                       out_scale: Optional[float] = None,
-                      halo: Optional[Halo] = None, n_mg: int = 1):
+                      halo: Optional[Halo] = None, n_mg: int = 1,
+                      residual: bool = False):
     """One (B-block, H-tile, M-tile) output block; accumulates over C-tiles.
 
     ``quantized`` inserts the per-channel scale ref after the bias and
     switches the accumulator to int32 (the fixed-point pipeline); the
     epilogue then requantizes (scale -> bias -> ReLU -> pool -> round).
+    ``residual`` inserts next the block of the residual operand that
+    matches the output block; the epilogue adds it after the bias and
+    before the ReLU (bias -> residual -> ReLU).
     ``halo`` (:class:`Halo`) means x is the unpadded image: the taps read
     a line-buffer scratch (the last one) that :func:`_make_halo` fills
     whenever the x block changes.
     """
     if quantized:
         s_ref, refs = refs[0], refs[1:]
+    if residual:
+        r_ref, refs = refs[0], refs[1:]
     o_ref, acc_ref, *refs = refs
     if pool is not None:
         y_ref, *refs = refs
@@ -366,7 +387,7 @@ def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
             # bias stays fp32 so it needs no per-channel rescaling
             y = y * s_ref[...]
         y = y + b_ref[...].astype(jnp.float32)
-        if relu:
+        if relu and not residual:
             y = jnp.maximum(y, 0.0)
         y = y.reshape(b_blk, oh_ext, ow_p, m_blk)
         if pool is not None:
@@ -399,6 +420,12 @@ def _conv_pipe_kernel(x_ref, w_ref, b_ref, *refs, oh_ext: int, ow: int,
                 y = y / (pool_k * pool_k)
         else:
             y = y[:, :, :ow]
+            if residual:
+                # the shortcut's tile joins the conv's in VMEM: ResNet's
+                # add never makes a pass of its own over HBM
+                y = y + r_ref[...].astype(jnp.float32)
+                if relu:
+                    y = jnp.maximum(y, 0.0)
         if quantized and out_scale is not None:
             # emit int8 for the next layer: same round-half-even/clip as
             # quant.core.quantize, so kernel and reference are bit-equal
@@ -414,8 +441,10 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
               c_blk: int = LANE, m_blk: int = LANE, oh_blk: int = 0,
               b_blk: int = 1, groups: int = 1,
               vmem_budget: int = VMEM_BYTES,
+              residual: Optional[jax.Array] = None,
               interpret: Optional[bool] = None) -> jax.Array:
-    """Fused conv(+bias)(+ReLU)(+pool). x (B,H,W,C); w (KH,KW,C/G,M); b (M,).
+    """Fused conv(+bias)(+residual)(+ReLU)(+pool). x (B,H,W,C);
+    w (KH,KW,C/G,M); b (M,); residual (B,OH,OW,M).
 
     c_blk/m_blk are the VEC_SIZE/CU_NUM analogues, counted in the
     space-to-depth channels (``s*s*C/G``, times kw where the column taps
@@ -429,6 +458,10 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     ``groups`` runs grouped convolution inside the one kernel: groups get
     an array axis of their own, so a narrow per-group slab (AlexNet conv2:
     48 channels) is a whole-dim block rather than a misaligned lane window.
+    ``residual`` is added to the conv's output before the ReLU, one
+    output-shaped block of it DMA'd for each output block (ResNet's
+    shortcut add); it takes no pool and no fixed-point mode.
+    A strided 1x1 conv runs on the subsampled image (:func:`subsamples`).
     ``interpret`` (None = from the backend, :mod:`repro.kernels.mode`)
     runs the kernel body on the host; compiled, c_blk/m_blk must be
     lane-legal (:func:`lane_legal`).
@@ -450,6 +483,13 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     if w.shape[2] != cg:
         raise ValueError(f"w channel axis {w.shape[2]} != C/groups = {cg}")
     g = s2d_geometry(H, W, cg, KH, KW, stride=stride, pad=pad)
+    if residual is not None:
+        if pool is not None or quantized:
+            raise ValueError("conv_pipe's residual epilogue takes no pool "
+                             "and no fixed-point mode")
+        if residual.shape != (B, g.oh, g.ow, M):
+            raise ValueError(f"residual {residual.shape} is not the conv's "
+                             f"output {(B, g.oh, g.ow, M)}")
     if pool is not None:
         ph = (g.oh - pool_k) // pool_s + 1
         pw = (g.ow - pool_k) // pool_s + 1
@@ -480,7 +520,7 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
         autotune.ConvShape(h=H, w=W, c=C, kh=KH, kw=KW, m=M, stride=stride,
                            pad=pad, groups=groups, pool=pool, pool_k=pool_k,
                            pool_s=pool_s, dtype=jnp.dtype(x.dtype).name,
-                           b=B),
+                           b=B, residual=residual is not None),
         c_blk, m_blk, oh_blk, b_blk, vmem_budget)
     halo = None
     if in_kernel:
@@ -489,7 +529,9 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
                     n_h=n_h)
     else:
         x = _pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad), (0, 0)))
-        if stride > 1:
+        if subsamples(KH, KW, stride):
+            x = x[:, :, ::stride, ::stride]
+        elif stride > 1:
             x, w = _space_to_depth(x, w, stride, g)
         # right-pad W so every tap reads ow_p columns in bounds
         x = _pad(x, ((0, 0),) * 3 + ((0, g.w - x.shape[3]), (0, 0)))
@@ -517,7 +559,8 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
         _conv_pipe_kernel, oh_ext=oh_ext, ow=g.ow, ow_p=g.ow_p,
         kw_fold=g.kw_fold, kh_fold=g.kh_fold, relu=relu,
         pool=pool, pool_k=pool_k, pool_s=pool_s, pr=pr, n_c_tiles=n_c,
-        quantized=quantized, out_scale=out_scale, halo=halo, n_mg=n_mg)
+        quantized=quantized, out_scale=out_scale, halo=halo, n_mg=n_mg,
+        residual=residual is not None)
 
     # x tiles overlap by the halo rows => element-offset indexing on H
     # only (B, W and C stay blocked); the folded leading axis decomposes
@@ -554,6 +597,13 @@ def conv_pipe(x: jax.Array, w: jax.Array, b: jax.Array, *,
     out_spec = pl.BlockSpec(
         (None, b_blk, pr, pw, m_blk),
         lambda bh, mi, ci: (mi // n_mg, bh // n_h, bh % n_h, 0, mi % n_mg))
+    if residual is not None:
+        # laid out as the output is, so its blocks are the output's
+        r = residual.reshape(B, g.oh, g.ow, groups, mg)
+        in_specs.append(out_spec)
+        args.append(_pad(r.transpose(3, 0, 1, 2, 4),
+                         ((0, 0), (0, n_b * b_blk - B),
+                          (0, n_h * pr - g.oh), (0, 0), (0, mgp - mg))))
     if quantized:
         out_dtype = jnp.int8 if out_scale is not None else jnp.float32
     else:

@@ -51,11 +51,13 @@ def _kernel_wrapper(static_argnames: Tuple[str, ...]) -> Callable:
     "stride", "pad", "relu", "pool", "pool_k", "pool_s", "use_pallas",
     "c_blk", "m_blk", "oh_blk", "b_blk", "groups", "plan", "out_scale",
     "vmem_budget"))
-def fused_conv(x, w, b, *, scale=None, out_scale=None, stride=1, pad=0,
-               relu=True, pool=None, pool_k=2, pool_s=2, use_pallas=False,
-               c_blk=LANE, m_blk=LANE, oh_blk=0, b_blk=1, groups=1,
-               plan=None, vmem_budget=VMEM_BYTES, interpret=False):
-    """Fused conv(+bias)(+ReLU)(+pool), grouped-conv and batch-fold aware.
+def fused_conv(x, w, b, *, scale=None, out_scale=None, residual=None,
+               stride=1, pad=0, relu=True, pool=None, pool_k=2, pool_s=2,
+               use_pallas=False, c_blk=LANE, m_blk=LANE, oh_blk=0, b_blk=1,
+               groups=1, plan=None, vmem_budget=VMEM_BYTES,
+               interpret=False):
+    """Fused conv(+bias)(+residual)(+ReLU)(+pool), grouped-conv and
+    batch-fold aware.
 
     ``plan`` (a frozen :class:`repro.kernels.autotune.ConvPlan`) overrides
     the c_blk/m_blk/oh_blk/b_blk knobs with an autotuned point; being
@@ -68,7 +70,14 @@ def fused_conv(x, w, b, *, scale=None, out_scale=None, stride=1, pad=0,
     (static float) quantizes the output for the next layer (None emits
     fp32). Its non-pallas path is the EXACT int32 reference
     (``quant.ref.conv_int8_ref``), bit-equal to the kernel.
+
+    ``residual`` (B, OH, OW, M) is summed into the conv's output after
+    the bias and before the ReLU, in the kernel's epilogue (ResNet's
+    shortcut add); it takes no pool and no int8.
     """
+    if residual is not None and (pool is not None or scale is not None):
+        raise ValueError("fused_conv's residual epilogue takes no pool and "
+                         "no int8 scale")
     if plan is not None:
         c_blk, m_blk, oh_blk = plan.c_blk, plan.m_blk, plan.oh_blk
         b_blk = plan.b_blk
@@ -78,7 +87,7 @@ def fused_conv(x, w, b, *, scale=None, out_scale=None, stride=1, pad=0,
                          pool_k=pool_k, pool_s=pool_s, c_blk=c_blk,
                          m_blk=m_blk, oh_blk=oh_blk, b_blk=b_blk,
                          groups=groups, vmem_budget=vmem_budget,
-                         interpret=interpret)
+                         residual=residual, interpret=interpret)
     if scale is not None:
         return quant_ref.conv_int8_ref(x, w, b, scale, stride=stride,
                                        pad=pad, relu=relu, pool=pool,
@@ -86,7 +95,7 @@ def fused_conv(x, w, b, *, scale=None, out_scale=None, stride=1, pad=0,
                                        groups=groups, out_scale=out_scale)
     return ref.conv_pipe_ref(x, w, b, stride=stride, pad=pad, relu=relu,
                              pool=pool, pool_k=pool_k, pool_s=pool_s,
-                             groups=groups)
+                             groups=groups, residual=residual)
 
 
 @_kernel_wrapper(("use_pallas", "exact"))

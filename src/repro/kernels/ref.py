@@ -15,13 +15,16 @@ from repro.kernels.lrn_pwl import LRN_ALPHA, LRN_BETA, LRN_K, LRN_N
 
 
 def conv_pipe_ref(x, w, b, *, stride=1, pad=0, relu=True, pool=None,
-                  pool_k=2, pool_s=2, groups=1):
-    """Oracle for kernels.conv_pipe (conv + bias + ReLU + pool, grouped)."""
+                  pool_k=2, pool_s=2, groups=1, residual=None):
+    """Oracle for kernels.conv_pipe (conv + bias (+ residual) + ReLU +
+    pool, grouped)."""
     out = jax.lax.conv_general_dilated(
         x, w, (stride, stride), [(pad, pad), (pad, pad)],
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         feature_group_count=groups)
     out = out + b
+    if residual is not None:
+        out = out + residual
     if relu:
         out = jnp.maximum(out, 0.0)
     if pool is not None:
@@ -29,10 +32,11 @@ def conv_pipe_ref(x, w, b, *, stride=1, pad=0, relu=True, pool=None,
     return out
 
 
-def pool_ref(x, pool="max", k=2, s=2):
+def pool_ref(x, pool="max", k=2, s=2, pad=0):
     """Pooling oracle; int dtypes supported for max (the int8 pipeline
     max-pools directly on codes — max commutes with the monotone
-    quantization map, so the scale passes through unchanged)."""
+    quantization map, so the scale passes through unchanged). ``pad``
+    pads a max pool with its identity (ResNet's 3x3/2 stem pool)."""
     integer = jnp.issubdtype(x.dtype, jnp.integer)
     if pool == "max":
         init = jnp.iinfo(x.dtype).min if integer else -jnp.inf
@@ -42,9 +46,12 @@ def pool_ref(x, pool="max", k=2, s=2):
             raise NotImplementedError(
                 "avg-pool on integer codes needs a requantize; "
                 "dequantize first")
+        if pad:
+            raise ValueError("only a max pool takes a pad")
         init, red = 0.0, jax.lax.add
     out = jax.lax.reduce_window(x, jnp.asarray(init, x.dtype), red,
-                                (1, k, k, 1), (1, s, s, 1), "VALID")
+                                (1, k, k, 1), (1, s, s, 1),
+                                ((0, 0), (pad, pad), (pad, pad), (0, 0)))
     return out / (k * k) if pool == "avg" else out
 
 

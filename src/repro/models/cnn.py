@@ -1,10 +1,14 @@
-"""CNN model stack: AlexNet / VGG-16 through the PipeCNN fused pipeline.
+"""CNN model stack: AlexNet / VGG-16 / ResNet-50 through the PipeCNN
+fused pipeline.
 
 :func:`run_group` executes one fusion group of ``fuse_plan(cfg)``:
 consecutive conv(+relu)+pool pairs run as ONE fused kernel (the paper's
 Conv->Pool channel), LRN runs as its own kernel off the pipeline (the paper
 implements LRN separately because of its multi-map access pattern), and FC
-layers run through the multi-mode engine in batched-FC mode.
+layers run through the multi-mode engine in batched-FC mode. In a layer
+graph (ResNet-50) a conv with no ReLU runs with the shortcut add that
+follows it as ONE kernel too, the add's other input summed in its
+epilogue; gap and the padded stem pool run in XLA.
 :func:`cnn_forward_stage` folds it over a slice of groups; the whole
 forward is ``repro.pipeline.compile_cnn(cfg, spec, params).forward``,
 which hands every group its compile-time tiling plan.
@@ -25,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.config import CNNConfig
+from repro.core.config import (CNNConfig, group_inputs, layer_inputs,
+                               layer_shapes)
 from repro.kernels import ops
 from repro.kernels.ref import pool_ref
 from repro.models.layers import dense_init
@@ -34,12 +39,14 @@ from repro.quant.core import dequantize, quantize
 
 
 def init_cnn_params(key, cfg: CNNConfig) -> List[Dict[str, Any]]:
-    """Per-layer param list aligned with cfg.layers (None for pool/lrn)."""
+    """Per-layer param list aligned with cfg.layers (None for the layers
+    with no weights: pool, lrn, add, gap); a layer's input follows its
+    ``inputs``."""
     params: List[Any] = []
-    c = cfg.input_ch
-    hw = cfg.input_hw
+    shapes = layer_shapes(cfg)
     dtype = jnp.float32 if cfg.dtype == "float32" else jnp.bfloat16
-    for l in cfg.layers:
+    for i, l in enumerate(cfg.layers):
+        ih, iw, c = shapes[layer_inputs(cfg.layers, i)[0]]
         if l.kind == "conv":
             key, k1 = jax.random.split(key)
             cg = c // l.groups
@@ -48,20 +55,13 @@ def init_cnn_params(key, cfg: CNNConfig) -> List[Dict[str, Any]]:
                                    jnp.float32)
                  * np.sqrt(2.0 / fan_in)).astype(dtype)
             params.append({"w": w, "b": jnp.zeros((l.out_ch,), dtype)})
-            hw = (hw + 2 * l.pad - l.kernel) // l.stride + 1
-            c = l.out_ch
-        elif l.kind == "pool":
-            params.append(None)
-            hw = (hw - l.kernel) // l.stride + 1
-        elif l.kind == "lrn":
-            params.append(None)
         elif l.kind == "fc":
             key, k1 = jax.random.split(key)
-            fan_in = c * hw * hw
             params.append({
-                "w": dense_init(k1, (fan_in, l.out_ch), dtype),
+                "w": dense_init(k1, (ih * iw * c, l.out_ch), dtype),
                 "b": jnp.zeros((l.out_ch,), dtype)})
-            hw, c = 1, l.out_ch
+        else:
+            params.append(None)
     return params
 
 
@@ -80,6 +80,7 @@ def fuse_plan(cfg: CNNConfig) -> List[Tuple[int, ...]]:
 
 def run_group(params, x: jax.Array, cfg: CNNConfig,
               group: Tuple[int, ...], *,
+              residual: Optional[jax.Array] = None,
               plans: Optional[Mapping[Tuple[int, ...], Any]] = None,
               use_pallas: bool = False) -> jax.Array:
     """Execute ONE fusion group — the one place a layer kind is run.
@@ -91,6 +92,11 @@ def run_group(params, x: jax.Array, cfg: CNNConfig,
     the params, so a stage can start from any group boundary given that
     boundary's codes.
 
+    ``x`` is the output of the layer the group's first layer reads;
+    ``residual`` the add's other input (``core.config.group_inputs``),
+    for a conv fused with an add (summed in the kernel's epilogue) or a
+    lone add.
+
     With ``use_pallas``, a conv or fc group runs the tiling
     ``plans[group]`` (a ``ConvPlan``/``GemmPlan`` that ``compile_cnn``
     froze); the reference path needs no plans.
@@ -100,7 +106,12 @@ def run_group(params, x: jax.Array, cfg: CNNConfig,
     p = params.layers[group[0]] if quant else params[group[0]]
     if l.kind == "pool":
         # max-pool commutes with the int8 map: pool the codes, keep scale
-        return pool_ref(x, l.pool, l.kernel, l.stride)
+        return pool_ref(x, l.pool, l.kernel, l.stride, pad=l.pad)
+    if l.kind == "gap":
+        return jnp.mean(x, axis=(1, 2), keepdims=True)
+    if l.kind == "add":
+        y = x + residual
+        return jnp.maximum(y, 0.0) if l.relu else y
     if l.kind == "lrn":
         if not quant:
             return ops.lrn(x, use_pallas=use_pallas)
@@ -115,11 +126,15 @@ def run_group(params, x: jax.Array, cfg: CNNConfig,
     else:
         w, b, epilogue = p["w"], p["b"], {}
     if l.kind == "conv":
-        pool = cfg.layers[group[1]] if len(group) == 2 else None
+        tail = cfg.layers[group[1]] if len(group) == 2 else None
+        pool = tail if tail is not None and tail.kind == "pool" else None
+        relu = l.relu
+        if tail is not None and tail.kind == "add":
+            epilogue["residual"], relu = residual, tail.relu
         # grouped conv (AlexNet two-tower) runs INSIDE the one kernel:
         # the M-tile grid axis spans groups, no concat on the hot path
         return ops.fused_conv(x, w, b, stride=l.stride, pad=l.pad,
-                              relu=l.relu,
+                              relu=relu,
                               pool=(pool.pool if pool else None),
                               pool_k=(pool.kernel if pool else 2),
                               pool_s=(pool.stride if pool else 2),
@@ -139,15 +154,29 @@ def cnn_forward_stage(params, x: jax.Array, cfg: CNNConfig, groups, *,
                       use_pallas: bool = False) -> jax.Array:
     """Run a contiguous slice of fusion groups — one pipeline STAGE.
 
+    ``x`` is the output of the layer before the slice's first (the image
+    batch for the whole network). The fold keeps each activation a later
+    group of the slice reads, by layer index, and drops it after the
+    last such group: in a chain that is the one ``x`` passed along; in
+    ResNet a block's input waits for its shortcut add.
+
     With ``QuantizedCNNParams``, ``x`` is the boundary activation: int8
     codes (any interior boundary) or the raw fp32 image batch for the
     first stage, which this function quantizes at the network edge.
     """
     if isinstance(params, QuantizedCNNParams) and x.dtype != jnp.int8:
         x = quantize(x, params.in_scale)
-    for group in groups:
-        x = run_group(params, x, cfg, group, plans=plans,
-                      use_pallas=use_pallas)
+    reads = [group_inputs(cfg.layers, g) for g in groups]
+    last = {j: k for k, ins in enumerate(reads) for j in ins}
+    acts = {groups[0][0] - 1: x}
+    for k, (group, ins) in enumerate(zip(groups, reads)):
+        x = run_group(params, acts[ins[0]], cfg, group,
+                      residual=acts[ins[1]] if len(ins) > 1 else None,
+                      plans=plans, use_pallas=use_pallas)
+        for j in set(ins):
+            if last[j] == k:
+                del acts[j]
+        acts[group[-1]] = x
     return x
 
 

@@ -36,7 +36,7 @@ import numpy as np
 
 from repro.core.config import CNNConfig, SpecError
 from repro.kernels import autotune, ops
-from repro.kernels.conv_pipe import s2d_geometry
+from repro.kernels.conv_pipe import s2d_geometry, subsamples
 from repro.kernels.mode import backend_interprets
 from repro.obs.trace import SPANS, now_ns
 from repro.pipeline.plan_table import PlanTable, load_plan, plan_key
@@ -50,19 +50,13 @@ def _group_shapes(cfg: CNNConfig, batch: int, dtype: str):
     ``GemmShape`` for fc groups. One shape constructor shared by the DSE
     resolve and the roofline breakdown, so they can never disagree on
     what a group's plan was tuned for."""
-    from repro.serve.stage_planner import group_io_shapes
+    from repro.serve.stage_planner import conv_shape, group_io_shapes
 
     for group, in_shape, out_shape in group_io_shapes(cfg):
         l = cfg.layers[group[0]]
         if l.kind == "conv":
-            h, w, c = in_shape
-            pool = cfg.layers[group[1]] if len(group) == 2 else None
-            yield group, "conv", autotune.ConvShape(
-                h=h, w=w, c=c, kh=l.kernel, kw=l.kernel, m=l.out_ch,
-                stride=l.stride, pad=l.pad, groups=l.groups,
-                pool=(pool.pool if pool else None),
-                pool_k=(pool.kernel if pool else 2),
-                pool_s=(pool.stride if pool else 2), dtype=dtype, b=batch)
+            yield group, "conv", conv_shape(cfg, group, in_shape, dtype,
+                                            batch)
         elif l.kind == "fc":
             k = 1
             for d in in_shape:
@@ -116,8 +110,11 @@ def _count_conv_paths(cfg: CNNConfig, batch: int, dtype: str,
     ``conv_pipe`` folds into its MXU contraction, (``conv.kh_fold``) each
     whose row taps fold too (:func:`~repro.kernels.conv_pipe.s2d_geometry`
     decides), (``conv.pool_fused``) each whose pool runs in its epilogue,
-    and (``conv.halo_in_kernel``) each whose halo it makes in VMEM under
-    its plan (:func:`~repro.kernels.autotune.halo_in_kernel`)."""
+    (``conv.residual_fused``) each whose epilogue adds a residual
+    operand, (``conv.subsampled``) each strided 1x1 conv run on its
+    subsampled input (:func:`~repro.kernels.conv_pipe.subsamples`), and
+    (``conv.halo_in_kernel``) each whose halo it makes in VMEM under its
+    plan (:func:`~repro.kernels.autotune.halo_in_kernel`)."""
     for group, kind, s in _group_shapes(cfg, batch, dtype):
         if kind != "conv":
             continue
@@ -133,6 +130,10 @@ def _count_conv_paths(cfg: CNNConfig, batch: int, dtype: str,
             SPANS.count("conv.kh_fold")
         if s.pool is not None:
             SPANS.count("conv.pool_fused")
+        if s.residual:
+            SPANS.count("conv.residual_fused")
+        if subsamples(s.kh, s.kw, s.stride):
+            SPANS.count("conv.subsampled")
 
 
 class CompiledCNN:
@@ -374,7 +375,6 @@ class CompiledCNN:
         unit) — ``None`` on unmeasured rows, so the modeled view is
         unchanged when no profiler ran.
         """
-        import dataclasses
 
         batch = self.spec.serving.batch
         dtype = "int8" if self.quant else self.spec.run_dtype
@@ -398,7 +398,7 @@ class CompiledCNN:
                     shape, plan.bm, plan.bn, plan.bk)
             t_model = max(tc, tm)
             m = measured.get(plan_key(
-                {"shape": dataclasses.asdict(shape), "backend": "tpu",
+                {"shape": shape.to_dict(), "backend": "tpu",
                  "vmem_budget": self.cfg.vmem_budget,
                  "plan": plan.to_dict()}))
             rows.append({"group": list(group), "kind": kind,

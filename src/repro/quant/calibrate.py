@@ -32,6 +32,7 @@ from typing import Iterable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.config import SpecError
 from repro.quant.core import quantize_channelwise
 from repro.quant.observers import make_observer
 
@@ -115,6 +116,11 @@ def calibrate_cnn(params, calib, cfg, *,
     """
     from repro.models.cnn import fuse_plan
 
+    if cfg.is_graph:
+        raise SpecError(
+            "CNNConfig.quant",
+            f"int8 calibration walks a chain of layers, and {cfg.name!r} "
+            f"is a layer graph; serve it in float")
     batches = [calib] if hasattr(calib, "shape") else list(calib)
     if not batches:
         raise ValueError("calibration set is empty")

@@ -26,7 +26,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
-from repro.core.config import CNNConfig
+from repro.core.config import (CNNConfig, SpecError, group_inputs,
+                               layer_shapes)
 from repro.core.roofline import HBM_BW, VMEM_BYTES, pipeline_bubble_fraction
 from repro.kernels.autotune import (_DTYPE_BYTES, ConvShape, GemmShape,
                                     get_gemm_plan, get_plan)
@@ -40,27 +41,36 @@ def group_io_shapes(cfg: CNNConfig) -> List[Tuple[Tuple[int, ...],
 
     Shapes are per-image activations: ``(H, W, C)`` between spatial
     stages, ``(features,)`` after an FC layer. These are the stage
-    boundary shapes the engine's canonical flat buffer must hold.
+    boundary shapes the engine's canonical flat buffer must hold. A
+    group's input is the layer its first layer reads (in a layer graph,
+    not always the group before it).
     """
-    out = []
-    hw, c = cfg.input_hw, cfg.input_ch
-    for group in fuse_plan(cfg):
-        in_shape: Tuple[int, ...] = (hw, hw, c)
-        for i in group:
-            l = cfg.layers[i]
-            if l.kind == "conv":
-                hw = (hw + 2 * l.pad - l.kernel) // l.stride + 1
-                c = l.out_ch
-            elif l.kind == "pool":
-                hw = (hw - l.kernel) // l.stride + 1
-            elif l.kind == "fc":
-                hw, c = 1, l.out_ch
-        out_shape = (c,) if cfg.layers[group[-1]].kind == "fc" \
-            else (hw, hw, c)
-        if cfg.layers[group[0]].kind == "fc":
-            in_shape = out[-1][2] if out else in_shape
-        out.append((group, in_shape, out_shape))
-    return out
+    shapes = layer_shapes(cfg)
+
+    def io(i: int) -> Tuple[int, ...]:
+        fc = i >= 0 and cfg.layers[i].kind == "fc"
+        return shapes[i][-1:] if fc else shapes[i]
+
+    return [(group, io(group_inputs(cfg.layers, group)[0]), io(group[-1]))
+            for group in fuse_plan(cfg)]
+
+
+def conv_shape(cfg: CNNConfig, group: Tuple[int, ...],
+               in_shape: Tuple[int, ...], dtype: str,
+               batch: int) -> ConvShape:
+    """The tuning key of a conv fusion group: the conv, with the pool or
+    the residual add fused into its epilogue."""
+    l = cfg.layers[group[0]]
+    h, w, c = in_shape
+    tail = cfg.layers[group[1]] if len(group) == 2 else None
+    pool = tail if tail is not None and tail.kind == "pool" else None
+    return ConvShape(
+        h=h, w=w, c=c, kh=l.kernel, kw=l.kernel, m=l.out_ch,
+        stride=l.stride, pad=l.pad, groups=l.groups,
+        pool=(pool.pool if pool else None),
+        pool_k=(pool.kernel if pool else 2),
+        pool_s=(pool.stride if pool else 2), dtype=dtype, b=batch,
+        residual=tail is not None and tail.kind == "add")
 
 
 def group_cost(cfg: CNNConfig, group: Tuple[int, ...],
@@ -81,14 +91,7 @@ def _plan_and_cost(cfg: CNNConfig, group: Tuple[int, ...],
     dt = _DTYPE_BYTES.get(dtype, 4)
     l = cfg.layers[group[0]]
     if l.kind == "conv":
-        h, w, c = in_shape
-        pool = cfg.layers[group[1]] if len(group) == 2 else None
-        shape = ConvShape(
-            h=h, w=w, c=c, kh=l.kernel, kw=l.kernel, m=l.out_ch,
-            stride=l.stride, pad=l.pad, groups=l.groups,
-            pool=(pool.pool if pool else None),
-            pool_k=(pool.kernel if pool else 2),
-            pool_s=(pool.stride if pool else 2), dtype=dtype, b=batch)
+        shape = conv_shape(cfg, group, in_shape, dtype, batch)
         plan = get_plan(shape, vmem_budget=cfg.vmem_budget)
         return plan, plan.t_model * batch
     if l.kind == "fc":
@@ -209,6 +212,11 @@ def plan_stages(cfg: CNNConfig, n_stages: int, *, batch: int = 1,
     exceed the group count.
     """
     dtype = dtype or cfg.dtype
+    if cfg.is_graph and n_stages > 1:
+        raise SpecError(
+            "CNNConfig.pp_stages",
+            f"{n_stages} pipeline stages pass one activation between "
+            f"them, and {cfg.name!r} is a layer graph")
     shapes = group_io_shapes(cfg)
     if n_stages < 1 or n_stages > len(shapes):
         raise ValueError(

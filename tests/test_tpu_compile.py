@@ -132,25 +132,82 @@ def test_lrn_pwl_compiles(sds):
                    sds((BATCH, 55, 55, 96), jnp.float32))
 
 
+@pytest.fixture(scope="module")
+def whole_forward(topo, sds):
+    """``whole_forward(arch)``: the compiled text of ``CompiledCNN.lower``
+    of the whole forward at ``BATCH`` (float32, the tuned plans), made
+    once per net in this module, with the span log on, as it is by
+    default."""
+    from repro.models.cnn import init_cnn_params
+    from repro.obs import SPANS
+    from repro.pipeline import ExecutionSpec, Serving, compile_cnn
+
+    texts = {}
+
+    def lowered(arch):
+        if arch not in texts:
+            assert SPANS.enabled
+            cfg = get_config(arch)
+            params = jax.tree.map(
+                lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+                    lambda: init_cnn_params(jax.random.key(0), cfg)))
+            compiled = compile_cnn(cfg, ExecutionSpec(
+                interpret=False, serving=Serving(batch=BATCH)), params,
+                with_engine=False)
+            texts[arch] = compiled.lower(sds(
+                (BATCH, cfg.input_hw, cfg.input_hw, cfg.input_ch),
+                jnp.float32)).compile().as_text()
+        return texts[arch]
+    return lowered
+
+
+def _kernels(txt):
+    """The instruction names of the compiled Pallas kernels in ``txt``."""
+    import re
+    return [re.match(r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=", line).group(1)
+            for line in txt.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
 # (conv groups, fc layers, groups with folded column taps, groups with
 # the pool in the kernel's epilogue)
-NET_GROUPS = {"alexnet": (5, 3, 2, 1), "vgg16": (13, 3, 3, 5)}
+NET_GROUPS = {"alexnet": (5, 3, 2, 1), "vgg16": (13, 3, 3, 5),
+              "resnet50": (53, 1, 4, 0)}
 
 
 @pytest.mark.parametrize("arch,dtype", [
-    ("alexnet", "float32"), ("alexnet", "int8"), ("vgg16", "float32")],
-    ids=["float32", "int8", "vgg16-float32"])
-def test_tuned_alexnet_plans_compile(sds, arch, dtype):
+    ("alexnet", "float32"), ("alexnet", "int8"), ("vgg16", "float32"),
+    ("resnet50", "float32")],
+    ids=["float32", "int8", "vgg16-float32", "resnet50-float32"])
+def test_tuned_alexnet_plans_compile(sds, whole_forward, arch, dtype):
     """Every plan the tuner picks for AlexNet and VGG-16 at the serving
     batch lowers: its lane/sublane-legal blocks and its VMEM model hold
     on the chip, AlexNet conv1's and conv2's and VGG conv1_1's, conv1_2's
     and conv2_1's over their folded column taps among them, and VGG's
     pooled groups at 256 and 512 output channels a tile (the epilogue's
     128-lane pool slabs; their int8 form is ``vgg16_conv5_pool``
-    above)."""
+    above). ResNet-50's 53 conv plans lower in its whole forward (one
+    lowering, shared with the test below): the 16 with the residual
+    epilogue, the three subsampled 1x1/2 projections, and the stem's and
+    stage 1's three 3x3s with both their column and row taps folded."""
+    from repro.pipeline.compile import _group_shapes
+
     cfg = get_config(arch)
     int8 = dtype == "int8"
     n_conv = n_fc = n_folded = n_pooled = 0
+    if arch == "resnet50":
+        shapes = [s for _, kind, s in _group_shapes(cfg, BATCH, dtype)
+                  if kind == "conv"]
+        geoms = [s2d_geometry(s.h, s.w, s.c, s.kh, s.kw, stride=s.stride,
+                              pad=s.pad) for s in shapes]
+        folded = [g for g in geoms if g.kw_fold > 1]
+        assert all(g.kh_fold > 1 for g in folded)
+        assert sum(s.residual for s in shapes) == 16
+        kernels = [k.split(".")[0] for k in _kernels(whole_forward(arch))]
+        counts = (kernels.count("fused_conv"), kernels.count("fc"),
+                  len(folded), sum(s.pool is not None for s in shapes))
+        assert counts == NET_GROUPS[arch]
+        return
     for group, in_shape, out_shape in group_io_shapes(cfg):
         layer = cfg.layers[group[0]]
         if layer.kind == "conv":
@@ -202,32 +259,17 @@ def test_tuned_alexnet_plans_compile(sds, arch, dtype):
 
 @pytest.mark.parametrize("arch,families,n_kernels", [
     ("alexnet", ["fc", "fused_conv", "lrn"], 10),   # 5 conv, 2 LRN, 3 FC
-    ("vgg16", ["fc", "fused_conv"], 16)],           # 13 conv, 3 FC
-    ids=["alexnet", "vgg16"])
-def test_whole_alexnet_forward_names_its_kernels(topo, sds, arch, families,
-                                                 n_kernels):
-    """``CompiledCNN.lower`` of the whole AlexNet (and VGG-16) forward
-    (with the span log on, as it is by default): every compiled Pallas
-    kernel is an instruction named after its jitted wrapper,
+    ("vgg16", ["fc", "fused_conv"], 16),            # 13 conv, 3 FC
+    ("resnet50", ["fc", "fused_conv"], 54)],        # 53 conv, 1 FC
+    ids=["alexnet", "vgg16", "resnet50"])
+def test_whole_alexnet_forward_names_its_kernels(whole_forward, arch,
+                                                 families, n_kernels):
+    """``CompiledCNN.lower`` of the whole AlexNet (and VGG-16, ResNet-50)
+    forward (with the span log on, as it is by default): every compiled
+    Pallas kernel is an instruction named after its jitted wrapper,
     ``fused_conv``, ``fc`` or ``lrn``, the families the benchmark's
-    kernel rooflines read from the device trace."""
-    import re
-
-    from repro.models.cnn import init_cnn_params
-    from repro.obs import SPANS
-    from repro.pipeline import ExecutionSpec, Serving, compile_cnn
-
-    assert SPANS.enabled
-    cfg = get_config(arch)
-    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
-        lambda: init_cnn_params(jax.random.key(0), cfg)))
-    compiled = compile_cnn(cfg, ExecutionSpec(
-        interpret=False, serving=Serving(batch=BATCH)), params,
-        with_engine=False)
-    txt = compiled.lower(sds((BATCH, cfg.input_hw, cfg.input_hw,
-                              cfg.input_ch), jnp.float32)).compile().as_text()
-    kernels = [re.match(r"\s*(?:ROOT\s+)?%?([\w.-]+)\s*=", line).group(1)
-               for line in txt.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernel rooflines read from the device trace; ResNet-50's adds run
+    inside its ``fused_conv`` kernels, one a conv layer."""
+    kernels = _kernels(whole_forward(arch))
     assert sorted({k.split(".")[0] for k in kernels}) == families, kernels
     assert len(kernels) == n_kernels, kernels

@@ -241,6 +241,31 @@ def test_halo_in_kernel_counted_per_conv_group(spans, arch, halo):
     assert spans.read()["counters"].get("conv.halo_in_kernel") == halo
 
 
+@pytest.mark.parametrize("arch,fused,subsampled", [
+    ("alexnet", 0, 0), ("vgg16", 0, 0), ("resnet50", 16, 3)])
+def test_residual_and_subsample_counted_per_conv_group(spans, arch, fused,
+                                                       subsampled):
+    """``compile_cnn`` counts ``conv.residual_fused`` once for each conv
+    group whose epilogue adds a residual operand (ResNet-50: each
+    block's add) and ``conv.subsampled`` once for each strided 1x1 conv
+    run on its subsampled input (ResNet-50's three 1x1/2 projections);
+    a chain has neither. The counters of the chains read on it too: the
+    stem's and stage 1's 3x3s fold both their taps, no pool is fused."""
+    from repro.models.cnn import init_cnn_params
+
+    cfg = get_config(arch)
+    params = jax.eval_shape(lambda: init_cnn_params(jax.random.key(0), cfg))
+    compile_cnn(cfg, ExecutionSpec(serving=Serving(batch=8)), params,
+                with_engine=False)
+    counters = spans.read()["counters"]
+    assert counters.get("conv.residual_fused", 0) == fused
+    assert counters.get("conv.subsampled", 0) == subsampled
+    if arch == "resnet50":
+        assert counters.get("conv.kw_fold") == 4
+        assert counters.get("conv.kh_fold") == 4
+        assert "conv.pool_fused" not in counters
+
+
 def test_chrome_export_validates(compiled, spans):
     c, cfg = compiled
     np.asarray(c.forward(_images(cfg, 4)))         # traced: one instant
